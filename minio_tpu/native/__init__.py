@@ -17,11 +17,15 @@ requests scale across cores where the host has them.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
 
 import numpy as np
+
+log = logging.getLogger("minio_tpu.native")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD = os.path.join(_DIR, "_build")
@@ -37,21 +41,46 @@ ALGO_HIGHWAY = 0
 ALGO_MUR3 = 1
 
 
+#: THE build command line (one fixed flag set: the binary must be the
+#: same from host to host, so no -march=native and no fallback ladder —
+#: a compiler that refuses these flags is an error, reported verbatim)
+BUILD_FLAGS = ("-O3", "-mavx2", "-shared", "-fPIC")
+
+
+def build_key() -> str:
+    """Content key of the library: sha256 over the five sources' bytes
+    plus the compiler flags. Stored beside the .so (``libnative.key``);
+    a mismatch rebuilds. mtimes play no part — a copied or freshly
+    checked-out tree carries none worth trusting."""
+    h = hashlib.sha256(" ".join(("g++",) + BUILD_FLAGS).encode())
+    for name in _SOURCES:
+        with open(os.path.join(_DIR, name), "rb") as f:
+            h.update(b"\0" + name.encode() + b"\0" + f.read())
+    return h.hexdigest()
+
+
 def _compile(src: str, out: str) -> None:
+    """Build ``out`` from ``src`` with the one command line, into a
+    temporary name inside ``_build/`` that is os.replace'd into place:
+    several processes (test workers, two servers) may build at the same
+    moment, and none may ever load a half-written file."""
     os.makedirs(_BUILD, exist_ok=True)
-    cmds = [
-        ["g++", "-O3", "-march=native", "-shared", "-fPIC", src, "-o", out],
-        ["g++", "-O3", "-mavx2", "-shared", "-fPIC", src, "-o", out],
-        ["g++", "-O3", "-shared", "-fPIC", src, "-o", out],
-    ]
-    last = None
-    for cmd in cmds:
+    tmp = f"{out}.tmp.{os.getpid()}.{threading.get_ident()}"
+    cmd = ["g++", *BUILD_FLAGS, src, "-o", tmp]
+    try:
+        proc = subprocess.run(cmd, capture_output=True)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"native build failed (rc {proc.returncode}): "
+                f"{' '.join(cmd)}\n"
+                f"{proc.stderr.decode(errors='replace')[-4000:]}")
+        # a build artifact, not stored data: no fsync policy applies
+        os.replace(tmp, out)  # graftlint: disable=GL009
+    finally:
         try:
-            subprocess.run(cmd, check=True, capture_output=True)
-            return
-        except subprocess.CalledProcessError as e:  # pragma: no cover
-            last = e
-    raise RuntimeError(f"native build failed: {last.stderr.decode()[:500]}")
+            os.unlink(tmp)
+        except OSError:
+            pass
 
 
 def load_native() -> ctypes.CDLL:
@@ -79,6 +108,11 @@ def load_native() -> ctypes.CDLL:
             return _load_native_locked()  # graftlint: disable=GL021
         except Exception as e:  # noqa: BLE001
             _load_error = e
+            # once, loudly: available() turns this into False for
+            # library users without a toolchain, and the on-disk bitrot
+            # default then changes — nobody may discover that by luck
+            log.error("native library unavailable (pure-Python "
+                      "fallbacks engage): %s", e)
             raise
 
 
@@ -86,10 +120,19 @@ def _load_native_locked() -> ctypes.CDLL:
     global _lib
     if _lib is None:
         out = os.path.join(_BUILD, "libnative.so")
-        src_mtime = max(os.path.getmtime(os.path.join(_DIR, s))
-                        for s in _SOURCES)
-        if not os.path.exists(out) or os.path.getmtime(out) < src_mtime:
+        key = build_key()
+        key_path = os.path.join(_BUILD, "libnative.key")
+        try:
+            with open(key_path) as f:
+                built = f.read().strip()
+        except OSError:
+            built = ""
+        if built != key or not os.path.exists(out):
             _compile(os.path.join(_DIR, "pipeline.cpp"), out)
+            # after the .so, and plainly: a torn key only fails to match
+            # and rebuilds
+            with open(key_path, "w") as f:
+                f.write(key + "\n")
         lib = ctypes.CDLL(out)
         c_u8p = ctypes.POINTER(ctypes.c_uint8)
         lib.gf256_encode.argtypes = [

@@ -208,8 +208,7 @@ class _TrackedJit:
             return jitted(*args, **kwargs)
         import jax
         leaves = jax.tree_util.tree_leaves((args, kwargs))
-        tracer = getattr(jax.core, "Tracer", ())
-        if any(isinstance(x, tracer) for x in leaves):
+        if any(isinstance(x, jax.core.Tracer) for x in leaves):
             return jitted(*args, **kwargs)
         sig = _signature(args, kwargs)
         with self._seen_lock:

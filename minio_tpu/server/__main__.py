@@ -24,7 +24,7 @@ def _root_creds() -> tuple[str, str]:
     return ak, sk
 
 
-def main(argv=None):
+def arg_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(prog="minio-tpu server")
     ap.add_argument("dirs", nargs="+", help="disk directories or "
                     "ellipses patterns like /data/disk{1...8}; "
@@ -40,13 +40,36 @@ def main(argv=None):
                     default=None,
                     help="gateway mode: serve the S3 API over a backend "
                          "(nas: shared mount path; s3: upstream endpoint)")
-    args = ap.parse_args(argv)
-    ak, sk = _root_creds()
-    if "," in args.address and any(
-            d.startswith(("http://", "https://")) for d in args.dirs):
-        ap.error("multi-addr --address is not supported in distributed "
-                 "mode; pass the single URL this node serves")
+    return ap
 
+
+def main(argv=None):
+    ap = arg_parser()
+    args = ap.parse_args(argv)
+    if any(d.startswith(("http://", "https://")) for d in args.dirs) \
+            and not args.gateway:
+        if "," in args.address:
+            ap.error("multi-addr --address is not supported in "
+                     "distributed mode; pass the single URL this node "
+                     "serves")
+        return _serve_distributed(args, *_root_creds())
+    srv, banner = build_server(args, ap)
+    print(f"{banner}; listening on {args.address}", file=sys.stderr)
+    try:
+        srv.serve_forever()
+    except KeyboardInterrupt:
+        pass
+
+
+def build_server(args, ap: argparse.ArgumentParser | None = None):
+    """Everything a single-node (or gateway) launch does short of
+    serving: object layer from the disk args, S3Server, service hook,
+    background services started. Returns (server, banner) —
+    ``main`` then serves forever; an embedding caller (chip_smoke.py)
+    serves on a thread and shuts down through ``server.shutdown()``.
+    ``args`` is ``arg_parser().parse_args([...])``."""
+    ap = ap or arg_parser()
+    ak, sk = _root_creds()
     if args.gateway:
         from ..gateway import new_gateway_layer
         if len(args.dirs) != 1:
@@ -56,8 +79,6 @@ def main(argv=None):
         obj = new_gateway_layer(args.gateway, args.dirs[0], up_ak, up_sk,
                                 args.region)
         banner = f"gateway {args.gateway} -> {args.dirs[0]}"
-    elif any(d.startswith(("http://", "https://")) for d in args.dirs):
-        return _serve_distributed(args, ak, sk)
     elif len(args.dirs) > 1 and any("{" in d for d in args.dirs) and \
             not all("{" in d for d in args.dirs):
         # the reference rejects mixed ellipses/non-ellipses endpoint args
@@ -137,11 +158,7 @@ def main(argv=None):
         # object layers; gateways proxy a backend that owns its own
         # durability (the reference skips these in gateway mode too)
         srv.start_background_services()
-    print(f"{banner}; listening on {args.address}", file=sys.stderr)
-    try:
-        srv.serve_forever()
-    except KeyboardInterrupt:
-        pass
+    return srv, banner
 
 
 def _install_service_hook(srv) -> None:

@@ -225,3 +225,72 @@ def test_device_bound_mode_gates():
     finally:
         os.environ.pop("MINIO_TPU_DISPATCH_MODE", None)
     q.stop()
+
+
+def test_stats_count_salvages_by_reason(monkeypatch, caplog):
+    """stats() carries what the smoke holds to zero: CPU salvages of
+    device work by reason (and the link probe's state, next test). A
+    device flush that raises logs at ERROR once per (op, shape), then at
+    WARNING."""
+    import logging
+
+    from minio_tpu import fault
+    q = DispatchQueue(max_batch=8, max_delay=0.001)
+    codec = get_codec(4, 2)
+    d = rng_shards(4, 1024, seed=3)
+    try:
+        assert q.stats()["salvaged_items"] == {}
+        rid = fault.arm("kernel:*:encode:error(FaultyDisk)@count=1")
+        try:
+            got = unpack_shards(q.encode(codec, pack_shards(d)).result(10))
+        finally:
+            fault.disarm(rid)
+        np.testing.assert_array_equal(got, codec.encode(d))
+        assert q.stats()["salvaged_items"] == {"injected": 1}
+
+        # a flush the device refuses (a compiler error, say): salvaged,
+        # counted under its own reason, ERROR first then WARNING
+        monkeypatch.setenv("MINIO_TPU_DISPATCH_MODE", "device")
+
+        def refuse(*a, **kw):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+        monkeypatch.setattr(q, "_flush_device", refuse)
+        with caplog.at_level(logging.WARNING, logger="minio_tpu.dispatch"):
+            for _ in range(2):
+                got = unpack_shards(
+                    q.encode(codec, pack_shards(d)).result(10))
+                np.testing.assert_array_equal(got, codec.encode(d))
+        st = q.stats()
+        assert st["salvaged_items"] == {"injected": 1,
+                                        "device_flush_failed": 2}
+        recs = [r for r in caplog.records
+                if "device flush failed" in r.getMessage()]
+        assert [r.levelno for r in recs] == [logging.ERROR,
+                                             logging.WARNING]
+        assert "Mosaic failed to compile" in recs[0].getMessage()
+        assert st["probe"]["state"] in ("ok", "failed", "pending")
+    finally:
+        q.stop()
+
+
+def test_probe_failure_is_logged_and_counted(monkeypatch, caplog):
+    import logging
+
+    from minio_tpu.runtime import dispatch as dp
+
+    def boom():
+        raise RuntimeError("no device answered")
+    monkeypatch.setattr(dp.LinkProfile, "probe", staticmethod(boom))
+    q = DispatchQueue(max_batch=8, max_delay=0.001)
+    try:
+        with caplog.at_level(logging.ERROR, logger="minio_tpu.dispatch"):
+            q._kick_probe()
+            q._probe_thread.join(timeout=10)
+        pr = q.stats()["probe"]
+        # >= 1: the queue's own loop may have kicked a probe as well
+        assert pr["state"] == "failed" and pr["failures"] >= 1
+        assert any("link probe failed" in r.getMessage()
+                   and "no device answered" in (r.exc_text or "")
+                   + str(r.exc_info) for r in caplog.records)
+    finally:
+        q.stop()

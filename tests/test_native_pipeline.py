@@ -228,3 +228,49 @@ def test_get_block_pread_roundtrip_and_errors(tmp_path):
     assert code == -(10 + 1)
     for i in (0, 2, 3):
         os.close(fds[i])
+
+
+def test_build_key_follows_content_and_flags_not_mtimes(monkeypatch,
+                                                        tmp_path):
+    """The rebuild key is a hash of the five sources + the compiler
+    flags: a changed byte or flag changes it, a touched mtime does not."""
+    import shutil
+    src = tmp_path / "native"
+    src.mkdir()
+    for name in native._SOURCES:
+        shutil.copy(os.path.join(native._DIR, name), src / name)
+    monkeypatch.setattr(native, "_DIR", str(src))
+    key = native.build_key()
+    assert len(key) == 64
+    os.utime(src / "mur3.cpp", (1, 1))
+    assert native.build_key() == key
+    with open(src / "mur3.cpp", "ab") as f:
+        f.write(b"\n")
+    changed = native.build_key()
+    assert changed != key
+    monkeypatch.setattr(native, "BUILD_FLAGS",
+                        native.BUILD_FLAGS + ("-DX",))
+    assert native.build_key() not in (key, changed)
+
+
+def test_failed_build_is_one_error_with_the_compilers_words(
+        monkeypatch, tmp_path, caplog):
+    """One fixed command line; a refusal is an ERROR carrying g++'s own
+    output, available() stays False, and nothing half-built is left."""
+    import logging
+    src = tmp_path / "native"
+    src.mkdir()
+    for name in native._SOURCES:
+        (src / name).write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_DIR", str(src))
+    monkeypatch.setattr(native, "_BUILD", str(tmp_path / "_build"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_error", None)
+    with caplog.at_level(logging.ERROR, logger="minio_tpu.native"):
+        assert native.available() is False
+        assert native.available() is False  # cached: no second build
+    errs = [r for r in caplog.records if r.levelno == logging.ERROR]
+    assert len(errs) == 1
+    msg = errs[0].getMessage()
+    assert "g++ -O3 -mavx2 -shared -fPIC" in msg and "error" in msg
+    assert os.listdir(tmp_path / "_build") == []
