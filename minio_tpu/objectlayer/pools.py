@@ -73,6 +73,8 @@ class ServerPools(ObjectLayer):
             bucket, object, stream, size, opts)
 
     def _route(self, bucket, object, opts=None):
+        if len(self.pools) == 1:
+            return self.pools[0]
         idx = self._pool_with_object(bucket, object, opts)
         return self.pools[idx if idx is not None else 0]
 
@@ -97,13 +99,11 @@ class ServerPools(ObjectLayer):
         raise last or dt.ObjectNotFound(bucket, object)
 
     def delete_object(self, bucket, object, opts=None):
-        last = None
-        for p in self.pools:
-            try:
-                return p.delete_object(bucket, object, opts)
-            except (dt.ObjectNotFound, dt.VersionNotFound) as e:
-                last = e
-        raise last or dt.ObjectNotFound(bucket, object)
+        # a pool answers the delete of a name it does not hold with
+        # success (S3's delete is idempotent), so asking the pools in turn
+        # stopped at pool 0 and left the object in the pool that holds it
+        return self._route(bucket, object, opts).delete_object(
+            bucket, object, opts)
 
     def delete_objects(self, bucket, objects, opts=None):
         from .datatypes import DeletedObject

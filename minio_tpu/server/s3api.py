@@ -1524,7 +1524,8 @@ class _S3Handler(BaseHTTPRequestHandler):
         # parse url/headers so the surrounding observability plane (per-
         # API 503 counters, trace, audit) attributes this rejection like
         # any other response; the body stays unread — close instead of
-        # leaving the keep-alive connection mid-stream
+        # leaving the keep-alive connection mid-stream, and say so: a
+        # client that is not told sends its next request into the close
         self._parse()
         self.close_connection = True
         self._send(
@@ -1535,7 +1536,8 @@ class _S3Handler(BaseHTTPRequestHandler):
                 "your request rate", self.url_path,
                 request_id=getattr(self, "_request_id", ""),
                 host_id=host_id()),
-            headers={"Retry-After": adm.retry_after_header(grant)})
+            headers={"Retry-After": adm.retry_after_header(grant),
+                     "Connection": "close"})
         return False, None
 
     def _span_exempt(self, path: str, query: str = "") -> bool:

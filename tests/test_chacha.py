@@ -116,13 +116,11 @@ def _pin_shapes(shapes):
 
 
 def test_pallas_kernel_pinned_to_numpy_reference():
-    # interpret-mode kernel compiles are ~30 s per distinct shape: the
-    # tier-1 set stays small (64 B shared with test_workloads' routing
-    # test — one jit cache entry serves both)
+    # 64 B is shared with test_workloads' routing test: one jit cache
+    # entry serves both
     _pin_shapes(((1, 64), (3, 1024)))
 
 
-@pytest.mark.slow
 def test_pallas_kernel_pinned_wider_shapes():
     _pin_shapes(((2, 4096), (5, 128)))
 

@@ -215,14 +215,13 @@ def test_hedged_get_fires_deterministic(tmp_path, monkeypatch):
     assert fired() > before
 
 
-@pytest.mark.slow
 def test_hedged_get_p99_beats_straggler_3x(tmp_path, monkeypatch):
     """delay(200ms) on ONE data shard: 1 MiB GET p99 with hedging is
     >= 3x better than without (the unhedged path must wait out the
     injected delay every time; the hedged path pays ~threshold +
-    reconstruct). Timing-distribution statistics are load-sensitive on
-    a saturated CI host, so this runs outside tier-1 (`slow`); the
-    deterministic variant above keeps the tier-1 gate."""
+    reconstruct). Judged by its best and median samples (below), which
+    held 10 of 10 times beside a whole `-n 6` run (PR 24); the
+    deterministic variant above does not depend on timing at all."""
     ol = _layer(tmp_path)
     body = _body()
     ol.put_object("b", "o", io.BytesIO(body), len(body))

@@ -447,6 +447,30 @@ def test_server_pools_routing(tmp_path):
         pools.get_object_info("b", "o")
 
 
+def test_server_pools_delete_reaches_second_pool(tmp_path):
+    """A new object goes to the pool with most free space, and two pools
+    on one file system tie until a neighbour writes or removes a file
+    between the two readings: under load `test_server_pools_routing` met
+    its object in pool 1, where the delete was acknowledged by pool 0 (an
+    S3 delete of a missing name succeeds) and the object stayed. Here the
+    object is PUT into pool 1 on purpose."""
+    p0 = ErasureSets(mk_disks(tmp_path, 4, "p0d"), 1, 4, default_parity=2)
+    p1 = ErasureSets(mk_disks(tmp_path, 4, "p1d"), 1, 4, default_parity=2)
+    pools = ServerPools([p0, p1])
+    pools.make_bucket("b")
+    for name in ("o", "batch"):
+        p1.put_object("b", name, io.BytesIO(b"in pool 1"), 9)
+        assert pools.get_pool_idx("b", name) == 1
+    pools.delete_object("b", "o")
+    deleted, errs = pools.delete_objects("b", ["batch", "never-there"])
+    assert errs == [None, None] and len(deleted) == 2
+    for name in ("o", "batch"):
+        with pytest.raises(dt.ObjectNotFound):
+            pools.get_object_info("b", name)
+    # a name no pool holds is still deleted without error
+    pools.delete_object("b", "never-there")
+
+
 # --- ADVICE round-1 regressions ---------------------------------------------
 
 

@@ -229,9 +229,12 @@ def test_status_lifecycle_ship_and_delete(c, cluster):
     # the target copy is marked REPLICA so it can never re-replicate
     assert _internal(cluster, dst, "a/k1", i=1)[repl.META_REPLICA] == \
         repl.REPLICA
-    # lag was observed through the Window -> SLO probe shape
-    rep = _rs(cluster).lag_report()
-    assert rep["samples"] >= 1 and rep["ok"]
+    # lag was observed through the Window -> SLO probe shape. The worker
+    # flips the status first and records the lag after it, so COMPLETED
+    # above does not yet say that the sample is in
+    wait_until(lambda: _rs(cluster).lag_report()["samples"] >= 1,
+               msg="lag sample")
+    assert _rs(cluster).lag_report()["ok"]
     # delete propagates
     assert c.delete_object(src, "a/k1").status_code == 204
     wait_until(lambda: S3Client(cluster.urls[1], AK, SK).get_object(

@@ -86,7 +86,6 @@ def test_batched_blocks_pinned():
     assert np.array_equal(ref, dev)
 
 
-@pytest.mark.slow
 def test_random_property_pinned():
     """Wider randomized pin: mixed garbage/int cells, several programs."""
     def rand_cell(r):

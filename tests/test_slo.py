@@ -1,6 +1,8 @@
 """SLO plane (minio_tpu/obs/slo.py): objective seeding/override,
 window math and burn rates with faked clocks, breach verdicts, the
 metrics family, the s3api request feed, and the admin endpoints."""
+import time
+
 import pytest
 
 from minio_tpu.obs import slo
@@ -185,36 +187,55 @@ def srv(tmp_path_factory):
     server.shutdown()
 
 
+def _settled_report(adm, want, timeout=20.0):
+    """The SLO report once ``want(report)`` holds. The server feeds the
+    windows in its handler's ``finally``, after the response has gone
+    out, so a client that has its answer may still be ahead of the
+    count it caused."""
+    deadline = time.monotonic() + timeout
+    while True:
+        rep = adm.slo_report()
+        if want(rep) or time.monotonic() > deadline:
+            return rep
+        time.sleep(0.02)
+
+
+def _requests(rep, cls):
+    return rep["classes"][cls]["windows"]["5m"]["requests"]
+
+
 def test_request_feed_and_admin_endpoints(srv):
+    import os
+    import sys
+    import threading
+
     import requests
 
     from minio_tpu.madmin import AdminClient
     slo.reset()
     adm = AdminClient(srv.endpoint(), AK, SK)
-    import os
-    import sys
     sys.path.insert(0, os.path.dirname(__file__))
     from s3client import S3Client
     c = S3Client(srv.endpoint(), AK, SK)
     assert c.put_bucket("slob").status_code == 200
     assert c.put_object("slob", "k", b"x" * 128).status_code == 200
     assert c.get_object("slob", "k").status_code == 200
-    rep = adm.slo_report()
-    w = rep["classes"]["interactive"]["windows"]["5m"]
-    assert w["requests"] >= 2          # the object PUT + GET
-    assert rep["classes"]["control"]["windows"]["5m"]["requests"] >= 1
-    # exempt planes never feed the SLO windows
-    before = w["requests"] + \
-        rep["classes"]["control"]["windows"]["5m"]["requests"]
+    # the object PUT + GET, and the bucket PUT: all three have landed
+    rep = _settled_report(adm, lambda r: _requests(r, "interactive") >= 2
+                          and _requests(r, "control") >= 1)
+    assert _requests(rep, "interactive") == 2
+    assert _requests(rep, "control") == 1
+    # exempt planes never feed the SLO windows: the health probe and the
+    # admin reports themselves leave the counts where they stand, seen
+    # from behind one more counted request (handlers finish in order of
+    # arrival only by chance)
     requests.get(srv.endpoint() + "/minio/health/live", timeout=5)
-    rep2 = adm.slo_report()
-    after = rep2["classes"]["interactive"]["windows"]["5m"]["requests"] \
-        + rep2["classes"]["control"]["windows"]["5m"]["requests"]
-    assert after == before
+    assert c.get_object("slob", "k").status_code == 200
+    rep2 = _settled_report(adm, lambda r: _requests(r, "interactive") >= 3)
+    assert _requests(rep2, "interactive") == 3
+    assert _requests(rep2, "control") == 1
     # admission 503s burn availability: pinch the gate and burst
-    import threading
     srv.qos_admission.reconfigure(1)
-    import os
     os.environ["MINIO_TPU_QOS_MAX_WAIT_MS"] = "1"
     try:
         errs = [0]
@@ -234,13 +255,14 @@ def test_request_feed_and_admin_endpoints(srv):
         os.environ.pop("MINIO_TPU_QOS_MAX_WAIT_MS", None)
         srv.qos_admission.reconfigure(256)
     assert errs[0] > 0
-    w = adm.slo_report()["classes"]["interactive"]["windows"]["5m"]
-    assert w["errors"] >= errs[0]
+    w = _settled_report(adm, lambda r: _requests(r, "interactive") >= 9)[
+        "classes"]["interactive"]["windows"]["5m"]
+    assert w["requests"] == 9 and w["errors"] == errs[0]
     # the health snapshot embeds the same verdicts (single node)
     h = adm.cluster_health()
     assert h["cluster"]["nodes"] == 1
     assert h["nodes"][0]["slo"]["classes"]["interactive"][
-        "windows"]["5m"]["requests"] >= w["requests"] - 1
+        "windows"]["5m"]["requests"] == w["requests"]
     # burn-rate family live on the metrics endpoint
     text = requests.get(srv.endpoint() + "/minio/v2/metrics",
                         timeout=10).text

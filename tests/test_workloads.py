@@ -189,7 +189,6 @@ def test_device_equals_classic_quoted_and_crlf_blocks():
                                                             results)
 
 
-@pytest.mark.slow
 def test_device_equals_classic_property():
     rows = [b"id,v,w,s"]
     for i in range(4000):

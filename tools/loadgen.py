@@ -1063,11 +1063,17 @@ class LoadGen:
         scanner_impact: dict = {}
         if scanner_win.get("start_s") is not None:
             last_ts = max((r[0] for r in rows), default=0.0)
-            # clamp to the sampled range: a cycle that outlives the
-            # measured phase (CPU-starved behind interactive traffic —
-            # the desired priority) is judged on its in-run overlap
+            # clamp to the measured phase: a cycle that outlives it
+            # (CPU-starved behind interactive traffic — the desired
+            # priority) is judged on its in-run overlap. Not to the last
+            # row: the clients stop issuing at duration_s, and a LIST
+            # that began inside the cycle can end with it, many seconds
+            # later — a window stretched to that row divides the
+            # completions by seconds in which no client was running and
+            # reads as a collapsed rate in every run
             win = (scanner_win["start_s"],
-                   min(scanner_win.get("end_s", last_ts), last_ts))
+                   min(scanner_win.get("end_s", last_ts), last_ts,
+                       profile.duration_s))
             during = _op_rollup(rows, win)["classes"].get(
                 "interactive", {})
             # baseline = the STEADY half of the pre-scanner phase: the
