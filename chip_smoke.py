@@ -16,9 +16,11 @@ reason, the link profile, compile totals):
 
   a  as shipped (no routing env): PUT/GET/HEAD/LIST, two drives emptied,
      degraded GET, admin heal, two other drives emptied, GET. Correctness is
-     asserted; where ``auto`` sent the work is REPORTED.
-  b  device route (MINIO_TPU_PUT_PATH=dispatch, MINIO_TPU_DISPATCH_MODE=
-     device): same sequence; asserts the chip did the work — every block
+     asserted; where ``auto`` sent the work is REPORTED. The degraded GET's
+     blocks take the one-call native route (``native_degraded``), none the
+     queue.
+  b  device route (MINIO_TPU_PUT_PATH=dispatch, MINIO_TPU_GET_PATH=dispatch,
+     MINIO_TPU_DISPATCH_MODE=device): same sequence; asserts the chip did the work — every block
      through the queue, device flushes of PUT and rebuild ops, nothing on
      the CPU but counted QoS spills, zero salvages, probe ok, tail_block-
      only host hashing, ETags equal to leg a's — then reads everything
@@ -52,7 +54,8 @@ import numpy as np
 ROOT = os.path.dirname(os.path.abspath(__file__))
 AK, SK = "smokeadmin", "smokesecret1"
 MIB = 1 << 20
-ROUTE_ENV = ("MINIO_TPU_PUT_PATH", "MINIO_TPU_DISPATCH_MODE")
+ROUTE_ENV = ("MINIO_TPU_PUT_PATH", "MINIO_TPU_GET_PATH",
+             "MINIO_TPU_DISPATCH_MODE")
 
 
 def say(msg: str) -> None:
@@ -95,6 +98,15 @@ def observe() -> dict:
                     for op, r in devobs.roofline_snapshot().items()},
         "tl_dropped": tl.dropped_total(),
     }
+
+
+def get_block_routes() -> dict:
+    """minio_tpu_pipeline_get_blocks_total by route: which path each GET
+    block took (native_fd, native_degraded, native, fused, plain)."""
+    from minio_tpu.obs import metrics as mx
+    return {k.split('route="')[1].split('"')[0]: int(v)
+            for k, v in mx.counters_snapshot().items()
+            if k.startswith("minio_tpu_pipeline_get_blocks_total")}
 
 
 def _ddict(a: dict, b: dict) -> dict:
@@ -327,8 +339,10 @@ def drive_sequence(sv, bucket, bodies, etags, clients) -> dict:
     phase("get", get_all, sv, bucket, bodies, etags, clients, "healthy")
     phase("head_list", head_list, sv, bucket, bodies, etags, clients)
     lost1 = empty_drives(sv, bucket, (0, 1), bodies)
+    routes = get_block_routes()
     phase("get_degraded", get_all, sv, bucket, bodies, etags, clients,
           "degraded")
+    routes = _ddict(routes, get_block_routes())
     phase("heal", heal_bucket, sv, bucket, bodies)
     lost2 = empty_drives(sv, bucket, (2, 3), bodies)
     phase("get_degraded_healed", get_all, sv, bucket, bodies, etags,
@@ -340,6 +354,7 @@ def drive_sequence(sv, bucket, bodies, etags, clients) -> dict:
         "objects": len(bodies),
         "bytes": sum(len(b) for b in bodies.values()),
         "phase_seconds": phase_s,
+        "get_degraded_block_routes": routes,
         "blocks_written": blocks(bodies),
         "blocks_rebuilt_get": blocks(lost1["data"]) + blocks(lost2["data"]),
         "blocks_rebuilt_heal": blocks(lost1["data"] + lost1["parity_only"]),
@@ -361,10 +376,19 @@ def leg_a(sv, bodies, etags, clients) -> None:
     d = delta(before, observe())
     # correctness was asserted above; the routing is the finding
     leg_line("a", did, t0, d)
+    # as shipped a degraded GET of local shard files is one native call a
+    # block and never reaches the queue (leg b forces the queued route)
+    routes = did["get_degraded_block_routes"]
+    check(routes.get("native_degraded", 0) > 0 and not routes.get("fused"),
+          f"leg a: degraded GET blocks by route {routes}: none took "
+          "native_degraded, or some took fused")
 
 
 def leg_b(sv, bodies, etags, clients) -> None:
     os.environ["MINIO_TPU_PUT_PATH"] = "dispatch"
+    # the queued GET route, which sources without an fd (RPC) take by
+    # themselves: degraded blocks ride fused verify+rebuild on the chip
+    os.environ["MINIO_TPU_GET_PATH"] = "dispatch"
     os.environ["MINIO_TPU_DISPATCH_MODE"] = "device"
     try:
         # both snapshots sit INSIDE the env window (leg a's background

@@ -118,35 +118,40 @@ def _framed_writers(erasure: Erasure, writers: list):
     return chunk, native_algo_id(live[0].algo)
 
 
-def _native_get_eligible(erasure: Erasure, readers: list) -> bool:
-    """True when healthy reads can run the fused native verify+assemble
-    (mt_get_block): all k data-shard readers alive and HighwayHash-framed
-    with one chunk size dividing the shard."""
+def _native_get_eligible(erasure: Erasure, readers: list):
+    """(chunk, algo_id) when reads can run as ONE native GIL-released call
+    per block (mt_get_block / mt_get_block_pread, and
+    mt_get_block_pread_degraded when a data shard is missing): at least
+    k live readers, every live one a StreamingBitrotReader on one
+    native-id algorithm with one chunk size dividing the shard. None
+    otherwise. Which k of them serve a block is chosen per block from
+    reader liveness (erasure_decode.submit)."""
     if os.environ.get("MINIO_TPU_GET_PATH", "auto") == "dispatch":
-        return False
+        return None
     if _fault.armed("disk"):
         # chaos runs need the Python shard reads (where read_at faults
         # inject and hedging mitigates); the fused C pread would bypass
         # both
-        return False
+        return None
     from .bitrot import StreamingBitrotReader, native_algo_id
-    k = erasure.data_blocks
-    if len(readers) < k:
-        return False
-    data = readers[:k]
+    live = [r for r in readers if r is not None]
+    if len(live) < erasure.data_blocks:
+        return None
     if not all(isinstance(r, StreamingBitrotReader)
-               and native_algo_id(r.algo) is not None for r in data):
-        return False
-    if len({r.algo for r in data}) != 1:
-        return False
-    chunks = {r.shard_size for r in data}
+               and native_algo_id(r.algo) is not None for r in live):
+        return None
+    if len({r.algo for r in live}) != 1:
+        return None
+    chunks = {r.shard_size for r in live}
     if len(chunks) != 1:
-        return False
+        return None
     (chunk,) = chunks
     if erasure.shard_size() % chunk:
-        return False
+        return None
     from .. import native
-    return native.available()
+    if not native.available():
+        return None
+    return chunk, native_algo_id(live[0].algo)
 
 
 @dataclass
@@ -930,21 +935,34 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
     if native_get:
         from .. import native
         from ..runtime.bufpool import global_pool
-        from .bitrot import HIGHWAY_KEY, native_algo_id
-        fuse_chunk = readers[0].shard_size
-        get_algo_id = native_algo_id(readers[0].algo)
+        from .bitrot import HIGHWAY_KEY
+        fuse_chunk, get_algo_id = native_get
         pool = global_pool()
+        #: rebuild rows of the degraded native call, per chosen sources
+        #: (which fix the missing data shards; the matrix inversion
+        #: behind them is cached on the codec)
+        rows_cache: dict = {}
 
-    def pread_block(fds, offs, shard_len, out=None):
+    def pread_block(fds, offs, shard_len, out=None, rebuild=None):
         """One native call: pread k framed spans + verify + assemble.
         ``out`` may be a reserved view into the sink's final buffer
-        (zero-copy scatter); otherwise a pooled buffer is used."""
+        (zero-copy scatter); otherwise a pooled buffer is used.
+        ``rebuild`` = (present, missing, rows) on a degraded block: the
+        spans are those of the k chosen sources ``present`` and the
+        ``missing`` data shards are rebuilt from them by ``rows`` in the
+        same call."""
         scratch = pool.get(k * native.framed_len(shard_len, fuse_chunk))
+        if out is None:
+            out = pool.get(k * shard_len)
         try:
-            return native.get_block_pread(
-                fds, offs, k, shard_len, fuse_chunk, HIGHWAY_KEY,
-                get_algo_id, scratch=scratch,
-                out=out if out is not None else pool.get(k * shard_len))
+            if rebuild is None:
+                return native.get_block_pread(
+                    fds, offs, k, shard_len, fuse_chunk, HIGHWAY_KEY,
+                    get_algo_id, scratch=scratch, out=out)
+            present, missing, rows = rebuild
+            return native.get_block_pread_degraded(
+                fds, offs, present, k, shard_len, fuse_chunk, HIGHWAY_KEY,
+                rows, missing, get_algo_id, scratch=scratch, out=out)
         finally:
             pool.put(scratch)
 
@@ -999,39 +1017,57 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
             dest = reserve(blen)
         shard_len = ceil_div(block_data_len, k)
         shard_offset = b * erasure.shard_size()
-        # Healthy stream + native library -> fused verify+assemble: one
-        # GIL-releasing call checks every chunk digest and scatters
-        # payloads (replaces the numpy per-chunk verify). When every
-        # data-shard source is a local file, the k span reads fuse into
-        # the same call (pread in C, mt_get_block_pread) — zero Python
-        # reads per block; RPC sources keep the pooled-read form.
-        if native_get and all(preader.readers[i] is not None
-                              for i in range(k)):
+        # Native library + bitrot-framed sources -> ONE GIL-releasing
+        # call a block. Healthy (the k data shards alive): verify every
+        # chunk digest and scatter payloads (replaces the numpy
+        # per-chunk verify); when every source is a local file the k
+        # span reads fuse into the same call (pread in C,
+        # mt_get_block_pread) — zero Python reads per block; RPC sources
+        # keep the pooled-read form. Degraded (a data shard missing) and
+        # every chosen source a local file: the same call with a GF(256)
+        # rebuild step (mt_get_block_pread_degraded). The sources are
+        # re-chosen per block — the first k live readers, data before
+        # parity (read_block's preference) — so one lost mid-object is
+        # replaced by the next live one.
+        present = tuple(i for i, r in enumerate(preader.readers)
+                        if r is not None)[:k]
+        if native_get and len(present) == k:
+            healthy = present[-1] == k - 1
             # a full aligned block whose assembled length equals the
             # reserved span can scatter DIRECTLY into the sink buffer
             out_dest = dest if dest is not None and boff == 0 and \
                 blen == k * shard_len and \
                 dest.flags["C_CONTIGUOUS"] else None
-            if out_dest is not None:
+            try:
+                fds = [preader.readers[i].fileno() for i in present]
+                offs = [preader.readers[i].phys_offset(shard_offset)
+                        for i in present]
+            except (AttributeError, OSError):
+                fds = None
+            if out_dest is not None and (healthy or fds is not None):
                 # block assembles straight into the caller's final buffer
                 _mx.inc("minio_tpu_pipeline_zero_copy_bytes_total", blen,
                         path="get")
-            try:
-                fds = [preader.readers[i].fileno() for i in range(k)]
-                offs = [preader.readers[i].phys_offset(shard_offset)
-                        for i in range(k)]
-            except (AttributeError, OSError):
-                fds = None
             if fds is not None:
+                rebuild = None
+                if not healthy:
+                    missing = tuple(i for i in range(k) if i not in present)
+                    rows = rows_cache.get(present)
+                    if rows is None:
+                        rows = rows_cache[present] = \
+                            erasure.codec.rebuild_rows(present, missing)
+                    rebuild = (present, missing, rows)
                 _mx.inc("minio_tpu_pipeline_get_blocks_total",
-                        route="native_fd")
+                        route="native_fd" if healthy else "native_degraded")
                 # pure CPU kernel work — records no spans
                 fut = encode_pool().submit(pread_block, fds, offs,  # graftlint: disable=GL005
-                                           shard_len, out_dest)
+                                           shard_len, out_dest, rebuild)
                 return ["native", fut, b, block_data_len, boff, blen,
-                        dest]
-            with _stages.timed(stc, "shard_read"):
-                framed = read_framed_k(shard_offset, shard_len)
+                        dest, present]
+            framed = None
+            if healthy:
+                with _stages.timed(stc, "shard_read"):
+                    framed = read_framed_k(shard_offset, shard_len)
             if framed is not None:
                 _mx.inc("minio_tpu_pipeline_get_blocks_total",
                         route="native")
@@ -1041,7 +1077,7 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
                     out=out_dest if out_dest is not None
                     else pool.get(k * shard_len))
                 return ["native", fut, b, block_data_len, boff, blen,
-                        dest]
+                        dest, present]
         # Degraded data read + device-hash-capable sources -> fused
         # verify+reconstruct: one launch hashes every source shard AND
         # rebuilds the missing ones (BASELINE config 4). Healthy streams
@@ -1058,12 +1094,13 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
             fut = erasure.decode_data_blocks_verified_async(
                 shards, preader.last_digests, preader.fuse_chunk(),
                 preader.fuse_algo())
-            return ["fused", fut, b, block_data_len, boff, blen, dest]
+            return ["fused", fut, b, block_data_len, boff, blen, dest,
+                    None]
         _mx.inc("minio_tpu_pipeline_get_blocks_total", route="plain")
         with _stages.timed(stc, "shard_read"):
             shards = preader.read_block(shard_offset, shard_len)
         return ["plain", erasure.decode_data_blocks_async(shards), b,
-                block_data_len, boff, blen, dest]
+                block_data_len, boff, blen, dest, None]
 
     def recover_block(corrupt: tuple[int, ...], b: int,
                       block_data_len: int) -> list:
@@ -1106,7 +1143,7 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
         return blocks
 
     def emit(entry):
-        kind, fut, b, block_data_len, boff, blen, dest = entry
+        kind, fut, b, block_data_len, boff, blen, dest, present = entry
         with _stages.timed(stc, "decode"):
             res = _compl.await_result(fut, op="decode")
         if kind == "native":
@@ -1133,16 +1170,18 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
                 return
             if out_arr is not dest:
                 pool.put(out_arr)
+            # the native calls name a source by its position among the
+            # block's chosen k (the shard index itself on healthy reads)
             if bad <= -10:
-                # a fused pread failed on shard -(bad+10): mark the
-                # source dead (a vote, like any disk read error) and
-                # redo via replacement reads
-                i = -(bad + 10)
+                # a fused pread failed on source -(bad+10): mark it
+                # dead (a vote, like any disk read error) and redo via
+                # replacement reads
+                i = present[-(bad + 10)]
                 preader.errs[i] = errors.FaultyDisk("pread failed")
                 preader.readers[i] = None
                 blocks = _redo_block(b, block_data_len)
             else:
-                blocks = recover_block((bad,), b, block_data_len)
+                blocks = recover_block((present[bad],), b, block_data_len)
         elif kind == "fused":
             blocks, corrupt = res
             if corrupt:
@@ -1157,14 +1196,16 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
                 dest[:] = block[boff: boff + blen]
         stats.bytes_written += blen
 
-    win = native_window_for(erasure.block_size) if native_get \
-        else ENCODE_WINDOW
+    # native entries need only pipeline overlap; queued rebuilds want a
+    # window deep enough to fill a dispatch batch
+    native_win = native_window_for(erasure.block_size)
     for b in range(start_block, end_block + 1):
         entry = submit(b)
         if entry is None:
             break
         window.append(entry)
-        if len(window) >= win:
+        if len(window) >= (native_win if entry[0] == "native"
+                           else ENCODE_WINDOW):
             emit(window.popleft())
     while window:
         emit(window.popleft())

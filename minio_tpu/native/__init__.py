@@ -8,8 +8,9 @@ gf256_simd.cpp + highwayhash.cpp) provides:
   bench.py's vs_baseline),
 - AVX2 HighwayHash-256 (bitrot digests),
 - the fused per-block data-plane calls ``mt_put_block`` / ``mt_get_block``
-  (split+encode+hash+frame, verify+assemble) that carry the end-to-end
-  object path on the CPU route.
+  (split+encode+hash+frame, verify+assemble) and
+  ``mt_get_block_pread_degraded`` (pread+verify+rebuild+assemble) that
+  carry the end-to-end object path on the CPU route.
 
 All entry points release the GIL (plain ctypes CDLL calls), so concurrent
 requests scale across cores where the host has them.
@@ -182,6 +183,13 @@ def _load_native_locked() -> ctypes.CDLL:
             ctypes.c_int, ctypes.c_long, ctypes.c_long, ctypes.c_char_p,
             c_u8p, c_u8p, ctypes.c_int]
         lib.mt_get_block_pread.restype = ctypes.c_long
+        lib.mt_get_block_pread_degraded.argtypes = [
+            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_long),
+            ctypes.POINTER(ctypes.c_int), ctypes.c_int, ctypes.c_long,
+            ctypes.c_long, ctypes.c_char_p, ctypes.c_char_p,
+            ctypes.POINTER(ctypes.c_int), ctypes.c_int, c_u8p, c_u8p,
+            ctypes.c_int]
+        lib.mt_get_block_pread_degraded.restype = ctypes.c_long
         lib.mur3x256.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
                                  ctypes.c_long, ctypes.c_char_p]
         lib.mur3x256.restype = None
@@ -339,6 +347,23 @@ def get_block(framed: list, k: int, plen: int, chunk: int, key: bytes,
     return out, bad
 
 
+def _pread_buffers(k: int, plen: int, chunk: int,
+                   scratch: np.ndarray | None, out: np.ndarray | None
+                   ) -> tuple[np.ndarray, np.ndarray]:
+    """The pread calls' (scratch, out): k framed spans and the k*plen
+    assembled block; a buffer the caller passes must have that size."""
+    fl = framed_len(plen, chunk)
+    if scratch is None:
+        scratch = np.empty(k * fl, dtype=np.uint8)
+    elif scratch.nbytes != k * fl:
+        raise ValueError("pread block: scratch size mismatch")
+    if out is None:
+        out = np.empty(k * plen, dtype=np.uint8)
+    elif out.nbytes != k * plen:
+        raise ValueError("pread block: out size mismatch")
+    return scratch, out
+
+
 def get_block_pread(fds: list[int], offsets: list[int], k: int, plen: int,
                     chunk: int, key: bytes, algo: int = ALGO_HIGHWAY,
                     scratch: np.ndarray | None = None,
@@ -354,20 +379,59 @@ def get_block_pread(fds: list[int], offsets: list[int], k: int, plen: int,
         raise ValueError(f"unsupported geometry k={k} chunk={chunk}")
     if len(fds) != k or len(offsets) != k:
         raise ValueError("get_block_pread: need one fd+offset per shard")
-    fl = framed_len(plen, chunk)
-    if scratch is None:
-        scratch = np.empty(k * fl, dtype=np.uint8)
-    elif scratch.nbytes != k * fl:
-        raise ValueError("get_block_pread: scratch size mismatch")
-    if out is None:
-        out = np.empty(k * plen, dtype=np.uint8)
-    elif out.nbytes != k * plen:
-        raise ValueError("get_block_pread: out size mismatch")
+    scratch, out = _pread_buffers(k, plen, chunk, scratch, out)
     cfds = (ctypes.c_int * k)(*fds)
     coffs = (ctypes.c_long * k)(*offsets)
     code = lib.mt_get_block_pread(
         cfds, coffs, k, plen, chunk, key, scratch.ctypes.data_as(_u8p),
         out.ctypes.data_as(_u8p), algo)
+    return out, int(code)
+
+
+def get_block_pread_degraded(fds: list[int], offsets: list[int],
+                             src_idx: tuple[int, ...], k: int, plen: int,
+                             chunk: int, key: bytes, rows: np.ndarray,
+                             missing: tuple[int, ...],
+                             algo: int = ALGO_HIGHWAY,
+                             scratch: np.ndarray | None = None,
+                             out: np.ndarray | None = None
+                             ) -> tuple[np.ndarray, int]:
+    """Fused pread+verify+rebuild+assemble for one degraded-read block:
+    source j (global shard ``src_idx[j]``, ascending, k of them) is read
+    from fds[j] at offsets[j]; every source chunk digest is verified;
+    the missing data shards ``missing`` are rebuilt with ``rows``
+    (uint8 [len(missing), k] over the chosen sources). Returns (block
+    uint8 [k*plen], code) with code -1 ok, >=0 the POSITION in
+    ``src_idx`` of the first corrupt source, <=-10 a failed read on
+    source -(code+10). ``scratch``/``out`` recycle through the bufpool
+    exactly as in get_block_pread."""
+    lib = load_native()
+    if k <= 0 or k > 256 or chunk <= 0:
+        raise ValueError(f"unsupported geometry k={k} chunk={chunk}")
+    if len(fds) != k or len(offsets) != k or len(src_idx) != k:
+        raise ValueError(
+            "get_block_pread_degraded: need one fd+offset+index per source")
+    # every data shard is written exactly once: copied from a source or
+    # rebuilt (the native call indexes `out` by these without a check)
+    data_src = [i for i in src_idx if i < k]
+    if sorted(data_src + list(missing)) != list(range(k)) or \
+            list(src_idx) != sorted(set(src_idx)) or \
+            not 0 <= src_idx[0] <= src_idx[-1] < 256:
+        raise ValueError("get_block_pread_degraded: sources (ascending, "
+                         "distinct) and missing must cover the data "
+                         "shards exactly")
+    rows = np.ascontiguousarray(rows, dtype=np.uint8)
+    if rows.shape != (len(missing), k):
+        raise ValueError("get_block_pread_degraded: rows shape mismatch")
+    scratch, out = _pread_buffers(k, plen, chunk, scratch, out)
+    cfds = (ctypes.c_int * k)(*fds)
+    coffs = (ctypes.c_long * k)(*offsets)
+    cidx = (ctypes.c_int * k)(*src_idx)
+    cmiss = (ctypes.c_int * max(1, len(missing)))(*missing)
+    code = lib.mt_get_block_pread_degraded(
+        cfds, coffs, cidx, k, plen, chunk, key,
+        rows.ctypes.data_as(ctypes.c_char_p), cmiss, len(missing),
+        scratch.ctypes.data_as(_u8p), out.ctypes.data_as(_u8p), algo)
     return out, int(code)
 
 

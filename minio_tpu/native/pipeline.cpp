@@ -17,7 +17,10 @@
 // mt_get_block is the read-side inverse: verify every chunk digest of the k
 // data shards and scatter the payloads into the caller's contiguous block
 // (replaces cmd/bitrot-streaming.go:115-151 verify + erasure-utils.go
-// writeDataBlocks for the healthy-read path).
+// writeDataBlocks for the healthy-read path). mt_get_block_pread_degraded
+// is the same shape with a rebuild step for reads that miss a data shard:
+// verify the k chosen sources, copy the data shards among them and
+// GF(256)-accumulate the missing ones, chunk by chunk.
 //
 // This TU includes the standalone kernels so one libnative.so serves the
 // gf256, highwayhash, and pipeline entry points.
@@ -242,19 +245,12 @@ int mt_get_block(const uint8_t* const* framed, int k, long plen, long chunk,
   return -1;
 }
 
-// mt_get_block + the shard-file reads in the same GIL-released call:
-// pread each of the k framed spans (offsets[i] bytes into fds[i]) into
-// `scratch` (k consecutive spans of mt_framed_len(plen, chunk) bytes),
-// then verify+assemble into `out`. Returns -1 on success, the index of
-// the first corrupt shard, or -(10+i) when shard i's read failed/came
-// up short. Replaces k Python-side reads + buffer handoffs per block
-// with zero Python work (the read-side mirror of mt_put_block_fds).
-long mt_get_block_pread(const int* fds, const long* offsets, int k,
-                        long plen, long chunk, const uint64_t key[4],
-                        uint8_t* scratch, uint8_t* out, int algo) {
-  if (k <= 0 || k > 256 || chunk <= 0) return -2;
-  const long framed_len = mt_framed_len(plen, chunk);
-  const uint8_t* ptrs[256];
+namespace {
+// pread k framed spans (offsets[i] bytes into fds[i]) into k consecutive
+// framed_len-byte slots of `scratch`. Returns -1, or -(10+i) when span i's
+// read failed or came up short.
+inline long pread_spans(const int* fds, const long* offsets, int k,
+                        long framed_len, uint8_t* scratch) {
   for (int i = 0; i < k; i++) {
     uint8_t* dst = scratch + (size_t)i * framed_len;
     long done = 0;
@@ -268,9 +264,77 @@ long mt_get_block_pread(const int* fds, const long* offsets, int k,
       if (r == 0) return -(10 + i);  // short file
       done += r;
     }
-    ptrs[i] = dst;
   }
+  return -1;
+}
+}  // namespace
+
+// mt_get_block + the shard-file reads in the same GIL-released call:
+// pread each of the k framed spans (offsets[i] bytes into fds[i]) into
+// `scratch` (k consecutive spans of mt_framed_len(plen, chunk) bytes),
+// then verify+assemble into `out`. Returns -1 on success, the index of
+// the first corrupt shard, or -(10+i) when shard i's read failed/came
+// up short. Replaces k Python-side reads + buffer handoffs per block
+// with zero Python work (the read-side mirror of mt_put_block_fds).
+long mt_get_block_pread(const int* fds, const long* offsets, int k,
+                        long plen, long chunk, const uint64_t key[4],
+                        uint8_t* scratch, uint8_t* out, int algo) {
+  if (k <= 0 || k > 256 || chunk <= 0) return -2;
+  const long framed_len = mt_framed_len(plen, chunk);
+  const long rc = pread_spans(fds, offsets, k, framed_len, scratch);
+  if (rc != -1) return rc;
+  const uint8_t* ptrs[256];
+  for (int i = 0; i < k; i++) ptrs[i] = scratch + (size_t)i * framed_len;
   return mt_get_block(ptrs, k, plen, chunk, key, out, algo);
+}
+
+// One degraded-read block in the same shape: pread the k chosen framed
+// spans (source j is global shard src_idx[j], ascending), verify every
+// source chunk's digest, copy each source that is a data shard to
+// out[src_idx[j]*plen ...] and GF(256)-accumulate each missing data shard
+// missing[t] = sum_j rows[t*k + j] * source_j straight into
+// out[missing[t]*plen ...] — chunk-major, so a chunk is hashed, copied and
+// multiplied while still cache-resident. `rows` is the [n_missing, k]
+// rebuild matrix over the chosen sources. Returns -1 on success, the
+// POSITION in src_idx of the first corrupt source, or -(10+j) when source
+// j's read failed/came up short. Replaces the per-block Python read +
+// dispatch-queue rebuild of a degraded GET whose sources are local files.
+long mt_get_block_pread_degraded(const int* fds, const long* offsets,
+                                 const int* src_idx, int k, long plen,
+                                 long chunk, const uint64_t key[4],
+                                 const uint8_t* rows, const int* missing,
+                                 int n_missing, uint8_t* scratch,
+                                 uint8_t* out, int algo) {
+  if (k <= 0 || k > 256 || chunk <= 0 || n_missing < 0 || n_missing > k)
+    return -2;
+  const long framed_len = mt_framed_len(plen, chunk);
+  const long rc = pread_spans(fds, offsets, k, framed_len, scratch);
+  if (rc != -1) return rc;
+  const long stride = 32 + chunk;
+  const uint8_t* hp[256];
+  long hl[256];
+  uint8_t digs[256 * 32];
+  long ci = 0;
+  for (long c0 = 0; c0 < plen; c0 += chunk, ci++) {
+    const long clen = (plen - c0 < chunk) ? plen - c0 : chunk;
+    for (int j = 0; j < k; j++) {
+      hp[j] = scratch + (size_t)j * framed_len + ci * stride + 32;
+      hl[j] = clen;
+    }
+    hash_many(algo, key, hp, hl, k, digs);
+    for (int j = 0; j < k; j++)
+      if (std::memcmp(digs + j * 32, hp[j] - 32, 32) != 0) return j;
+    for (int j = 0; j < k; j++)
+      if (src_idx[j] < k)
+        std::memcpy(out + (size_t)src_idx[j] * plen + c0, hp[j],
+                    (size_t)clen);
+    for (int t = 0; t < n_missing; t++) {
+      uint8_t* dst = out + (size_t)missing[t] * plen + c0;
+      for (int j = 0; j < k; j++)
+        gf_accum(rows[t * k + j], hp[j], dst, clen, j == 0);
+    }
+  }
+  return -1;
 }
 
 // Verify-only over one framed span (deep scan / VerifyFile): returns -1 ok,

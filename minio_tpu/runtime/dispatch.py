@@ -62,7 +62,10 @@ ride a SECOND, latency-tuned lane:
   backend, so the small HBM round trips don't double-allocate.
 
 Bulk PUT/encode and the device workloads keep the coalescing lane
-untouched; healthy GETs never reach the queue at all (CPU-native path).
+untouched; healthy GETs never reach the queue at all (CPU-native path),
+nor do degraded GETs whose chosen sources are all local shard files (one
+native pread+verify+rebuild call a block, erasure/streaming.py): the
+lane's callers are heal and degraded reads from sources without an fd.
 
 Enable/disable batching entirely with MINIO_TPU_DISPATCH=1/0 (default: on).
 """

@@ -311,6 +311,139 @@ def test_fused_degraded_decode():
     assert out.getvalue() == data
 
 
+def _get_blocks(route):
+    from minio_tpu.obs import metrics as mx
+    return mx.counters_snapshot().get(
+        'minio_tpu_pipeline_get_blocks_total{route="%s"}' % route, 0)
+
+
+class _FdThenGone:
+    """A local shard file whose fd is lost after ``good`` fileno() calls:
+    the native pread then fails, the Python read_at keeps working."""
+
+    def __init__(self, src, good):
+        self.src, self.left = src, good
+
+    def read_at(self, offset, length):
+        return self.src.read_at(offset, length)
+
+    def fileno(self):
+        self.left -= 1
+        return self.src.fileno() if self.left >= 0 else -1
+
+
+def file_readers(tmp_path, er, sinks, size, dead=(), corrupt=(),
+                 fd_gone=None):
+    """hh_readers over real local files (_FileReadAt, as XLStorage
+    hands them out): every live reader answers fileno()."""
+    from minio_tpu.storage.xlstorage import _FileReadAt
+    sfs = er.shard_file_size(size)
+    out = []
+    for i, s in enumerate(sinks):
+        if i in dead:
+            out.append(None)
+            continue
+        blob = bytearray(s.getvalue())
+        if i in corrupt:
+            blob[len(blob) // 2] ^= 0xFF
+        path = tmp_path / f"shard{i}"
+        path.write_bytes(bytes(blob))
+        src = _FileReadAt(str(path))
+        if fd_gone is not None and i == fd_gone[0]:
+            src = _FdThenGone(src, fd_gone[1])
+        out.append(new_bitrot_reader(src, HH, sfs, er.shard_size()))
+    return out
+
+
+@pytest.mark.parametrize("read", ["whole", "ranged", "prealloc",
+                                  "prealloc_ranged"])
+def test_native_degraded_decode(tmp_path, read):
+    """A degraded GET over local shard files is one native call a block
+    (route native_degraded), never the dispatch queue, and exact for
+    whole, ranged (mid-block start and end) and zero-copy sink reads."""
+    from minio_tpu.erasure.streaming import PreallocSink
+    data = rng_bytes((3 << 20) + 777, seed=21)
+    er, sinks = encode_hh(4, 2, 1 << 20, data)
+    off, n = (0, len(data)) if read in ("whole", "prealloc") \
+        else ((1 << 20) + 4321, (1 << 20) + 99)
+    out = PreallocSink(n) if read.startswith("prealloc") else io.BytesIO()
+    nd, fu = _get_blocks("native_degraded"), _get_blocks("fused")
+    stats = erasure_decode(
+        er, out, file_readers(tmp_path, er, sinks, len(data), dead=(0, 5)),
+        off, n, len(data))
+    assert bytes(out.getvalue()) == data[off: off + n]
+    assert stats.bytes_written == n
+    want = 4 if off == 0 else 2
+    assert _get_blocks("native_degraded") - nd == want
+    assert _get_blocks("fused") == fu
+    # the missing readers keep their votes for heal-on-read
+    assert isinstance(stats.errs[0], errors.DiskNotFound)
+    assert isinstance(stats.errs[5], errors.DiskNotFound)
+
+
+def test_native_degraded_decode_corrupt_source(tmp_path):
+    data = rng_bytes(3 << 20, seed=22)
+    er, sinks = encode_hh(4, 2, 1 << 20, data)
+    readers = file_readers(tmp_path, er, sinks, len(data), dead=(0,),
+                           corrupt=(4,))
+    nd = _get_blocks("native_degraded")
+    out = io.BytesIO()
+    stats = erasure_decode(er, out, readers, 0, len(data), len(data))
+    assert out.getvalue() == data
+    assert _get_blocks("native_degraded") > nd
+    # the corrupt PARITY source (position 3 of the chosen 1,2,3,4) must
+    # carry the FileCorrupt vote for heal-on-read, under its own index
+    assert isinstance(stats.errs[4], errors.FileCorrupt)
+    assert isinstance(stats.errs[0], errors.DiskNotFound)
+    assert [e for i, e in enumerate(stats.errs) if i not in (0, 4)] == \
+        [None] * 4
+
+
+def test_native_degraded_decode_source_lost_mid_object(tmp_path):
+    """A chosen source whose fd goes away after the first block: its
+    pread fails, it gets a FaultyDisk vote, the block is redone and the
+    next live reader replaces it for the rest of the object."""
+    data = rng_bytes((6 << 20) + 5, seed=23)
+    er, sinks = encode_hh(4, 2, 1 << 20, data)
+    readers = file_readers(tmp_path, er, sinks, len(data), dead=(1,),
+                           fd_gone=(2, 1))
+    nd = _get_blocks("native_degraded")
+    out = io.BytesIO()
+    stats = erasure_decode(er, out, readers, 0, len(data), len(data))
+    assert out.getvalue() == data
+    assert isinstance(stats.errs[2], errors.FaultyDisk)
+    # block 0 with source 2, block 1 lost it, later blocks use shard 5
+    assert _get_blocks("native_degraded") - nd >= 6
+
+
+@pytest.mark.parametrize("why", ["dispatch_env", "fault_armed",
+                                 "buffer_source"])
+def test_degraded_decode_keeps_fused_route(tmp_path, monkeypatch, why):
+    """What the native degraded call must not take: a forced dispatch
+    GET, a chaos run, sources without an fd. These stay on the fused
+    device verify+reconstruct."""
+    from minio_tpu import fault
+    data = rng_bytes(2 << 20, seed=24)
+    er, sinks = encode_hh(4, 2, 1 << 20, data)
+    if why == "buffer_source":
+        readers = hh_readers(er, sinks, len(data), dead=(0,))
+    else:
+        readers = file_readers(tmp_path, er, sinks, len(data), dead=(0,))
+    if why == "dispatch_env":
+        monkeypatch.setenv("MINIO_TPU_GET_PATH", "dispatch")
+    if why == "fault_armed":
+        fault.arm("disk:no-such-endpoint:read_at:delay(1)")
+    nd, fu = _get_blocks("native_degraded"), _get_blocks("fused")
+    out = io.BytesIO()
+    try:
+        erasure_decode(er, out, readers, 0, len(data), len(data))
+    finally:
+        fault.clear()
+    assert out.getvalue() == data
+    assert _get_blocks("native_degraded") == nd
+    assert _get_blocks("fused") - fu == 2
+
+
 def test_fused_decode_detects_corruption_and_retries():
     data = rng_bytes(2 << 20, seed=12)
     er, sinks = encode_hh(4, 2, 1 << 20, data)

@@ -102,6 +102,32 @@ def test_native_get_detects_bitrot(ol):
             fh.write(orig)
 
 
+def test_degraded_get_is_native_and_still_heals_on_read(tmp_path):
+    """A GET with a data shard's drive emptied: exact bytes through the
+    one-call native degraded route, and on_partial (heal-on-read) still
+    hears of the object."""
+    import shutil
+    from minio_tpu.objectlayer.metadata import hash_order
+    from minio_tpu.obs import metrics as mx
+    ol = _mk(str(tmp_path), n=12, parity=4)
+    body = np.random.default_rng(5).integers(
+        0, 256, (9 << 20) + 321, dtype=np.uint8).tobytes()
+    ol.put_object("b", "o", io.BytesIO(body), len(body))
+    # the drive holding data shard 1 loses the object
+    drive = hash_order("b/o", 12).index(1)
+    shutil.rmtree(os.path.join(str(tmp_path), f"d{drive}", "b", "o"))
+    heard = []
+    ol.on_partial = lambda *a, **kw: heard.append((a, kw))
+    key = 'minio_tpu_pipeline_get_blocks_total{route="%s"}'
+    before = mx.counters_snapshot()
+    assert ol.get_object_bytes("b", "o") == body
+    after = mx.counters_snapshot()
+    assert after.get(key % "native_degraded", 0) - \
+        before.get(key % "native_degraded", 0) == 3
+    assert after.get(key % "fused", 0) == before.get(key % "fused", 0)
+    assert [a[:2] for a, _ in heard] == [("b", "o")]
+
+
 def test_put_block_fds_roundtrip(tmp_path):
     """put_block_fds writes the same framed bytes mt_put_block produces,
     honours fd=-1 skips, and reports per-fd errors without raising."""
@@ -274,3 +300,80 @@ def test_failed_build_is_one_error_with_the_compilers_words(
     msg = errs[0].getMessage()
     assert "g++ -O3 -mavx2 -shared -fPIC" in msg and "error" in msg
     assert os.listdir(tmp_path / "_build") == []
+
+
+_DEGRADED_CHUNK = 1024
+#: payload bytes a shard: whole chunks / a short last chunk / a length
+#: that is no multiple of 32 (gf_accum's scalar tail)
+_DEGRADED_PLEN = {"full": 4 * _DEGRADED_CHUNK,
+                  "tail": 4 * _DEGRADED_CHUNK + 512,
+                  "odd": 3 * _DEGRADED_CHUNK + 37}
+_DEGRADED_CASES = [(k, m, lost, kind)
+                   for k, m in ((4, 2), (8, 4), (16, 4))
+                   for lost in range(1, m + 1)
+                   for kind in _DEGRADED_PLEN]
+
+
+@pytest.mark.parametrize("k,m,lost,kind", _DEGRADED_CASES)
+def test_get_block_pread_degraded_matches_decode(tmp_path, k, m, lost, kind):
+    """mt_get_block_pread_degraded against Erasure.decode_data_blocks over
+    real shard files: exact bytes with data and parity shards lost; a
+    flipped byte names its source's position; a short file is -(10+i)."""
+    from minio_tpu.erasure import Erasure
+    from minio_tpu.erasure.bitrot import HIGHWAY_KEY
+    from minio_tpu.ops import gf256
+    chunk, plen = _DEGRADED_CHUNK, _DEGRADED_PLEN[kind]
+    rng = np.random.default_rng(1000 * k + 10 * lost + len(kind))
+    data = rng.integers(0, 256, k * plen, dtype=np.uint8).tobytes()
+    framed = native.put_block(data, len(data), gf256.build_matrix(k, m)[k:],
+                              k, m, plen, chunk, HIGHWAY_KEY)
+    fl = native.framed_len(plen, chunk)
+    paths = []
+    for i in range(k + m):
+        paths.append(os.path.join(tmp_path, f"s{i}"))
+        with open(paths[i], "wb") as f:
+            f.write(framed[i * fl:(i + 1) * fl].tobytes())
+    # data and parity mixed: the larger half of the loss falls on data
+    n_data = (lost + 1) // 2
+    gone = set(rng.choice(k, n_data, replace=False).tolist()) | \
+        set((k + rng.choice(m, lost - n_data, replace=False)).tolist())
+    present = tuple(i for i in range(k + m) if i not in gone)[:k]
+    missing = tuple(i for i in range(k) if i in gone)
+    er = Erasure(k, m, k * plen)
+    rows = er.codec.rebuild_rows(present, missing)
+    fds = [os.open(paths[i], os.O_RDONLY) for i in present]
+    try:
+        def call():
+            return native.get_block_pread_degraded(
+                fds, [0] * k, present, k, plen, chunk, HIGHWAY_KEY, rows,
+                missing)
+        out, code = call()
+        assert code == -1
+        assert out.tobytes() == data
+        # the plain reference: the codec's own reconstruct on the payloads
+        shards = [None if i in gone else np.frombuffer(
+            native.get_block([framed[i * fl:(i + 1) * fl]], 1, plen, chunk,
+                             HIGHWAY_KEY)[0], dtype=np.uint8)
+            for i in range(k + m)]
+        ref = er.decode_data_blocks(shards)
+        assert out.tobytes() == b"".join(
+            np.asarray(s).tobytes() for s in ref[:k])
+        # one flipped payload byte in the LAST chunk of one source
+        pos = int(rng.integers(0, k))
+        with open(paths[present[pos]], "r+b") as f:
+            f.seek(fl - 1)
+            last = f.read(1)
+            f.seek(fl - 1)
+            f.write(bytes([last[0] ^ 0x40]))
+        assert call()[1] == pos
+        with open(paths[present[pos]], "r+b") as f:
+            f.seek(fl - 1)
+            f.write(last)
+        assert call()[1] == -1
+        # a truncated source file
+        short = int(rng.integers(0, k))
+        os.truncate(paths[present[short]], fl - 7)
+        assert call()[1] == -(10 + short)
+    finally:
+        for fd in fds:
+            os.close(fd)

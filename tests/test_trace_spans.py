@@ -74,17 +74,21 @@ def test_request_id_on_every_response_and_error_xml(c, tmp_path):
     assert "<HostId>" in r3.text and "<HostId></HostId>" not in r3.text
 
 
-def test_degraded_get_yields_full_span_tree(c, srv, tmp_path):
+def test_degraded_get_yields_full_span_tree(c, srv, tmp_path, monkeypatch):
     """The acceptance tree: a GetObject served through the device
     dispatch path (degraded read -> masked rebuild flush) assembles
     http -> objectlayer -> kernel(link) -> storage spans sharing one
     trace_id, retrievable by ?trace_id= — and the request shows up in
     ?slow=1 without any live trace subscriber attached."""
+    # a degraded read of LOCAL shard files is one native call a block
+    # and launches no kernel; the queue is the route of sources without
+    # an fd (RPC), forced here as chip_smoke.py's device leg forces it
+    monkeypatch.setenv("MINIO_TPU_GET_PATH", "dispatch")
     c.put_bucket("spb")
     assert c.put_object("spb", "o", b"q" * 300_000).status_code == 200
     # degrade one DATA shard (erasure index <= k) so the GET must
-    # rebuild through the dispatch queue — losing a parity shard would
-    # serve the read natively and never launch a kernel
+    # rebuild — losing a parity shard would serve the read as a healthy
+    # one whatever the route
     k = len(srv.obj.disks) - 2
     victim = next(d for d in srv.obj.disks
                   if d.read_version("spb", "o", "").erasure.index <= k)
