@@ -9,19 +9,21 @@ from .kms import (KESClient, KMS, KMSError, KMSUnreachable, LocalKMS,
                   get_kms, set_kms)
 from .sse import (CIPHER_AESGCM, CIPHER_CHACHA20, META_CIPHER, META_SCHEME,
                   PKG_SIZE, DecryptWriter, EncryptReader,
-                  SSEInfo, cipher_of, decrypt_range_bounds, default_cipher,
+                  RangeDecryptWriter, SSEInfo, SSERead, cipher_of,
+                  decrypt_range_bounds, default_cipher, derive_part_key,
                   enc_size, package_cipher,
-                  parse_sse_headers, plain_size_of, seal_object_key,
-                  sse_kms_context, unseal_object_key)
+                  parse_sse_headers, plain_size_of, plan_range,
+                  seal_object_key, sse_kms_context, unseal_object_key)
 
 __all__ = [
     "KESClient", "KMS", "KMSError", "KMSUnreachable", "LocalKMS",
     "VaultClient",
     "get_kms", "set_kms",
     "CIPHER_AESGCM", "CIPHER_CHACHA20", "META_CIPHER",
-    "META_SCHEME", "PKG_SIZE", "DecryptWriter", "EncryptReader", "SSEInfo",
+    "META_SCHEME", "PKG_SIZE", "DecryptWriter", "EncryptReader",
+    "RangeDecryptWriter", "SSEInfo", "SSERead",
     "cipher_of", "decrypt_range_bounds", "default_cipher",
-    "enc_size", "package_cipher", "parse_sse_headers",
-    "plain_size_of", "seal_object_key", "sse_kms_context",
+    "derive_part_key", "enc_size", "package_cipher", "parse_sse_headers",
+    "plain_size_of", "plan_range", "seal_object_key", "sse_kms_context",
     "unseal_object_key",
 ]

@@ -30,8 +30,10 @@ from __future__ import annotations
 
 import base64
 import hashlib
+import hmac
 import secrets
 import struct
+import time
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -53,6 +55,7 @@ except ImportError:  # gated optional dep: SSE raises at use, not import
                 "SSE/KMS is unavailable on this build")
 
 from ..objectlayer import datatypes as dt
+from ..obs import stages as _stages
 
 PKG_SIZE = 64 << 10
 TAG = 16
@@ -75,10 +78,15 @@ META_KMS_KEY_ID = "x-minio-internal-sse-kms-key-id"  # SSE-KMS master key id
 META_KMS_CONTEXT = "x-minio-internal-sse-kms-context"  # b64 JSON context
 META_PLAIN_SIZE = "x-minio-internal-sse-plain-size"
 META_CIPHER = "x-minio-internal-sse-cipher"  # package cipher; absent = GCM
+META_MULTIPART = "x-minio-internal-sse-multipart"  # "1": a stream per part
 
 SSE_META_KEYS = (META_SCHEME, META_SEALED, META_IV, META_KEY_MD5,
                  META_KMS_BLOB, META_KMS_KEY_ID, META_KMS_CONTEXT,
-                 META_PLAIN_SIZE, META_CIPHER)
+                 META_PLAIN_SIZE, META_CIPHER, META_MULTIPART)
+
+# a part's own metadata (part sidecar, then ``parts[i].m`` of xl.meta)
+PART_IV = "sse-iv"          # b64 12-byte IV of this part's package stream
+PART_NUMBER = "sse-part"    # the UploadPart number its key was derived from
 
 
 def default_cipher() -> str:
@@ -226,11 +234,35 @@ def _aad(seq: int) -> bytes:
     return _AAD + struct.pack(">I", seq)
 
 
+def _short(cipher: str) -> str:
+    return "chacha20" if cipher == CIPHER_CHACHA20 else "aes-gcm"
+
+
+def _timed_block(lane, op: str, seq0: int, pkgs: list) -> list:
+    """``lane.seal_block`` / ``lane.open_block`` with its wall time charged
+    to the armed stage collector (``sse_seal`` / ``sse_open``) and to
+    minio_tpu_workloads_sse_seconds_total{cipher,op}."""
+    t0 = time.monotonic()
+    try:
+        return getattr(lane, op + "_block")(seq0, pkgs)
+    finally:
+        dt_s = time.monotonic() - t0
+        st = _stages.active()
+        if st is not None:
+            st.add("sse_" + op, dt_s)
+        try:
+            from ..obs import metrics as _mx
+            _mx.inc("minio_tpu_workloads_sse_seconds_total", dt_s,
+                    cipher=_short(lane.name), op=op)
+        except Exception:  # noqa: BLE001 — obs never breaks the path
+            pass
+
+
 def _workload(op: str, cipher: str, route: str, pkgs: int, nbytes: int):
     """workloads metric group feed (docs/observability.md)."""
     try:
         from ..obs import metrics as _mx
-        short = "chacha20" if cipher == CIPHER_CHACHA20 else "aes-gcm"
+        short = _short(cipher)
         _mx.inc("minio_tpu_workloads_sse_packages_total", pkgs,
                 cipher=short, route=route)
         _mx.inc("minio_tpu_workloads_sse_bytes_total", nbytes,
@@ -440,7 +472,8 @@ class EncryptReader:
                     break
             if not pkgs:
                 break
-            for sealed in self.cipher.seal_block(self._seq, pkgs):
+            for sealed in _timed_block(self.cipher, "seal", self._seq,
+                                       pkgs):
                 self._chunks.append(memoryview(sealed))
                 self._avail += len(sealed)
             self._seq += len(pkgs)
@@ -518,7 +551,7 @@ class DecryptWriter:
         cts = [ct[i * unit: min((i + 1) * unit, len(ct))]
                for i in range(npkgs)]
         try:
-            plains = self.cipher.open_block(self._seq, cts)
+            plains = _timed_block(self.cipher, "open", self._seq, cts)
         except _TagError:
             raise dt.SSEDecryptError(*self._bo) from None
         self._seq += npkgs
@@ -567,6 +600,131 @@ def decrypt_range_bounds(offset: int, length: int, plain_size: int
     enc_off = pkg0 * (PKG_SIZE + TAG)
     enc_end = min((pkg1 + 1) * (PKG_SIZE + TAG), enc_size(plain_size))
     return enc_off, enc_end - enc_off, pkg0, offset - pkg0 * PKG_SIZE
+
+
+def derive_part_key(oek: bytes, part_number: int) -> bytes:
+    """The key of part ``part_number`` of a multipart object (reference
+    cmd/crypto/key.go ObjectKey.DerivePartKey: HMAC-SHA256 of the
+    little-endian part number under the object key)."""
+    return hmac.new(oek, struct.pack("<I", part_number),
+                    hashlib.sha256).digest()
+
+
+@dataclass(frozen=True)
+class PartStream:
+    """One package stream of an object: a single-PUT object is one such
+    stream under (OEK, base IV), a multipart object one per part."""
+    key: bytes
+    iv: bytes
+    plain: int
+
+
+@dataclass(frozen=True)
+class Segment:
+    """What one ``DecryptWriter`` of a ranged read does: ``stored`` bytes
+    of ciphertext under (key, iv) from package ``seq0``, of whose plaintext
+    ``skip`` bytes are dropped and ``limit`` released."""
+    key: bytes
+    iv: bytes
+    seq0: int
+    skip: int
+    limit: int
+    stored: int
+
+
+@dataclass
+class SSERead:
+    """An encrypted object as one request may read it (s3api
+    ``_sse_read_ctx``): the unsealed OEK never leaves this record."""
+    streams: tuple
+    plain_size: int
+    resp: dict
+    cipher: str
+
+
+def part_streams(oek: bytes, parts, bucket: str = "", object: str = ""
+                 ) -> tuple:
+    """The package streams of a multipart-encrypted object from its
+    ``xl.meta`` parts (``actual_size`` = plaintext size, ``meta`` = the IV
+    and the part number the key derives from)."""
+    out = []
+    for p in parts:
+        try:
+            iv = base64.b64decode(p.meta[PART_IV], validate=True)
+            number = int(p.meta[PART_NUMBER])
+        except (KeyError, ValueError):
+            raise dt.SSEDecryptError(bucket, object) from None
+        if len(iv) != 12:
+            raise dt.SSEDecryptError(bucket, object)
+        out.append(PartStream(derive_part_key(oek, number), iv,
+                              p.actual_size))
+    return tuple(out)
+
+
+def plan_range(streams, offset: int, length: int
+               ) -> tuple[int, int, list[Segment]]:
+    """For the plaintext range [offset, offset+length) of an object made
+    of ``streams``: the ONE stored span to read (enc_off, enc_len) and the
+    segments it is cut into, one per stream touched. A stream that is not
+    the range's last is read to its end and the next from its package 0,
+    so the span is contiguous. length < 0 means to-end."""
+    total = sum(s.plain for s in streams)
+    end = total if length < 0 else min(offset + length, total)
+    segs: list[Segment] = []
+    enc_off = enc_end = 0
+    p0 = e0 = 0     # where this stream starts: plaintext, stored
+    for s in streams:
+        lo, hi = max(offset, p0), min(end, p0 + s.plain)
+        if lo < hi:
+            off, ln, seq0, skip = decrypt_range_bounds(lo - p0, hi - lo,
+                                                       s.plain)
+            if not segs:
+                enc_off = e0 + off
+            enc_end = e0 + off + ln
+            segs.append(Segment(s.key, s.iv, seq0, skip, hi - lo, ln))
+        p0 += s.plain
+        e0 += enc_size(s.plain)
+    return enc_off, enc_end - enc_off, segs
+
+
+class RangeDecryptWriter:
+    """Writer over the stored span ``plan_range`` names: cuts it at the
+    segment boundaries and opens each piece with a ``DecryptWriter`` under
+    that segment's key, IV and first sequence number."""
+
+    def __init__(self, writer, segments, cipher: str, bucket: str = "",
+                 object: str = ""):
+        self.writer = writer
+        self._segs = iter(segments)
+        self._cipher = cipher
+        self._bo = (bucket, object)
+        self._dw: DecryptWriter | None = None
+        self._left = 0
+
+    def write(self, b):
+        mv = memoryview(b).cast("B")
+        while len(mv):
+            if self._dw is None:
+                seg = next(self._segs, None)
+                if seg is None:     # more stored bytes than were planned
+                    raise dt.SSEDecryptError(*self._bo)
+                self._dw = DecryptWriter(
+                    self.writer, seg.key, seg.iv, seg.seq0, seg.skip,
+                    seg.limit, *self._bo, cipher=self._cipher)
+                self._left = seg.stored
+            take = min(len(mv), self._left)
+            self._dw.write(mv[:take])
+            mv = mv[take:]
+            self._left -= take
+            if self._left == 0:
+                self._dw.finish()
+                self._dw = None
+
+    def finish(self):
+        """Flush the trailing packages without closing the sink."""
+        if self._dw is not None:
+            self._dw.finish()
+            self._dw = None
 
 
 def _read_full(stream, n: int) -> bytes:

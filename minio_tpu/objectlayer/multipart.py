@@ -120,9 +120,35 @@ class MultipartMixin:
 
     # --- put part -----------------------------------------------------------
 
+    def get_multipart_info(self, bucket: str, object: str, upload_id: str
+                           ) -> MultipartInfo:
+        """The upload's own record (reference GetMultipartInfo): what
+        CreateMultipartUpload stored, internal keys included, so that the
+        part handler can tell an encrypted upload."""
+        fi, _, _ = self._upload_meta(bucket, object, upload_id)
+        return MultipartInfo(bucket=bucket, object=object,
+                             upload_id=upload_id, initiated=fi.mod_time,
+                             user_defined=dict(fi.metadata))
+
     def put_object_part(self, bucket: str, object: str, upload_id: str,
                         part_id: int, stream, size: int,
                         opts: ObjectOptions = None) -> PartInfo:
+        """``stream`` may be a HashReader whose ``actual_size`` differs
+        from ``size`` (an encrypted part: stored and plaintext size);
+        ``opts.user_defined`` is the part's own metadata, kept in its
+        sidecar and carried into ``xl.meta`` at complete."""
+        from .. import qos as _qos
+        from ..obs import attribution as _attr
+        with _spans.span("objectlayer.put_object_part", bucket=bucket,
+                         object=object), _attr.observed("put"), \
+                _qos.lane_affinity(self._lane_key):
+            return self._put_object_part_inner(bucket, object, upload_id,
+                                               part_id, stream, size, opts)
+
+    def _put_object_part_inner(self, bucket: str, object: str,
+                               upload_id: str, part_id: int, stream,
+                               size: int, opts: ObjectOptions = None
+                               ) -> PartInfo:
         from .erasure_objects import to_object_err
         if not 1 <= part_id <= MAX_PARTS:
             raise dt.InvalidPart(bucket, object, str(part_id))
@@ -191,7 +217,9 @@ class MultipartMixin:
         part_meta = msgpack.packb({
             "etag": etag, "size": total,
             "actual_size": hr.actual_size if hr.actual_size >= 0 else total,
-            "mtime": FileInfo.now()}, use_bin_type=True)
+            "mtime": FileInfo.now(),
+            "meta": dict(opts.user_defined) if opts is not None else {}},
+            use_bin_type=True)
         errs = [None] * len(disks)
         for j, d in enumerate(shuffled):
             if d is None or writers[j] is None:
@@ -336,7 +364,7 @@ class MultipartMixin:
                 raise dt.EntityTooSmall(bucket, object, str(p.part_number))
             fi_parts.append(ObjectPartInfo(
                 number=i + 1, etag=m["etag"], size=m["size"],
-                actual_size=m["actual_size"]))
+                actual_size=m["actual_size"], meta=m.get("meta") or {}))
             total += m["size"]
             actual_total += m["actual_size"]
 

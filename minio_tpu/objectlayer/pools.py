@@ -199,6 +199,10 @@ class ServerPools(ObjectLayer):
             .put_object_part(bucket, object, upload_id, part_id, stream,
                              size, opts)
 
+    def get_multipart_info(self, bucket, object, upload_id):
+        return self._pool_with_upload(bucket, object, upload_id) \
+            .get_multipart_info(bucket, object, upload_id)
+
     def list_object_parts(self, bucket, object, upload_id, part_marker=0,
                           max_parts=1000):
         return self._pool_with_upload(bucket, object, upload_id) \

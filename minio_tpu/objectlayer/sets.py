@@ -163,6 +163,10 @@ class ErasureSets(ObjectLayer):
         return self.get_hashed_set(object).put_object_part(
             bucket, object, upload_id, part_id, stream, size, opts)
 
+    def get_multipart_info(self, bucket, object, upload_id):
+        return self.get_hashed_set(object).get_multipart_info(
+            bucket, object, upload_id)
+
     def list_object_parts(self, bucket, object, upload_id, part_marker=0,
                           max_parts=1000):
         return self.get_hashed_set(object).list_object_parts(

@@ -1451,13 +1451,7 @@ class _S3Handler(BaseHTTPRequestHandler):
         # BytesProcessed (s3select/message.py events)
         scanned = oi.size
         if sse:
-            from ..crypto import DecryptWriter, enc_size
-            oek, base_iv, plain_size, _, cipher = sse
-            scanned = enc_size(plain_size)
-            dw = DecryptWriter(sink, oek, base_iv, 0, 0, plain_size,
-                               self.bucket, self.key, cipher=cipher)
-            self.s3.obj.get_object(self.bucket, self.key, dw, 0, -1, opts)
-            dw.finish()
+            self._sse_write(sse, sink, 0, sse.plain_size, opts)
         elif oi.internal.get(cz.META_COMPRESSION):
             # stored bytes are compressed: the SQL engine needs plaintext
             dz = cz.decompress_writer(
@@ -1788,9 +1782,12 @@ class _S3Handler(BaseHTTPRequestHandler):
         stream length."""
         from ..bucket import transition as tx
         from ..crypto import META_SCHEME, plain_size_of
+        from ..crypto.sse import META_MULTIPART
         from ..utils import compress as cz
         for oi in r.objects:
-            if oi.internal.get(META_SCHEME):
+            if oi.internal.get(META_MULTIPART):
+                oi.size = oi.actual_size    # the sum of its parts'
+            elif oi.internal.get(META_SCHEME):
                 oi.size = plain_size_of(oi.internal, oi.size)
             elif oi.internal.get(cz.META_COMPRESSION):
                 oi.size = oi.actual_size
@@ -2148,27 +2145,26 @@ class _S3Handler(BaseHTTPRequestHandler):
             **sse_resp})
         self._notify("s3:ObjectCreated:Put", oi)
 
-    def _encrypt_setup(self, sse, hr, size: int, user_defined: dict):
-        """Envelope setup for a PUT (cmd/encryption-v1.go EncryptRequest):
-        random OEK sealed under the request key (SSE-C) or a KMS data key
-        (SSE-S3); internal metadata records everything a reader needs
-        except the secret itself. Returns (cipher stream, encrypted size,
-        response headers)."""
+    def _sse_new_key(self, sse, user_defined: dict):
+        """Envelope setup for a new encrypted object or multipart upload
+        (cmd/encryption-v1.go EncryptRequest / newEncryptMetadata): random
+        OEK sealed under the request key (SSE-C) or a KMS data key
+        (SSE-S3, SSE-KMS); ``user_defined`` records everything a reader
+        needs except the secret itself. Returns (OEK, base IV, package
+        cipher, response headers)."""
         import base64
         import secrets
 
-        from ..crypto import (EncryptReader, enc_size, get_kms,
-                              seal_object_key, sse_kms_context)
+        from ..crypto import get_kms, seal_object_key, sse_kms_context
         from ..crypto.sse import (META_CIPHER, META_IV, META_KEY_MD5,
                                   META_KMS_BLOB, META_KMS_CONTEXT,
-                                  META_KMS_KEY_ID, META_PLAIN_SIZE,
-                                  META_SCHEME, META_SEALED, default_cipher)
+                                  META_KMS_KEY_ID, META_SCHEME, META_SEALED,
+                                  default_cipher)
         oek = secrets.token_bytes(32)
         base_iv = secrets.token_bytes(12)
         cipher = default_cipher()
         user_defined[META_SCHEME] = sse.scheme
         user_defined[META_IV] = base64.b64encode(base_iv).decode()
-        user_defined[META_PLAIN_SIZE] = str(size)
         user_defined[META_CIPHER] = cipher
         if sse.scheme == "C":
             sealed = seal_object_key(oek, sse.key, self.bucket, self.key,
@@ -2200,6 +2196,15 @@ class _S3Handler(BaseHTTPRequestHandler):
             user_defined[META_KMS_BLOB] = base64.b64encode(blob).decode()
             resp = {"x-amz-server-side-encryption": "AES256"}
         user_defined[META_SEALED] = base64.b64encode(sealed).decode()
+        return oek, base_iv, cipher, resp
+
+    def _encrypt_setup(self, sse, hr, size: int, user_defined: dict):
+        """A single PUT's cipher stream: (stream, encrypted size, response
+        headers)."""
+        from ..crypto import EncryptReader, enc_size
+        from ..crypto.sse import META_PLAIN_SIZE
+        oek, base_iv, cipher, resp = self._sse_new_key(sse, user_defined)
+        user_defined[META_PLAIN_SIZE] = str(size)
         return (EncryptReader(hr, oek, base_iv, cipher=cipher),
                 enc_size(size), resp)
 
@@ -2213,35 +2218,32 @@ class _S3Handler(BaseHTTPRequestHandler):
             raise dt.KMSNotAvailable(self.bucket, self.key,
                                      extra=str(e)) from None
 
-    def _sse_read_ctx(self, oi):
-        """For an encrypted object: unseal the OEK using this request's
-        credentials and return (oek, base_iv, plain_size, response
-        headers, package cipher); None for plaintext objects. SSE-C
-        requires the customer key headers on GET/HEAD (matching
-        fingerprint — a wrong key MD5 403s BEFORE any package is read or
-        opened), SSE-S3 unseals via the KMS (cmd/encryption-v1.go
-        DecryptRequest)."""
+    def _sse_unseal(self, internal: dict):
+        """Unseal the OEK of an encrypted object or multipart upload from
+        its internal metadata with this request's credentials: (OEK,
+        response headers, package cipher), or None when the metadata
+        names no scheme. SSE-C requires the customer key headers
+        (matching fingerprint: a wrong key MD5 403s BEFORE any package is
+        read or opened), SSE-S3 and SSE-KMS unseal via the KMS
+        (cmd/encryption-v1.go DecryptRequest)."""
+        from ..crypto.sse import META_SCHEME
+        scheme = internal.get(META_SCHEME, "")
+        if not scheme:
+            return None
         import base64
 
         from ..crypto import (get_kms, parse_sse_headers, sse_kms_context,
                               unseal_object_key)
-        from ..crypto.sse import (META_IV, META_KEY_MD5, META_KMS_BLOB,
+        from ..crypto.sse import (META_KEY_MD5, META_KMS_BLOB,
                                   META_KMS_CONTEXT, META_KMS_KEY_ID,
-                                  META_PLAIN_SIZE, META_SCHEME, META_SEALED,
-                                  cipher_of)
-        from ..crypto import plain_size_of
-        scheme = oi.internal.get(META_SCHEME, "")
-        if not scheme:
-            return None
-        sealed = base64.b64decode(oi.internal.get(META_SEALED, ""))
-        base_iv = base64.b64decode(oi.internal.get(META_IV, ""))
-        plain_size = plain_size_of(oi.internal, oi.size)
-        cipher = cipher_of(oi.internal)
+                                  META_SEALED, cipher_of)
+        sealed = base64.b64decode(internal.get(META_SEALED, ""))
+        cipher = cipher_of(internal)
         if scheme == "C":
             req = parse_sse_headers(self.hdr, self.bucket, self.key)
             if req is None or req.scheme != "C":
                 raise dt.SSEEncryptedObject(self.bucket, self.key)
-            if req.key_md5 != oi.internal.get(META_KEY_MD5, ""):
+            if req.key_md5 != internal.get(META_KEY_MD5, ""):
                 raise dt.SSEKeyMismatch(self.bucket, self.key)
             oek = unseal_object_key(sealed, req.key, self.bucket, self.key,
                                     cipher=cipher)
@@ -2249,41 +2251,68 @@ class _S3Handler(BaseHTTPRequestHandler):
                 "x-amz-server-side-encryption-customer-algorithm": "AES256",
                 "x-amz-server-side-encryption-customer-key-MD5":
                     req.key_md5}
-        elif scheme == "KMS":
-            blob = base64.b64decode(oi.internal.get(META_KMS_BLOB, ""))
-            key_id = oi.internal.get(META_KMS_KEY_ID, "")
+            return oek, resp, cipher
+        blob = base64.b64decode(internal.get(META_KMS_BLOB, ""))
+        if scheme == "KMS":
+            key_id = internal.get(META_KMS_KEY_ID, "")
             stored_ctx = ""
-            if oi.internal.get(META_KMS_CONTEXT, ""):
+            if internal.get(META_KMS_CONTEXT, ""):
                 stored_ctx = base64.b64decode(
-                    oi.internal[META_KMS_CONTEXT]).decode()
+                    internal[META_KMS_CONTEXT]).decode()
             ctx = sse_kms_context(self.bucket, self.key, stored_ctx)
-            from ..crypto import KMSUnreachable
-            try:
-                dk = get_kms().unseal(blob, ctx, key_id=key_id)
-            except KMSUnreachable as e:
-                # transient KMS outage — not a wrong-key condition
-                raise dt.KMSNotAvailable(self.bucket, self.key,
-                                         extra=str(e)) from None
-            except Exception:  # noqa: BLE001 — rotated/deleted master key
-                raise dt.SSEKeyMismatch(self.bucket, self.key) from None
-            oek = unseal_object_key(sealed, dk, self.bucket, self.key,
-                                    cipher=cipher)
             resp = {"x-amz-server-side-encryption": "aws:kms",
                     "x-amz-server-side-encryption-aws-kms-key-id": key_id}
         else:
-            from ..crypto import KMSUnreachable
-            blob = base64.b64decode(oi.internal.get(META_KMS_BLOB, ""))
-            try:
-                dk = get_kms().unseal(blob, f"{self.bucket}/{self.key}")
-            except KMSUnreachable as e:
-                raise dt.KMSNotAvailable(self.bucket, self.key,
-                                         extra=str(e)) from None
-            except Exception:  # noqa: BLE001 — rotated/wrong master key
-                raise dt.SSEKeyMismatch(self.bucket, self.key) from None
-            oek = unseal_object_key(sealed, dk, self.bucket, self.key,
-                                    cipher=cipher)
+            key_id, ctx = "", f"{self.bucket}/{self.key}"
             resp = {"x-amz-server-side-encryption": "AES256"}
-        return oek, base_iv, plain_size, resp, cipher
+        from ..crypto import KMSUnreachable
+        try:
+            dk = get_kms().unseal(blob, ctx, key_id=key_id)
+        except KMSUnreachable as e:
+            # transient KMS outage — not a wrong-key condition
+            raise dt.KMSNotAvailable(self.bucket, self.key,
+                                     extra=str(e)) from None
+        except Exception:  # noqa: BLE001 — rotated/deleted master key
+            raise dt.SSEKeyMismatch(self.bucket, self.key) from None
+        oek = unseal_object_key(sealed, dk, self.bucket, self.key,
+                                cipher=cipher)
+        return oek, resp, cipher
+
+    def _sse_read_ctx(self, oi):
+        """For an encrypted object: its package streams under the OEK this
+        request unsealed (``crypto.SSERead``: one stream for a single PUT,
+        one per part for a multipart upload, docs/sse.md), the plaintext
+        size and the response headers; None for plaintext objects."""
+        unsealed = self._sse_unseal(oi.internal)
+        if unsealed is None:
+            return None
+        import base64
+
+        from ..crypto import SSERead, plain_size_of
+        from ..crypto.sse import (META_IV, META_MULTIPART, PartStream,
+                                  part_streams)
+        oek, resp, cipher = unsealed
+        if oi.internal.get(META_MULTIPART):
+            streams = part_streams(oek, oi.parts, self.bucket, self.key)
+            plain_size = sum(s.plain for s in streams)
+        else:
+            plain_size = plain_size_of(oi.internal, oi.size)
+            streams = (PartStream(oek, base64.b64decode(
+                oi.internal.get(META_IV, "")), plain_size),)
+        return SSERead(streams, plain_size, resp, cipher)
+
+    def _sse_write(self, sse, sink, offset: int, length: int, opts=None):
+        """Decrypt the plaintext range [offset, offset+length) of
+        ``self.bucket/self.key`` into ``sink``: only the stored bytes that
+        cover it are read, every package is verified before release."""
+        from ..crypto import RangeDecryptWriter, plan_range
+        enc_off, enc_len, segs = plan_range(sse.streams, offset, length)
+        dw = RangeDecryptWriter(sink, segs, sse.cipher, self.bucket,
+                                self.key)
+        if enc_len > 0:
+            self.s3.obj.get_object(self.bucket, self.key, dw, enc_off,
+                                   enc_len, opts)
+        dw.finish()
 
     def _hash_reader(self, size: int) -> HashReader:
         """Body reader verifying Content-MD5 / x-amz-content-sha256 on the
@@ -2401,12 +2430,12 @@ class _S3Handler(BaseHTTPRequestHandler):
         sse = self._sse_read_ctx(oi)
         from ..utils import compress as cz
         compressed = oi.internal.get(cz.META_COMPRESSION, "")
-        logical_size = sse[2] if sse else (
+        logical_size = sse.plain_size if sse else (
             oi.actual_size if compressed else oi.size)
         rng = self._parse_range(logical_size) if logical_size > 0 else None
         headers = self._obj_headers(oi)
         if sse:
-            headers.update(sse[3])
+            headers.update(sse.resp)
         if rng is None:
             offset, length = 0, logical_size
             status = 200
@@ -2423,17 +2452,7 @@ class _S3Handler(BaseHTTPRequestHandler):
         self.end_headers()
         if length > 0:
             if sse:
-                from ..crypto import DecryptWriter, decrypt_range_bounds
-                oek, base_iv, plain_size, _, cipher = sse
-                enc_off, enc_len, seq0, skip = decrypt_range_bounds(
-                    offset, length, plain_size)
-                dw = DecryptWriter(self.wfile, oek, base_iv, seq0, skip,
-                                   length, self.bucket, self.key,
-                                   cipher=cipher)
-                if enc_len > 0:
-                    self.s3.obj.get_object(self.bucket, self.key, dw,
-                                           enc_off, enc_len, opts)
-                dw.finish()
+                self._sse_write(sse, self.wfile, offset, length, opts)
             elif compressed:
                 # inflate the whole stored stream, trim to the requested
                 # plaintext range (reference compressed-range behavior)
@@ -2519,8 +2538,8 @@ class _S3Handler(BaseHTTPRequestHandler):
         sse = self._sse_read_ctx(oi)
         h = self._obj_headers(oi)
         if sse:
-            h.update(sse[3])
-            h["Content-Length"] = str(sse[2])
+            h.update(sse.resp)
+            h["Content-Length"] = str(sse.plain_size)
         else:
             from ..utils import compress as cz
             h["Content-Length"] = str(
@@ -2805,18 +2824,41 @@ class _S3Handler(BaseHTTPRequestHandler):
 
     def initiate_upload(self, ak):
         self._authorize(ak, "s3:PutObject")
-        if self.hdr.get("x-amz-server-side-encryption") or self.hdr.get(
-                "x-amz-server-side-encryption-customer-algorithm"):
-            # multipart SSE (per-part cipher streams) is not wired yet;
-            # refuse instead of storing parts unencrypted
-            raise dt.NotImplemented(self.bucket, self.key)
         opts = self._opts()
         opts.user_defined = self._user_meta()
+        from ..crypto import parse_sse_headers
+        sse = parse_sse_headers(self.hdr, self.bucket, self.key)
+        sse_resp = {}
+        if sse is not None:
+            # the OEK is made and sealed now and kept in the upload's own
+            # metadata; every part is sealed under a key derived from it
+            # (docs/sse.md). A backend that cannot hand that metadata
+            # back to put_part must refuse: a request for SSE is never
+            # answered by storing plaintext parts
+            if not hasattr(self.s3.obj, "get_multipart_info"):
+                raise dt.NotImplemented(self.bucket, self.key)
+            from ..crypto.sse import META_MULTIPART
+            _, _, _, sse_resp = self._sse_new_key(sse, opts.user_defined)
+            opts.user_defined[META_MULTIPART] = "1"
         uid = self.s3.obj.new_multipart_upload(self.bucket, self.key, opts)
-        self._send(200, xu.initiate_multipart_xml(self.bucket, self.key, uid))
+        self._send(200, xu.initiate_multipart_xml(self.bucket, self.key, uid),
+                   headers=sse_resp)
+
+    def _upload_internal(self, uid: str) -> dict:
+        """The upload's metadata as CreateMultipartUpload stored it, {} on
+        a backend that keeps none (it refused SSE at initiate)."""
+        info = getattr(self.s3.obj, "get_multipart_info", None)
+        if info is None:
+            return {}
+        return info(self.bucket, self.key, uid).user_defined
 
     def put_part(self, ak):
         self._authorize(ak, "s3:PutObject")
+        if "x-amz-copy-source" in self.hdr:
+            # UploadPartCopy is not wired (ROADMAP A6): the request has no
+            # body, and storing that empty body as the part would be
+            # silent wrong data
+            raise dt.NotImplemented(self.bucket, self.key)
         part_id = int(self.q("partNumber"))
         uid = self.q("uploadId")
         size = int(self.hdr.get("content-length", "-1") or "-1")
@@ -2831,9 +2873,48 @@ class _S3Handler(BaseHTTPRequestHandler):
         # like PutObject — otherwise corrupted parts are accepted and only
         # surface as a confusing InvalidPart at complete time.
         hr = self._hash_reader(size)
+        stream, put_size, opts, sse_resp = hr, size, None, {}
+        from ..crypto.sse import META_SCHEME
+        internal = self._upload_internal(uid)
+        scheme = internal.get(META_SCHEME, "")
+        if scheme:
+            stream, put_size, opts, sse_resp = self._encrypt_part(
+                internal, part_id, hr, size)
+        elif self.hdr.get(
+                "x-amz-server-side-encryption-customer-algorithm"):
+            raise dt.InvalidRequest(
+                self.bucket, self.key,
+                "the upload was not initiated with SSE-C")
         pi = self.s3.obj.put_object_part(self.bucket, self.key, uid,
-                                         part_id, hr, size)
-        self._send(200, headers={"ETag": f'"{pi.etag}"'})
+                                         part_id, stream, put_size, opts)
+        from ..obs import metrics as mx
+        mx.inc("minio_tpu_multipart_parts_total", sse=scheme or "none")
+        self._send(200, headers={"ETag": f'"{pi.etag}"', **sse_resp})
+
+    def _encrypt_part(self, internal: dict, part_id: int, hr, size: int):
+        """One part of an encrypted upload (reference PutObjectPartHandler):
+        the OEK unsealed with this request's credentials, the part's key
+        derived from it and the part number, a fresh IV for this request
+        (a part number uploaded twice never repeats a nonce under its
+        key). Returns (stream, stored size, options, response headers)."""
+        import base64
+        import secrets
+
+        from ..crypto import EncryptReader, derive_part_key, enc_size
+        from ..crypto.sse import PART_IV, PART_NUMBER
+        oek, resp, cipher = self._sse_unseal(internal)
+        iv = secrets.token_bytes(12)
+        # the part's ETag is of the stored stream; the plaintext digest is
+        # kept only where the client sent one to verify
+        hr.disable_payload_hash()
+        stored = enc_size(size)
+        stream = HashReader(
+            EncryptReader(hr, derive_part_key(oek, part_id), iv,
+                          cipher=cipher), stored, actual_size=size)
+        opts = ObjectOptions(user_defined={
+            PART_IV: base64.b64encode(iv).decode(),
+            PART_NUMBER: str(part_id)})
+        return stream, stored, opts, resp
 
     def list_parts(self, ak):
         self._authorize(ak, "s3:ListMultipartUploadParts")
@@ -2841,6 +2922,10 @@ class _S3Handler(BaseHTTPRequestHandler):
             self.bucket, self.key, self.q("uploadId"),
             int(self.q("part-number-marker", "0") or "0"),
             min(int(self.q("max-parts", "1000") or "1000"), 10_000))
+        from ..crypto.sse import META_SCHEME
+        if self._upload_internal(self.q("uploadId")).get(META_SCHEME):
+            for p in info.parts:    # listings speak plaintext sizes
+                p.size = p.actual_size
         self._send(200, xu.list_parts_xml(info))
 
     def list_uploads(self, ak):
@@ -2866,6 +2951,12 @@ class _S3Handler(BaseHTTPRequestHandler):
         opts = self._opts()
         oi = self.s3.obj.complete_multipart_upload(
             self.bucket, self.key, self.q("uploadId"), parts, opts)
+        from ..crypto.sse import META_MULTIPART, META_SCHEME
+        from ..obs import metrics as mx
+        mx.inc("minio_tpu_multipart_completes_total",
+               sse=oi.internal.get(META_SCHEME, "") or "none")
+        if oi.internal.get(META_MULTIPART):
+            oi.size = oi.actual_size    # events speak plaintext sizes
         # multipart-complete is a replication charge point too; the
         # status rides a meta update since the parts were written long
         # before the obligation existed

@@ -500,7 +500,7 @@ def handle_download(h, bucket: str, object: str) -> None:
 def _logical_size(h, oi, sse) -> int:
     from ..utils import compress as cz
     if sse:
-        return sse[2]
+        return sse.plain_size
     return oi.actual_size if oi.internal.get(cz.META_COMPRESSION) \
         else oi.size
 
@@ -512,12 +512,8 @@ def _write_logical(h, bucket: str, object: str, oi, sse, sink) -> None:
     from ..utils import compress as cz
     compressed = oi.internal.get(cz.META_COMPRESSION, "")
     if sse:
-        from ..crypto import DecryptWriter
-        oek, base_iv, psize, _, cipher = sse
-        dw = DecryptWriter(sink, oek, base_iv, 0, 0, psize,
-                           bucket, object, cipher=cipher)
-        h.s3.obj.get_object(bucket, object, dw)
-        dw.finish()
+        h.bucket, h.key = bucket, object
+        h._sse_write(sse, sink, 0, sse.plain_size)
     elif compressed:
         dz = cz.decompress_writer(compressed, sink)
         h.s3.obj.get_object(bucket, object, dz)

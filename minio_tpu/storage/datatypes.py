@@ -18,15 +18,22 @@ class ObjectPartInfo:
     etag: str = ""
     size: int = 0            # on-wire (possibly compressed/encrypted) size
     actual_size: int = 0     # original client size
+    #: the part's own metadata, opaque to the object layer (multipart SSE:
+    #: the IV and the part number its key derives from, docs/sse.md);
+    #: written only when there is any
+    meta: dict = field(default_factory=dict)
 
     def to_dict(self):
-        return {"n": self.number, "e": self.etag, "s": self.size,
-                "as": self.actual_size}
+        d = {"n": self.number, "e": self.etag, "s": self.size,
+             "as": self.actual_size}
+        if self.meta:
+            d["m"] = dict(self.meta)
+        return d
 
     @classmethod
     def from_dict(cls, d):
         return cls(number=d["n"], etag=d.get("e", ""), size=d.get("s", 0),
-                   actual_size=d.get("as", 0))
+                   actual_size=d.get("as", 0), meta=d.get("m") or {})
 
 
 @dataclass
