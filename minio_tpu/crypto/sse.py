@@ -67,6 +67,9 @@ CIPHER_CHACHA20 = "CHACHA20-POLY1305"
 #: packages per coalesced seal/open flush (1 MiB of 64 KiB packages —
 #: the PUT/GET block quantum the dispatch lane batches on)
 FLUSH_PKGS = 16
+#: how long the open side stays off the interpreter lock between two lane
+#: calls of one block: long enough for a waiting thread to wake and take it
+_GIVE_WAY_S = 5e-5
 
 # internal metadata keys (reference: X-Minio-Internal-Server-Side-Encryption-*)
 META_SCHEME = "x-minio-internal-sse-scheme"          # "C" | "S3" | "KMS"
@@ -287,8 +290,7 @@ class _GCMPackages:
         for i, pkg in enumerate(pkgs):
             total += len(pkg)
             out.append(self._aead.encrypt(
-                _nonce(self.base_iv, seq0 + i), bytes(pkg),
-                _aad(seq0 + i)))
+                _nonce(self.base_iv, seq0 + i), pkg, _aad(seq0 + i)))
         _workload("seal", self.name, "cpu", len(pkgs), total)
         return out
 
@@ -299,8 +301,7 @@ class _GCMPackages:
             total += len(ct)
             try:
                 out.append(self._aead.decrypt(
-                    _nonce(self.base_iv, seq0 + i), bytes(ct),
-                    _aad(seq0 + i)))
+                    _nonce(self.base_iv, seq0 + i), ct, _aad(seq0 + i)))
             except InvalidTag:
                 raise _TagError from None
         _workload("open", self.name, "cpu", len(cts), total)
@@ -461,17 +462,20 @@ class EncryptReader:
 
     def _fill(self):
         while not self._eof and self._avail < (1 << 20):
-            pkgs = []
-            for _ in range(FLUSH_PKGS):
-                pkg = _read_full(self.stream, PKG_SIZE)
-                if len(pkg) < PKG_SIZE:
-                    self._eof = True
-                if pkg:
-                    pkgs.append(pkg)
-                if self._eof:
-                    break
-            if not pkgs:
+            # a flush's worth in ONE read, cut into packages where it
+            # lies: a read a package made a 16 MiB part 256 turns at the
+            # interpreter lock for its socket reads alone, and a request
+            # that works in many short turns waits its turn behind every
+            # reader that opens a block in one hold (PERF.md section 6,
+            # PR 27: part PUT p95 1.1 -> 2.3 s)
+            flush = memoryview(_read_full(self.stream,
+                                          FLUSH_PKGS * PKG_SIZE))
+            if len(flush) < FLUSH_PKGS * PKG_SIZE:
+                self._eof = True
+            if not len(flush):
                 break
+            pkgs = [flush[at:at + PKG_SIZE]
+                    for at in range(0, len(flush), PKG_SIZE)]
             for sealed in _timed_block(self.cipher, "seal", self._seq,
                                        pkgs):
                 self._chunks.append(memoryview(sealed))
@@ -512,77 +516,150 @@ class EncryptReader:
         return bytes(out[:got])
 
 
+class _Staging:
+    """The plaintext of one ``write`` call on its way to the sink: ONE
+    buffer a request, as large as the largest block it was handed, sent as
+    one view of itself. The sink must be done with that view when its
+    ``write`` returns (sockets, BytesIO and the zip/chunked writers are):
+    the next block's plaintext overwrites it."""
+
+    def __init__(self, writer):
+        self.writer = writer
+        self._mv = memoryview(bytearray())
+        self._n = 0
+
+    def room(self, n: int):
+        """Make room for ``n`` more bytes: a larger buffer REPLACES the
+        old one (never a resize: a view of it may still be alive in a
+        frame the profiler pinned)."""
+        if self._n + n > len(self._mv):
+            grown = memoryview(bytearray(self._n + n))
+            grown[:self._n] = self._mv[:self._n]
+            self._mv = grown
+
+    def put(self, plain):
+        n = self._n + len(plain)
+        self._mv[self._n:n] = plain
+        self._n = n
+
+    def drop(self):
+        self._n = 0
+
+    def release(self):
+        """Everything staged goes out in one sink write."""
+        if not self._n:
+            return
+        n, self._n = self._n, 0
+        self.writer.write(self._mv[:n])
+        try:
+            from ..obs import metrics as _mx
+            _mx.inc("minio_tpu_workloads_sse_sink_writes_total", 1,
+                    op="open")
+        except Exception:  # noqa: BLE001 — obs never breaks the path
+            pass
+
+
 class DecryptWriter:
     """Writer wrapper decrypting a package-aligned ciphertext stream and
     emitting the plaintext sub-range [skip, skip+limit) of it (ranged GETs
-    read whole covering packages; the trim happens here). Full packages
-    accumulate up to FLUSH_PKGS and open through the package cipher's one
-    coalesced flush; nothing is emitted from a flush whose tags do not
-    ALL verify."""
+    read whole covering packages; the trim happens here).
+
+    It works on the block a ``write`` hands it, where the block lies: the
+    full packages are opened as slices of the incoming view, FLUSH_PKGS to
+    a lane call, and only the package that straddles two calls (under
+    PKG_SIZE + TAG bytes) is copied, into ``_carry``. No view of the
+    block outlives the call: ``erasure_decode`` recycles its pooled buffer
+    right after. The plaintexts are gathered in the staging buffer and go
+    to the sink in ONE write, and only once EVERY package of the block has
+    verified; a bad tag raises before a byte of the block is sent."""
 
     def __init__(self, writer, oek: bytes, base_iv: bytes, seq0: int,
                  skip: int, limit: int, bucket: str = "", object: str = "",
-                 cipher: str = CIPHER_AESGCM):
+                 cipher: str = CIPHER_AESGCM,
+                 staging: _Staging | None = None):
         self.writer = writer
         self.base_iv = base_iv
         self.cipher = package_cipher(cipher, oek, base_iv)
         self._seq = seq0
         self._skip = skip
         self._left = limit
-        self._buf = bytearray()
+        self._carry = bytearray()
         self._bo = (bucket, object)
+        self._staging = staging or _Staging(writer)
+
+    def feed(self, b):
+        """Open the packages ``b`` completes into the staging buffer;
+        nothing is sent (``write`` = ``feed`` + release)."""
+        mv = memoryview(b).cast("B")
+        unit = PKG_SIZE + TAG
+        # the most a write of this size can complete, so that writes of
+        # one size find the buffer they need in place
+        self._staging.room((len(mv) // unit + 1) * PKG_SIZE)
+        cts = []
+        if self._carry:
+            take = min(unit - len(self._carry), len(mv))
+            self._carry += mv[:take]
+            mv = mv[take:]
+            if len(self._carry) < unit:
+                return
+            # whole now: opened from where it is, and REPLACED, never
+            # cleared (an exported bytearray cannot be resized)
+            cts.append(self._carry)
+            self._carry = bytearray()
+        whole = len(mv) - len(mv) % unit
+        cts.extend(mv[at:at + unit] for at in range(0, whole, unit))
+        if whole < len(mv):
+            self._carry = bytearray(mv[whole:])
+        self._open(cts)
+
+    def _open(self, cts: list):
+        for at in range(0, len(cts), FLUSH_PKGS):
+            group = cts[at:at + FLUSH_PKGS]
+            try:
+                plains = _timed_block(self.cipher, "open", self._seq, group)
+            except _TagError:
+                self._staging.drop()
+                raise dt.SSEDecryptError(*self._bo) from None
+            self._seq += len(group)
+            for plain in plains:
+                plain = memoryview(plain).cast("B")
+                if self._skip:
+                    drop = min(self._skip, len(plain))
+                    plain = plain[drop:]
+                    self._skip -= drop
+                if self._left >= 0:
+                    plain = plain[:self._left]
+                    self._left -= len(plain)
+                if len(plain):
+                    self._staging.put(plain)
+            if at + FLUSH_PKGS < len(cts):
+                # between two lane calls the interpreter lock is given
+                # away: AES-GCM holds it through a call, and a block
+                # opened in one hold makes every other request thread's
+                # turn that much longer (a sleep of 0 does not hand it
+                # over: the sleeper has it back before a waiter wakes)
+                time.sleep(_GIVE_WAY_S)
+
+    def drain(self):
+        """Open the stream's last, short package (nothing is sent)."""
+        if self._carry:
+            last, self._carry = self._carry, bytearray()
+            self._staging.room(len(last))
+            self._open([last])
 
     def write(self, b):
-        self._buf += b
-        unit = PKG_SIZE + TAG
-        while len(self._buf) >= FLUSH_PKGS * unit:
-            n = (len(self._buf) // unit) * unit
-            # REPLACE the buffer, never resize it: _open hands views of
-            # it downstream, and anything briefly pinning a frame (the
-            # continuous profiler's sample pass, a debugger) keeps such
-            # a view alive past function return — resizing an exported
-            # bytearray raises BufferError. The old buffer just lives
-            # until its last view dies.
-            full, self._buf = self._buf, self._buf[n:]
-            self._open(memoryview(full)[:n], n // unit)
-
-    def _open(self, ct: memoryview, npkgs: int):
-        unit = PKG_SIZE + TAG
-        cts = [ct[i * unit: min((i + 1) * unit, len(ct))]
-               for i in range(npkgs)]
-        try:
-            plains = _timed_block(self.cipher, "open", self._seq, cts)
-        except _TagError:
-            raise dt.SSEDecryptError(*self._bo) from None
-        self._seq += npkgs
-        for plain in plains:
-            plain = memoryview(plain).cast("B")
-            if self._skip:
-                drop = min(self._skip, len(plain))
-                plain = plain[drop:]
-                self._skip -= drop
-            if self._left >= 0:
-                plain = plain[:self._left]
-                self._left -= len(plain)
-            if len(plain):
-                self.writer.write(plain)
-
-    def _drain(self):
-        if self._buf:
-            unit = PKG_SIZE + TAG
-            npkgs = -(-len(self._buf) // unit)
-            # replace, don't clear() — same exported-view rule as write
-            full, self._buf = self._buf, bytearray()
-            self._open(memoryview(full), npkgs)
+        self.feed(b)
+        self._staging.release()
 
     def close(self):
-        self._drain()
+        self.finish()
         if hasattr(self.writer, "close"):
             self.writer.close()
 
     def finish(self):
-        """Flush the trailing packages without closing the sink."""
-        self._drain()
+        """Flush the trailing package without closing the sink."""
+        self.drain()
+        self._staging.release()
 
 
 def decrypt_range_bounds(offset: int, length: int, plain_size: int
@@ -690,7 +767,10 @@ def plan_range(streams, offset: int, length: int
 class RangeDecryptWriter:
     """Writer over the stored span ``plan_range`` names: cuts it at the
     segment boundaries and opens each piece with a ``DecryptWriter`` under
-    that segment's key, IV and first sequence number."""
+    that segment's key, IV and first sequence number. The staging buffer
+    is this writer's, one for all its segments: what a ``write`` call
+    opens, on either side of a part's edge, goes to the sink in one write
+    once all of it has verified."""
 
     def __init__(self, writer, segments, cipher: str, bucket: str = "",
                  object: str = ""):
@@ -700,6 +780,7 @@ class RangeDecryptWriter:
         self._bo = (bucket, object)
         self._dw: DecryptWriter | None = None
         self._left = 0
+        self._staging = _Staging(writer)
 
     def write(self, b):
         mv = memoryview(b).cast("B")
@@ -707,24 +788,28 @@ class RangeDecryptWriter:
             if self._dw is None:
                 seg = next(self._segs, None)
                 if seg is None:     # more stored bytes than were planned
+                    self._staging.drop()
                     raise dt.SSEDecryptError(*self._bo)
                 self._dw = DecryptWriter(
                     self.writer, seg.key, seg.iv, seg.seq0, seg.skip,
-                    seg.limit, *self._bo, cipher=self._cipher)
+                    seg.limit, *self._bo, cipher=self._cipher,
+                    staging=self._staging)
                 self._left = seg.stored
             take = min(len(mv), self._left)
-            self._dw.write(mv[:take])
+            self._dw.feed(mv[:take])
             mv = mv[take:]
             self._left -= take
             if self._left == 0:
-                self._dw.finish()
+                self._dw.drain()
                 self._dw = None
+        self._staging.release()
 
     def finish(self):
-        """Flush the trailing packages without closing the sink."""
+        """Flush the trailing package without closing the sink."""
         if self._dw is not None:
-            self._dw.finish()
+            self._dw.drain()
             self._dw = None
+        self._staging.release()
 
 
 def _read_full(stream, n: int) -> bytes:

@@ -23,6 +23,7 @@ from minio_tpu.crypto import kms as kms_mod  # noqa: E402
 from minio_tpu.crypto import sse as sse_mod  # noqa: E402
 from minio_tpu.crypto import sse_ref  # noqa: E402
 from minio_tpu.objectlayer import ErasureObjects  # noqa: E402
+from minio_tpu.objectlayer.datatypes import SSEDecryptError  # noqa: E402
 from minio_tpu.obs import metrics as mx  # noqa: E402
 from minio_tpu.obs import stages  # noqa: E402
 from minio_tpu.server import S3Server  # noqa: E402
@@ -453,6 +454,179 @@ def test_upload_part_copy_answers_501_and_stores_nothing(env):
     assert r.status_code == 200 and "<PartNumber>" not in r.text
 
 
+# --- the open side works a block at a time (docs/sse.md "The open side") -----
+
+UNIT = PKG + sse_mod.TAG
+#: parts whose edges fall inside packages and blocks; the middle one is
+#: shorter than a package
+SMALL = (PKG + 5, 17, PKG)
+CIPHERS = ["aes-gcm", "chacha20"]
+
+
+@pytest.fixture
+def host_lane(monkeypatch):
+    monkeypatch.setenv("MINIO_TPU_SSE_DEVICE", "off")   # numpy, same bytes
+
+
+def sealed_by_the_reference(cipher: str, sizes, seed: int):
+    """(streams, stored bytes, plaintext) of an object of ``sizes`` parts,
+    every package sealed by ``sse_ref``."""
+    name = WANT_CIPHER[cipher]
+    bodies = parts_of(seed, sizes)
+    streams = [sse_ref.Stream(bytes([40 + i]) * 32, bytes([i]) * 12, n)
+               for i, n in enumerate(sizes)]
+    stored = b"".join(
+        sse_ref.aead_seal(name, s.key, s.iv[:8] + seq.to_bytes(4, "big"),
+                          body[seq * PKG:(seq + 1) * PKG],
+                          sse_ref.AAD + seq.to_bytes(4, "big"))
+        for s, body in zip(streams, bodies)
+        for seq in range(-(-len(body) // PKG)))
+    return streams, stored, b"".join(bodies)
+
+
+class RecordingSink:
+    """Keeps a copy of every write it is handed (the writer sends a view
+    of a buffer it uses again)."""
+
+    def __init__(self):
+        self.writes: list[bytes] = []
+
+    def write(self, b):
+        self.writes.append(bytes(b))
+        return len(b)
+
+
+def open_through(cipher, streams, stored, lo, ln, size, sink):
+    """What a GET of plaintext [lo, lo + ln) does to the writer: the stored
+    span ``plan_range`` names arrives cut where the object's ``size``-byte
+    blocks end, each piece in a pooled buffer that is overwritten as soon
+    as ``write`` returns. Returns the number of writes handed over."""
+    segs = tuple(sse_mod.PartStream(*s) for s in streams)
+    off, enc_len, plan = sse_mod.plan_range(segs, lo, ln)
+    dw = sse_mod.RangeDecryptWriter(sink, plan, WANT_CIPHER[cipher], "b",
+                                    "o")
+    pooled, handed, at = bytearray(size), 0, off
+    while at < off + enc_len:
+        stop = min(off + enc_len, (at // size + 1) * size)
+        pooled[:stop - at] = stored[at:stop]
+        dw.write(memoryview(pooled)[:stop - at])
+        pooled[:] = b"\xa5" * size
+        handed += 1
+        at = stop
+    dw.finish()
+    return handed
+
+
+@pytest.mark.parametrize("sizes,size", [
+    (SMALL, 1), (SMALL, PKG + 15), (SIZES, PKG + 15), (SIZES, UNIT),
+    (SIZES, 4 * MIB), (SIZES, sse_ref.enc_size(SIZES[0]))],
+    ids=["1B", "small-64KiB+15", "64KiB+15", "one-package", "4MiB",
+         "a-whole-part"])
+@pytest.mark.parametrize("cipher", CIPHERS)
+def test_open_hands_the_sink_one_write_a_block(host_lane, cipher, sizes,
+                                               size):
+    streams, stored, body = sealed_by_the_reference(cipher, sizes, 27)
+    sink = RecordingSink()
+    handed = open_through(cipher, streams, stored, 0, len(body), size, sink)
+    assert b"".join(sink.writes) == body == sse_ref.read_range(
+        WANT_CIPHER[cipher], streams, stored, 0, len(body))
+    assert len(sink.writes) <= handed + 1
+    assert all(sink.writes)         # no empty write reaches the sink
+
+
+SPANS = {
+    "inside_one_package": (1000, 999),
+    "starts_and_ends_inside_packages": (3 * PKG + 17, 6 * PKG - 12),
+    "across_a_block_edge": (4 * MIB - 70000, 140001),
+    "across_the_first_part_edge": (E1 - PKG - 3, 2 * PKG + 9),
+    "across_three_parts": (E1 - 70000, SIZES[1] + 70010),
+    "the_last_byte": (sum(SIZES) - 1, 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SPANS))
+@pytest.mark.parametrize("cipher", CIPHERS)
+def test_open_of_a_range_equals_the_reference(host_lane, cipher, name):
+    lo, ln = SPANS[name]
+    streams, stored, body = sealed_by_the_reference(cipher, SIZES, 28)
+    for size in (4 * MIB, 3 * UNIT + 7):
+        sink = RecordingSink()
+        handed = open_through(cipher, streams, stored, lo, ln, size, sink)
+        assert b"".join(sink.writes) == body[lo:lo + ln] \
+            == sse_ref.read_range(WANT_CIPHER[cipher], streams, stored, lo,
+                                  ln)
+        assert len(sink.writes) <= handed + 1
+
+
+@pytest.mark.parametrize("cipher", CIPHERS)
+def test_single_stream_writer_keeps_no_view_of_its_input(host_lane, cipher):
+    """``DecryptWriter`` by itself (a single-PUT object): the caller's
+    buffer is overwritten after every ``write``; the straddling package
+    must have been copied, everything else opened before the return."""
+    (s,), stored, body = sealed_by_the_reference(cipher, (40 * PKG + 77,),
+                                                 29)
+    sink = RecordingSink()
+    dw = sse_mod.DecryptWriter(sink, s.key, s.iv, 0, 5, len(body) - 9, "b",
+                               "o", cipher=WANT_CIPHER[cipher])
+    size = 17 * UNIT + 1000     # one lane call of 16 and one of 1, a carry
+    pooled, handed = bytearray(size), 0
+    for at in range(0, len(stored), size):
+        piece = stored[at:at + size]
+        pooled[:len(piece)] = piece
+        dw.write(memoryview(pooled)[:len(piece)])
+        pooled[:] = bytes(size)
+        handed += 1
+    dw.finish()
+    assert b"".join(sink.writes) == body[5:-4]
+    assert len(sink.writes) <= handed + 1
+
+
+@pytest.mark.parametrize("cipher", CIPHERS)
+def test_bad_tag_in_a_blocks_last_package_releases_nothing_of_it(
+        host_lane, cipher):
+    """The release rule is a block's: 40 packages arrive in one write, the
+    flipped byte sits in the last of them (the third lane call); the 39
+    that verified before it stay unsent."""
+    streams, stored, body = sealed_by_the_reference(cipher, (100 * PKG,),
+                                                    30)
+    size = 40 * UNIT
+    bad = bytearray(stored)
+    bad[2 * size - 20] ^= 1         # the second block's package 39
+    sink = RecordingSink()
+    with pytest.raises(SSEDecryptError) as e:
+        open_through(cipher, streams, bad, 0, len(body), size, sink)
+    assert (e.value.bucket, e.value.object) == ("b", "o")
+    assert sink.writes == [body[:40 * PKG]]
+
+
+class CountingBody:
+    """A request body that says how it was read."""
+
+    def __init__(self, body: bytes):
+        import io
+        self.raw, self.reads = io.BytesIO(body), []
+
+    def read(self, n: int = -1) -> bytes:
+        self.reads.append(n)
+        return self.raw.read(n)
+
+
+@pytest.mark.parametrize("cipher", CIPHERS)
+def test_seal_reads_a_flush_of_body_at_a_time(host_lane, cipher):
+    """``EncryptReader`` asks its source for 16 packages (1 MiB) in one
+    read, not for a package a read, and what it seals is what the
+    reference seals package by package."""
+    (s,), stored, body = sealed_by_the_reference(cipher, (3 * MIB + 70001,),
+                                                 31)
+    src = CountingBody(body)
+    er = sse_mod.EncryptReader(src, s.key, s.iv, WANT_CIPHER[cipher])
+    out = bytearray(len(stored) + 100)
+    assert er.readinto(out) == len(stored) and bytes(out[:len(stored)]) \
+        == stored
+    assert src.reads[0] == sse_mod.FLUSH_PKGS * PKG
+    assert len(src.reads) <= 2 * (len(body) // MIB + 1)
+
+
 def counter(prefix: str, **labels) -> float:
     return sum(v for k, v in mx.counters_snapshot().items()
                if k.startswith(prefix)
@@ -473,7 +647,17 @@ def test_counters_and_stages(env):
                           cipher=short, op="seal")}
     bodies = parts_of(11, (5 * MIB, 100))
     env.upload("counted", bodies, SSE_S3)
+    opened = {"writes": counter("minio_tpu_workloads_sse_sink_writes_total",
+                                op="open"),
+              "bytes": counter("minio_tpu_workloads_sse_bytes_total",
+                               op="open")}
     assert env.c.request("GET", f"/{BUCKET}/counted").status_code == 200
+    # a whole-object GET hands the sink a block at a time, not a package
+    writes = counter("minio_tpu_workloads_sse_sink_writes_total",
+                     op="open") - opened["writes"]
+    mib = (counter("minio_tpu_workloads_sse_bytes_total", op="open")
+           - opened["bytes"]) / MIB
+    assert mib > 5 and 0 < writes / mib < 1
     assert counter("minio_tpu_multipart_parts_total", sse="S3") \
         == before["parts"] + 2
     assert counter("minio_tpu_multipart_completes_total", sse="S3") \

@@ -712,7 +712,9 @@ _HOT_PATH_FUNCS: dict[str, tuple[str, ...]] = {
     # lane (chacha kernel + batched numpy poly), not ad-hoc host calls
     "minio_tpu/crypto/sse.py": (
         "EncryptReader.readinto", "EncryptReader._fill",
-        "DecryptWriter.write", "DecryptWriter._open",
+        "DecryptWriter.write", "DecryptWriter.feed", "DecryptWriter._open",
+        "DecryptWriter.drain", "RangeDecryptWriter.write",
+        "_Staging.put", "_Staging.release",
     ),
     "minio_tpu/s3select/device.py": (
         "DeviceScan.rows", "DeviceScan._codes_for",
