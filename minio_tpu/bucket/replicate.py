@@ -34,7 +34,7 @@ interactive GETs.
 Replication lag (charge→replica-landed seconds) is measured through
 ``obs.latency.Window`` — the same percentile machinery behind every
 other latency metric — and surfaces as an SLO objective
-(``obs.slo``), loadgen verdicts, and the ``node_chaos`` bench extra.
+(``obs.slo``).
 """
 from __future__ import annotations
 

@@ -1,15 +1,15 @@
 """In-process multi-node topology harness (ROADMAP item 4): N
 ``dist.node.Node`` server processes' worth of cluster — separate HTTP
 listeners on localhost ports, storage REST RPC between them, dsync
-quorum locks — inside ONE test/bench/loadgen process, with node-level
+quorum locks — inside ONE test process, with node-level
 chaos hooks (:mod:`minio_tpu.fault.node`) pre-wired: every node is
 registered for ``node_kill``/``node_restart`` and carries the restart
 spec a fresh ``Node`` needs.
 
-This is the topology the node chaos matrix (tests/test_node_chaos.py),
-``tools/loadgen.py --topology N`` and the ``node_chaos`` bench extra
-all stand on. It is NOT a deployment surface — a real cluster runs one
-process per node (tests/test_cluster_heal_oop.py covers that shape).
+This is the topology the node chaos matrix (tests/test_node_chaos.py)
+and tests/test_replication.py stand on. It is NOT a deployment
+surface — a real cluster runs one process per node
+(tests/test_cluster_heal_oop.py covers that shape).
 """
 from __future__ import annotations
 

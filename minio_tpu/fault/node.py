@@ -17,7 +17,7 @@ Two kinds of primitive compose here:
   background services (peers see connection-refused — the same signal
   a SIGKILL'd process emits) and :func:`node_restart` brings a fresh
   ``Node`` up over the same endpoints/port. Registration is explicit
-  (``register_node``) because only test/loadgen topologies run several
+  (``register_node``) because only test topologies run several
   nodes in one process; a real deployment kills processes.
 
 Every rule armed through here is tagged so :func:`clear_node_faults`
@@ -37,7 +37,7 @@ _nodes_lock = threading.Lock()
 
 def register_node(name: str, node) -> None:
     """Make an in-process ``dist.node.Node`` addressable by
-    :func:`node_kill`/:func:`node_restart` (test/loadgen topologies)."""
+    :func:`node_kill`/:func:`node_restart` (test topologies)."""
     with _nodes_lock:
         _nodes[name] = node
 

@@ -4,8 +4,8 @@ The reference keeps its hot math in assembly-backed Go modules (SURVEY.md
 §2.10); here one combined libnative.so (pipeline.cpp, which includes
 gf256_simd.cpp + highwayhash.cpp) provides:
 
-- the CPU GF(256) codec (fallback path + the measured AVX2 baseline for
-  bench.py's vs_baseline),
+- the CPU GF(256) codec (fallback path + the AVX2 baseline the device
+  codec is compared with),
 - AVX2 HighwayHash-256 (bitrot digests),
 - the fused per-block data-plane calls ``mt_put_block`` / ``mt_get_block``
   (split+encode+hash+frame, verify+assemble) and

@@ -1202,7 +1202,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             # heal-shard rebuilds ride the INTERACTIVE device lane
             # (ISSUE 13): bounded small batches + deadline-aware sizing
             # + async completion instead of 20-second coalesced flushes
-            # (BENCH_r05's device heal p99). The op-based default in
+            # (round-5 record, a set-up that is gone). The op-based default in
             # runtime/dispatch covers the rebuild ops already; pinning
             # the stream here makes the routing explicit and keeps any
             # future heal-path dispatch op on the latency lane too.

@@ -1,8 +1,8 @@
 """Standing gap-attribution: per-op pipeline stage breakdowns across ALL
-requests, not just the sampled/bench-armed ones.
+requests, not just the ones a caller armed.
 
 PR 7's ``obs/stages.py`` gave one request a ``StageTimes`` collector
-(armed by bench.py / tests); this module arms one for EVERY object
+(armed by its caller); this module arms one for EVERY object
 operation and aggregates the results into standing per-op reports:
 
 * per-stage p50/p99 seconds over the last minute (the same

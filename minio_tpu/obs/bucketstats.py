@@ -550,7 +550,7 @@ def report(now: float | None = None) -> dict:
 def metric_lines(now: float | None = None) -> list[str]:
     """The ``minio_tpu_bucket_*`` exposition lines (cardinality ≤
     (top_n + 1 fold row) x the fixed api/class taxonomies — the bound
-    the loadgen ``bucket_metrics_bounded_ok`` verdict measures). Label
+    tests/test_bucketstats.py holds under concurrent traffic). Label
     values are registry keys, already folded at admission."""
     from .metrics import _esc
     qs = (0.5, 0.99)
@@ -663,7 +663,7 @@ def metric_lines(now: float | None = None) -> list[str]:
 
 
 def reset() -> None:
-    """Drop the whole registry (tests / loadgen isolation)."""
+    """Drop the whole registry (test isolation)."""
     global _folds, _evictions, _reconciles, _last_drift
     global _cluster_bytes, _cluster_objects, _history, _history_loaded
     with _lock:

@@ -30,7 +30,8 @@ host profiler (ISSUE 16). Four pillars:
   (``GET /minio/admin/v3/device?trace=<seconds>``).
 * **Roofline attribution.** Per-op achieved GiB/s (bytes moved over
   estimated device-seconds) vs. the calibrated kernel-plane ceiling
-  (BENCH_r05: 179 GiB/s encode / 183 GiB/s reconstruct) as
+  (179 GiB/s encode / 183 GiB/s reconstruct: round-5 record, a set-up
+  that is gone, git history; not measured on the current chip) as
   ``minio_tpu_kernel_roofline_ratio{op}`` — "the mesh scaled 6×"
   becomes a per-kernel measured claim.
 
@@ -52,9 +53,9 @@ import time
 #: compile-storm defaults (overridable via the ``device_obs`` KVS)
 DEFAULT_STORM_THRESHOLD = 8.0
 DEFAULT_STORM_WINDOW_S = 30.0
-#: calibrated roofline ceilings, GiB/s (BENCH_r05 kernel plane: encode
-#: 179, reconstruct 183 on the reference TPU host; operators re-pin via
-#: config after running bench.py on their own part)
+#: roofline ceilings, GiB/s (encode 179, reconstruct 183: round-5
+#: record, a set-up that is gone, git history; operators re-pin via
+#: config after measuring their own part)
 DEFAULT_ROOFLINE_ENCODE_GIBS = 179.0
 DEFAULT_ROOFLINE_RECONSTRUCT_GIBS = 183.0
 #: cap on distinct (op, shape-signature) compile rows — signatures are

@@ -9,9 +9,7 @@ one slot index, a handful of increments under a per-window lock that is
 never held across I/O. Reads (metrics scrapes, admin endpoints) merge at
 most ``window_s`` slots. This is the window behind
 ``minio_tpu_disk_latency_seconds`` and
-``minio_tpu_kernel_op_latency_seconds`` — and ``bench.py`` reports its
-heal-shard percentiles through the very same class, so the benchmark and
-the production metric can never diverge in method.
+``minio_tpu_kernel_op_latency_seconds``.
 
 Every time-taking function accepts an explicit ``now`` (monotonic
 seconds) so tests can fake timestamps and verify bucket expiry.
@@ -242,9 +240,9 @@ def get_window(family: str, **labels) -> Window:
 
 
 def reset_window(family: str, **labels) -> Window:
-    """Swap in a fresh window for this series and return it (bench.py
-    uses this so each measured configuration reads a clean window — the
-    same object the metrics exposition would serve)."""
+    """Swap in a fresh window for this series and return it (a caller
+    that measures one configuration reads a clean window — the same
+    object the metrics exposition would serve)."""
     key = _key(family, labels)
     w = Window()
     with _reg_lock:

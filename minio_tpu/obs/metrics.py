@@ -740,7 +740,7 @@ def _exemplar_fetchable(trace_id: str) -> bool:
 def _g_kernel(server) -> list[str]:
     """Per-op dispatch/heal kernel latency percentiles + GiB/s — the
     paper's headline metric (erasure encode/reconstruct GiB/s, p99
-    heal-shard latency) served online instead of only by bench.py.
+    heal-shard latency) served online.
     The p50/p99 gauges keep their names for dashboard compatibility;
     the same windows ALSO render as real histograms
     (minio_tpu_kernel_op_duration_seconds / minio_tpu_heal_shard_

@@ -738,9 +738,9 @@ def delta_report(before: dict, n: int = 10) -> dict:
     """Attribution report over the base aggregate's growth since
     ``before`` (an :func:`agg_snapshot`). This is the ZERO-ADDED-COST
     window: it rides the always-on sampler instead of attaching a
-    capture, so a measured section (bench par8, the loadgen scanner
-    cycle) pays nothing beyond the standing base rate — and crucially,
-    a window and its surrounding baseline carry the identical sampling
+    capture, so a measured section (a parallel-GET burst, a forced
+    scanner cycle) pays nothing beyond the standing base rate — and
+    crucially, a window and its surrounding baseline carry the identical sampling
     tax, so before/during comparisons stay unbiased."""
     after = agg_snapshot(full="stacks" in before)
     samples = max(0, after["samples"] - before["samples"])

@@ -33,9 +33,8 @@ config KVS subsystem; latency thresholds left empty are seeded from
 plane and the dispatch scheduler judge "slow" identically by default.
 
 Surfaced as the ``minio_tpu_slo_*`` metric family on
-``/minio/v2/metrics``, inside ``GET /minio/admin/v3/health`` (the
-cluster snapshot), and as the verdict section of the ``tools/loadgen``
-scale-harness report (docs/observability.md "SLO plane & health
+``/minio/v2/metrics`` and inside ``GET /minio/admin/v3/health`` (the
+cluster snapshot; docs/observability.md "SLO plane & health
 snapshot").
 """
 from __future__ import annotations
@@ -360,7 +359,7 @@ def report(now: float | None = None) -> dict:
 
 
 def reset() -> None:
-    """Drop every window (tests / loadgen isolation): earlier suite
+    """Drop every window (test isolation): earlier suite
     traffic must not bleed into a fresh measurement's ratios."""
     global _gen
     with _lock:

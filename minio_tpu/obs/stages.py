@@ -1,8 +1,8 @@
 """Per-request pipeline stage accounting — where a PUT's wall time goes.
 
 A ``StageTimes`` collector rides a contextvar for the duration of one
-object operation (armed by bench.py's ``put_stage_breakdown`` and by
-tests); the data-plane hot paths charge seconds to named stages ONLY when
+object operation (armed by ``obs/attribution.py`` and by tests); the
+data-plane hot paths charge seconds to named stages ONLY when
 a collector is armed, so production requests pay one contextvar read per
 block and nothing else. Pool workers receive the collector by closure
 (contextvars don't follow executor submits), and ``add`` is a GIL-atomic

@@ -3,8 +3,9 @@ verify+reconstruct launch, and the hash lane of the fused encode+hash PUT
 flush (BENCH config 4 / ROADMAP item 1).
 
 Why a third implementation: the jnp kernel (mur3_jax) is correct but stuck
-at ~41-47 GiB/s standalone and ~34 fused, which BENCH_r05 shows is the
-whole fused ceiling (reconstruct alone runs 183). Its limiting shape is the
+at ~41-47 GiB/s standalone and ~34 fused, which was the whole fused
+ceiling (reconstruct alone ran 183; round-5 record, a set-up that is
+gone: git history). Its limiting shape is the
 scan state: every h lane is a ``[2, N]`` array — 2 seed instances on the
 sublane axis — so each VPU op runs at 2/8 sublane occupancy, and the
 per-packet tuple-of-streams slicing adds relayout traffic. Here the batch

@@ -40,7 +40,8 @@ the scanners/healers; interactive buckets flush first.
 
 Interactive device lane (ISSUE 13, ROADMAP item 2): the coalescing
 discipline above is throughput-tuned — at conc 128 it put device
-heal-shard p99 at 20.3 s vs 14 ms on CPU (BENCH_r05), because every
+heal-shard p99 at 20.3 s vs 14 ms on CPU (round-5 record, a set-up
+that is gone: git history), because every
 flush blocks toward max-batch buckets and the readback parks a
 completer thread. Heal-shard rebuilds and degraded-GET reconstruct
 ('masked'/'fused' ops, overridable via ``qos.device_stream``) therefore

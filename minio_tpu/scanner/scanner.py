@@ -79,7 +79,7 @@ class DataScanner:
 
         Always runs as QoS class ``background`` — applied HERE rather
         than in the periodic loop so a directly-forced cycle (admin
-        trigger, the loadgen scale harness, tests) gets the same
+        trigger, tests) gets the same
         spill-first dispatch treatment as a scheduled one and can never
         stall interactive traffic by omission."""
         from .. import qos

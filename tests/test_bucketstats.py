@@ -307,3 +307,67 @@ def test_metrics_group_scrape_carries_bucket_families():
     text = mx.render_prometheus(_Srv(), scope="node").decode()
     assert "minio_tpu_bucket_stats_tracked" in text
     assert 'bucket="scraped"' in text
+
+
+def test_concurrent_tenants_over_http_stay_bounded(tmp_path, monkeypatch):
+    """More tenants than the cap, charged by concurrent requests through
+    a live server: the registry tracks at most top_n rows, the scrape
+    carries at most top_n + 1 bucket label values (the fold row), and
+    no request is lost between the rows."""
+    import threading
+    import time
+
+    from minio_tpu.objectlayer import ErasureObjects
+    from minio_tpu.server import S3Server
+    from minio_tpu.storage import XLStorage
+    from s3client import S3Client
+    monkeypatch.setenv("MINIO_TPU_BUCKETSTATS_TOP_N", "4")
+    obj = ErasureObjects([XLStorage(str(tmp_path / f"d{i}"))
+                          for i in range(4)], default_parity=2)
+    srv = S3Server(obj, "127.0.0.1", 0, access_key="bsak",
+                   secret_key="bssecret1")
+    srv.start_background()
+    tenants = [f"tenant-{i:02d}" for i in range(12)]
+    codes: list[int] = []
+
+    def client(wid: int) -> None:
+        c = S3Client(srv.endpoint(), "bsak", "bssecret1")
+        for b in tenants[wid::4]:
+            codes.append(c.put_bucket(b).status_code)
+            for j in range(3):
+                codes.append(c.put_object(b, f"o{j}", b"x" * 512)
+                             .status_code)
+                codes.append(c.get_object(b, f"o{j}").status_code)
+    try:
+        ths = [threading.Thread(target=client, args=(w,), daemon=True,
+                                name=f"tenant-client-{w}")
+               for w in range(4)]
+        for t in ths:
+            t.start()
+        for t in ths:
+            t.join(timeout=60)
+        assert codes and set(codes) == {200}, sorted(set(codes))
+
+        def charged():
+            return sum(r["requests_total"]
+                       for r in bs.report()["buckets"].values())
+        # a request is charged after its reply has gone out
+        deadline = time.monotonic() + 10
+        while charged() != len(codes) and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert charged() == len(codes)
+        rep = bs.report()
+        assert rep["tracked"] <= 4 and rep["folds"] > 0, rep
+        import requests
+        text = requests.get(srv.endpoint() + "/minio/v2/metrics/cluster",
+                            timeout=30).text
+        labels = {line.split('bucket="', 1)[1].split('"', 1)[0]
+                  for line in text.splitlines()
+                  if line.startswith("minio_tpu_bucket_")
+                  and 'bucket="' in line
+                  # the replication monitor's family: process-global,
+                  # named from bucket configs, not from requests
+                  and not line.startswith("minio_tpu_bucket_bandwidth_")}
+        assert bs.OVERFLOW in labels and len(labels) <= 5, labels
+    finally:
+        srv.shutdown()

@@ -334,3 +334,65 @@ def test_failed_put_rollback_consistent(tmp_path, monkeypatch):
     assert store.failed_puts == 1
     with store._count_lock:
         assert store._count == 0       # the reservation was rolled back
+
+
+def test_dead_target_queue_caps_and_puts_never_stall(tmp_path):
+    """The target is down for good and its queue is small: concurrent
+    PUTs all answer 200 without waiting for it, the queue never holds
+    more than its limit, and every event past it is counted, by the
+    store and by the exported drop counter."""
+    from minio_tpu.obs import metrics as mx
+    limit, clients, puts = 8, 4, 10
+    srv = _server(tmp_path, "http://127.0.0.1:9/nobody-listens")
+    arn = "arn:minio:sqs:us-east-1:t1:webhook"
+    n = srv.ensure_notifier()
+    n.stores[arn].stop()
+    # built directly for the small limit; a long retry base keeps the
+    # doomed sender quiet during the test
+    store = n.stores[arn] = QueueStore(
+        str(tmp_path / "small-queue"), n.targets[arn].send, limit=limit,
+        retry_base_s=5.0).start()
+    counter = f'minio_tpu_notify_events_dropped_total{{target="{arn}"}}'
+    dropped0 = mx.counters_snapshot().get(counter, 0)
+    slowest: list[float] = []
+    codes: list[int] = []
+    over: list[int] = []
+
+    def client(wid: int) -> None:
+        c = S3Client(srv.endpoint(), AK, SK)
+        for j in range(puts):
+            t0 = time.monotonic()
+            codes.append(c.put_object("evq", f"w{wid}-{j}", b"body")
+                         .status_code)
+            slowest.append(time.monotonic() - t0)
+            queued = len(store._pending())
+            if queued > limit:
+                over.append(queued)
+    try:
+        c = S3Client(srv.endpoint(), AK, SK)
+        assert c.put_bucket("evq").status_code == 200
+        xml = ("<NotificationConfiguration><QueueConfiguration>"
+               f"<Queue>{arn}</Queue><Event>s3:ObjectCreated:*</Event>"
+               "</QueueConfiguration></NotificationConfiguration>")
+        assert c.request("PUT", "/evq", query={"notification": ""},
+                         body=xml.encode()).status_code == 200
+        ths = [threading.Thread(target=client, args=(w,), daemon=True,
+                                name=f"event-client-{w}")
+               for w in range(clients)]
+        for t in ths:
+            t.start()
+        for t in ths:
+            t.join(timeout=60)
+        assert codes == [200] * (clients * puts), codes
+        assert max(slowest) < 30.0, max(slowest)   # no PUT waits on it
+        assert not over, over
+        # an event is routed after its PUT has been answered
+        assert _wait(lambda: store.failed_puts >= clients * puts - limit)
+        assert store.delivered == 0
+        assert len(store._pending()) == limit
+        assert store.failed_puts == clients * puts - limit
+        assert mx.counters_snapshot().get(counter, 0) - dropped0 == \
+            store.failed_puts
+    finally:
+        store.stop()
+        srv.shutdown()

@@ -74,7 +74,7 @@ def test_parallel_get_no_collapse(k, m):
             ol.put_object("b", f"p{j}", io.BytesIO(body), OBJ_SIZE)
 
         def read_one(j):
-            # the zero-copy accessor — the path bench.py's par8 GET uses
+            # the zero-copy accessor
             got = ol.get_object_buffer("b", f"p{j}")
             assert got == body, f"payload mismatch on p{j}"
 

@@ -1068,7 +1068,7 @@ def test_gl018_home_module_and_foreign_paths_exempt():
         ctx_for(src, path="minio_tpu/obs/bucketstats.py"))
     # outside minio_tpu/ (tools, tests) out of scope
     assert not checkers.check_bounded_request_labels(
-        ctx_for(src, path="tools/loadgen.py"))
+        ctx_for(src, path="tools/graftlint/checkers.py"))
     # same source elsewhere under minio_tpu/ is a finding
     assert checkers.check_bounded_request_labels(
         ctx_for(src, path="minio_tpu/obs/health.py"))

@@ -324,6 +324,87 @@ def test_heal_object_missing_disk(tmp_path):
     assert obj2.get_object_bytes("b", "o") == data
 
 
+def _empty(disk, bucket, key=None):
+    """What replacing a drive (or losing one object's shards on it)
+    leaves: the bucket directory stays, its contents are gone."""
+    base = os.path.join(disk.base, bucket)
+    for name in ([key] if key else os.listdir(base)):
+        shutil.rmtree(os.path.join(base, name))
+
+
+@pytest.mark.parametrize("size", [5 << 18, 10 << 20],
+                         ids=["1.25MiB", "10MiB"])
+@pytest.mark.parametrize("route", ["sequence", "on_read"])
+@pytest.mark.parametrize("k,m", [(4, 2), (8, 4)], ids=["4p2", "8p4"])
+def test_replaced_drive_heals_and_reads_back_with_exactly_k(
+        tmp_path, k, m, route, size):
+    """A drive is replaced (emptied), the set stays under reads, and the
+    drive is rebuilt by an admin heal sequence or by heal-on-read alone.
+    Then m OTHER drives lose every shard, which leaves exactly k, the
+    healed ones among them: one shard a heal left wrong or missing is a
+    failed GET here (the read-back of the benchmark's heal-drive cells,
+    which at 4+2 leaves exactly k as well)."""
+    import threading
+    import time
+
+    from minio_tpu.runtime.dispatch import global_queue
+    from minio_tpu.scanner.healseq import HealSequence
+    from minio_tpu.scanner.mrf import MRFHealer
+    disks = mk_disks(tmp_path, k + m)
+    obj = ErasureObjects(disks, default_parity=m)
+    obj.make_bucket("b")
+    bodies = {f"obj-{i:05d}": rng_bytes(size, seed=100 + i)
+              for i in range(4)}
+    for key, body in bodies.items():
+        obj.put_object("b", key, io.BytesIO(body), len(body))
+    _empty(disks[0], "b")
+    mrf = MRFHealer(obj)
+    lane = global_queue().stats()["interactive_lane"]["items"]
+    wrong: list[str] = []
+
+    def read_all():
+        for key, body in bodies.items():
+            if obj.get_object_bytes("b", key) != body:
+                wrong.append(key)
+
+    def settle(busy):
+        deadline = time.monotonic() + 120
+        while busy() and time.monotonic() < deadline:
+            time.sleep(0.05)
+
+    try:
+        if route == "sequence":
+            seq = HealSequence(obj, "b").start()
+            readers = [threading.Thread(target=read_all, daemon=True,
+                                        name=f"heal-reader-{i}")
+                       for i in range(2)]
+            for t in readers:
+                t.start()
+            for t in readers:
+                t.join(timeout=120)
+            settle(lambda: seq.status == "running")
+            assert (seq.status, seq.scanned, seq.healed, seq.failed) == \
+                ("done", len(bodies), len(bodies), 0), seq.summary()
+        else:
+            obj.on_partial = mrf.add_partial
+            mrf.start()
+            read_all()       # every GET sees drive 0 short and says so
+            settle(lambda: mrf.healed < len(bodies))
+            assert mrf.healed >= len(bodies), mrf.stats()
+            assert mrf.failed == 0, mrf.stats()
+    finally:
+        mrf.stop()
+    assert not wrong, wrong
+    # the rebuilds rode the dispatch queue's interactive lane
+    assert global_queue().stats()["interactive_lane"]["items"] > lane
+    for key in bodies:
+        disks[0].read_version("b", key)     # xl.meta back on the drive
+        for j in range(1, m + 1):
+            _empty(disks[j], "b", key)
+    for key, body in bodies.items():
+        assert obj.get_object_bytes("b", key) == body, key
+
+
 def test_heal_object_corrupt_shard(tmp_path):
     disks = mk_disks(tmp_path, 6)
     obj = ErasureObjects(disks, default_parity=2)

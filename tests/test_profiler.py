@@ -72,8 +72,7 @@ def test_hot_spin_attribution(prof):
     tag registry. A unique tag keys the assertion: whatever thread zoo
     the rest of the suite left running, only the injected workers
     carry it, so the verdict is deterministic (in a quiet process the
-    spin is also the GLOBAL top frame — demonstrated by the loadgen /
-    bench evidence channels, not pinned here)."""
+    spin is also the GLOBAL top frame — not pinned here)."""
     stop = threading.Event()
     ths = _spin_threads(6, stop, cls="qos-test-hotspin",
                         op="op-test-hotspin")
@@ -314,3 +313,67 @@ def test_overhead_under_two_percent(prof, tmp_path):
     st = profiler.status()
     assert st["running"] and st["samples_total"] > 0
     assert st["overhead_ratio"] < 0.02, st
+
+
+def test_mixed_load_with_scanner_cycle_profile_and_lock_order(prof, srv):
+    """Concurrent mixed PUT/GET/LIST/DELETE against a live server with
+    one scanner cycle forced mid-run: every request is admitted under
+    its class, the lock-order detector (on for the whole suite,
+    tests/conftest.py) reports nothing new, and the always-on profile
+    of the run is well-formed: samples taken, subsystems and roles
+    named, every share (the scanner's among them) a fraction."""
+    from minio_tpu.scanner.scanner import DataScanner
+    from s3client import S3Client
+    assert lockrank.enabled()
+    reports0 = len(lockrank.reports())
+    admitted0 = dict(srv.qos_admission.stats()["admitted"])
+    S3Client(srv.endpoint(), AK, SK).put_bucket("mixed")
+    stop = threading.Event()
+    codes: set[int] = set()
+
+    def client(wid: int) -> None:
+        c = S3Client(srv.endpoint(), AK, SK)
+        seq = 0
+        while not stop.is_set():
+            key = f"w{wid}/o{seq % 8}"
+            seq += 1
+            codes.add(c.put_object("mixed", key, b"m" * 2048).status_code)
+            codes.add(c.get_object("mixed", key).status_code)
+            codes.add(c.request("GET", "/mixed",
+                                query={"list-type": "2",
+                                       "prefix": f"w{wid}/"}).status_code)
+            if seq % 3 == 0:
+                codes.add(c.delete_object("mixed", key).status_code)
+
+    cycle: list[dict] = []
+    scan = threading.Thread(
+        target=lambda: cycle.append(
+            DataScanner(srv.obj, sleep_per_object=0).scan_cycle()),
+        daemon=True, name="data-scanner-mixed-load")
+    ths = [threading.Thread(target=client, args=(w,), daemon=True,
+                            name=f"mixed-client-{w}") for w in range(4)]
+    for t in ths:
+        t.start()
+    time.sleep(0.5)
+    scan.start()
+    scan.join(timeout=60)
+    time.sleep(0.5)
+    stop.set()
+    for t in ths:
+        t.join(timeout=60)
+    assert cycle and cycle[0]["buckets"].get("mixed"), cycle
+    assert codes <= {200, 204}, codes
+    # object requests were admitted as interactive, listings as control
+    admitted = srv.qos_admission.stats()["admitted"]
+    for cls in ("interactive", "control"):
+        assert admitted.get(cls, 0) > admitted0.get(cls, 0), admitted
+    assert len(lockrank.reports()) == reports0, \
+        lockrank.reports()[reports0:]
+    rep = profiler.snapshot_report(n=8)
+    assert rep["samples"] > 0, rep
+    for family in ("subsystems", "roles"):
+        assert rep[family], rep
+        assert all(isinstance(k, str) and k and 0.0 <= v <= 1.0
+                   for k, v in rep[family].items()), rep[family]
+    assert "http-worker" in rep["roles"], rep["roles"]
+    assert isinstance(rep["lock_contention"], list)

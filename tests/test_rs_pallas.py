@@ -1,5 +1,6 @@
 """Pallas kernel golden tests (interpret mode on the CPU mesh; the same
-kernel compiles natively on TPU — exercised by bench.py / __graft_entry__)."""
+kernel compiles natively on TPU — tests/test_chip_compile.py compiles it for
+the chip)."""
 import numpy as np
 import pytest
 

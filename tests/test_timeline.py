@@ -322,8 +322,8 @@ def test_attribution_matches_hand_computed_fixture():
 
 
 def test_attribution_chains_to_outer_collector():
-    """bench.py's put_stage_breakdown arms an outer collector; the
-    always-on attribution must feed it, not starve it."""
+    """A caller may arm an outer collector of its own; the always-on
+    attribution must feed it, not starve it."""
     with stages.collect() as outer:
         with attribution.observed("put"):
             inner = stages.active()

@@ -1242,8 +1242,8 @@ def check_thread_names(ctx: FileCtx) -> list[Finding]:
     """GL016: the continuous profiler (``obs/profiler.py``) classifies
     every sample by thread ROLE, resolved through a name registry — an
     unnamed ``threading.Thread`` can only ever classify as ``other``,
-    silently degrading every profile and the loadgen/bench subsystem
-    shares built on it. Any ``Thread(...)`` construction under
+    silently degrading every profile and the subsystem shares built
+    on it. Any ``Thread(...)`` construction under
     ``minio_tpu/`` without a ``name=`` keyword is a finding (Thread
     SUBCLASS constructions pass their name to ``super().__init__`` and
     are matched by their own class name, so they stay out of scope)."""
