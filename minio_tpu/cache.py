@@ -30,6 +30,7 @@ import threading
 import time
 
 from .objectlayer import datatypes as dt
+from .objectlayer.interface import ObjectLayer
 
 CACHE_META = "cache.json"
 CACHE_DATA = "part.1"
@@ -377,6 +378,11 @@ class CacheObjects:
                 if meta is not None:
                     return self._oi_from_meta(bucket, object, meta)
             raise
+
+    # the body has to come through the cache: the layers' default (this
+    # get_object_info, then this get_object), not the inner layer's
+    # handle, which __getattr__ would hand out
+    get_object_n_info = ObjectLayer.get_object_n_info
 
     def put_object(self, bucket, object, stream, size, opts=None):
         oi = self.inner.put_object(bucket, object, stream, size, opts)

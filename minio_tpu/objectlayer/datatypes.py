@@ -331,6 +331,10 @@ class MultipartInfo:
     upload_id: str = ""
     initiated: float = field(default_factory=time.time)
     user_defined: dict[str, str] = field(default_factory=dict)
+    #: the quorum FileInfo behind this record, for the layer that read
+    #: it: ``put_object_part(..., upload=info)`` writes the part from it
+    #: and makes no metadata pass of its own
+    held: object = field(default=None, repr=False, compare=False)
 
 
 @dataclass

@@ -18,6 +18,7 @@ from ..erasure import (DEFAULT_BITROT_ALGO, Erasure, new_bitrot_reader,
                        new_bitrot_writer)
 from ..obs import attribution as _attr
 from ..obs import latency as _lat
+from ..obs import metrics as _mx
 from ..obs import spans as _spans
 from ..obs import trace as _trc
 from .. import qos as _qos
@@ -89,6 +90,26 @@ def check_names(bucket: str, object: str = ""):
         if object.startswith("/") or ".." in object.split("/") \
                 or object.endswith("/"):
             raise dt.ObjectNameInvalid(bucket, object)
+
+
+class HeldObject:
+    """One object version as one quorum metadata pass found it: the
+    ObjectInfo for the headers and, through ``read``, the body from the
+    same FileInfos (what get_object_n_info returns beside the info)."""
+
+    __slots__ = ("_layer", "info", "fi", "fis", "errs")
+
+    def __init__(self, layer: "ErasureObjects", info: ObjectInfo,
+                 fi: FileInfo, fis: list, errs: list):
+        self._layer = layer
+        self.info = info
+        self.fi = fi
+        self.fis = fis
+        self.errs = errs
+
+    def read(self, writer, offset: int = 0, length: int = -1) -> ObjectInfo:
+        return self._layer.get_object(self.info.bucket, self.info.name,
+                                      writer, offset, length, held=self)
 
 
 class ErasureObjects(MultipartMixin, ObjectLayer):
@@ -570,10 +591,12 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
     # --- get ---------------------------------------------------------------
 
     def _read_quorum_fileinfo(self, bucket: str, object: str,
-                              version_id: str = "", read_data: bool = False
-                              ) -> tuple[FileInfo, list, list]:
+                              version_id: str = "", read_data: bool = False,
+                              *, op: str) -> tuple[FileInfo, list, list]:
         """(quorum FileInfo, fis, errs) — getObjectFileInfo,
-        cmd/erasure-object.go:387."""
+        cmd/erasure-object.go:387. ``op`` names the caller on the counter
+        of passes (a GET takes one, shared by its headers and its body)."""
+        _mx.inc("minio_tpu_objectlayer_quorum_meta_reads_total", op=op)
         disks = self.disks
         # "" = latest; "null" resolves to the unversioned entry inside the
         # journal (XLMeta.find_version) — do NOT collapse it to latest here
@@ -588,41 +611,17 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         fi = find_file_info_in_quorum(fis, read_quorum)
         return fi, fis, errs
 
-    def get_object_info(self, bucket: str, object: str,
-                        opts: ObjectOptions = None) -> ObjectInfo:
-        opts = opts or ObjectOptions()
+    def _stat_object(self, bucket: str, object: str, opts: ObjectOptions,
+                     read_data: bool, op: str) -> "HeldObject":
+        """Names, bucket, ONE quorum metadata pass and the delete-marker
+        rules: what HEAD and GET have in common."""
         check_names(bucket, object)
         self.get_bucket_info(bucket)
         try:
-            fi, _, _ = self._read_quorum_fileinfo(
-                bucket, object, opts.version_id)
+            fi, fis, errs = self._read_quorum_fileinfo(
+                bucket, object, opts.version_id, read_data, op=op)
         except Exception as e:  # noqa: BLE001
             raise to_object_err(e, bucket, object) from e
-        if fi.deleted:
-            if not opts.version_id:
-                raise dt.ObjectNotFound(bucket, object)
-            raise dt.MethodNotAllowed(bucket, object)
-        return ObjectInfo.from_file_info(
-            fi, bucket, object,
-            opts.versioned or bool(opts.version_id) or bool(fi.version_id))
-
-    def get_object(self, bucket: str, object: str, writer, offset: int = 0,
-                   length: int = -1, opts: ObjectOptions = None
-                   ) -> ObjectInfo:
-        with _spans.span("objectlayer.get_object", bucket=bucket,
-                         object=object), _attr.observed("get"), \
-                _qos.lane_affinity(self._lane_key):
-            return self._get_object_inner(bucket, object, writer, offset,
-                                          length, opts)
-
-    def _get_object_inner(self, bucket: str, object: str, writer,
-                          offset: int = 0, length: int = -1,
-                          opts: ObjectOptions = None) -> ObjectInfo:
-        opts = opts or ObjectOptions()
-        check_names(bucket, object)
-        self.get_bucket_info(bucket)
-        fi, fis, errs = self._read_quorum_fileinfo(
-            bucket, object, opts.version_id, read_data=True)
         if fi.deleted:
             if not opts.version_id:
                 raise dt.ObjectNotFound(bucket, object)
@@ -630,6 +629,44 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         oi = ObjectInfo.from_file_info(
             fi, bucket, object,
             opts.versioned or bool(opts.version_id) or bool(fi.version_id))
+        return HeldObject(self, oi, fi, fis, errs)
+
+    def get_object_info(self, bucket: str, object: str,
+                        opts: ObjectOptions = None) -> ObjectInfo:
+        return self._stat_object(bucket, object, opts or ObjectOptions(),
+                                 read_data=False, op="head").info
+
+    def get_object_n_info(self, bucket: str, object: str,
+                          opts: ObjectOptions = None
+                          ) -> tuple[ObjectInfo, "HeldObject"]:
+        """The ObjectInfo and the body behind it from ONE quorum metadata
+        pass (reference GetObjectNInfo, cmd/erasure-object.go: one
+        getObjectFileInfo, then the reader over the same FileInfo). The
+        handle's ``read(writer, offset, length)`` streams from the
+        FileInfos held here: it serves the version the ObjectInfo
+        describes or fails (an overwrite in between purges that version's
+        data dir), never another version's bytes."""
+        held = self._stat_object(bucket, object, opts or ObjectOptions(),
+                                 read_data=True, op="get")
+        return held.info, held
+
+    def get_object(self, bucket: str, object: str, writer, offset: int = 0,
+                   length: int = -1, opts: ObjectOptions = None,
+                   held: "HeldObject" = None) -> ObjectInfo:
+        """Every body read of the set passes here. ``held`` is what
+        ``get_object_n_info`` found for this object (its ``read`` calls
+        in with it): the body comes from that pass and none is made."""
+        with _spans.span("objectlayer.get_object", bucket=bucket,
+                         object=object), _attr.observed("get"), \
+                _qos.lane_affinity(self._lane_key):
+            if held is None:
+                held = self.get_object_n_info(bucket, object, opts)[1]
+            return self._get_object_inner(held, writer, offset, length)
+
+    def _get_object_inner(self, held: "HeldObject", writer,
+                          offset: int = 0, length: int = -1) -> ObjectInfo:
+        oi, fi, fis, errs = held.info, held.fi, held.fis, held.errs
+        bucket, object = oi.bucket, oi.name
         if length < 0:
             length = fi.size - offset
         if offset < 0 or length < 0 or offset + length > fi.size:
@@ -1042,8 +1079,8 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         to every disk would make all disks claim the same shard index and
         permanently break read quorum."""
         with self._locked(bucket, object):
-            fi, fis, _ = self._read_quorum_fileinfo(bucket, object,
-                                                    version_id)
+            fi, fis, _ = self._read_quorum_fileinfo(
+                bucket, object, version_id, op="update")
             if fi.deleted:
                 raise dt.MethodNotAllowed(bucket, object)
             meta = mutate(fi, dict(fi.metadata))
@@ -1096,8 +1133,8 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
     def get_object_tags(self, bucket: str, object: str,
                         opts: ObjectOptions = None) -> str:
         opts = opts or ObjectOptions()
-        fi, _, _ = self._read_quorum_fileinfo(bucket, object,
-                                              opts.version_id)
+        fi, _, _ = self._read_quorum_fileinfo(
+            bucket, object, opts.version_id, op="tags")
         if fi.deleted:
             raise dt.MethodNotAllowed(bucket, object)
         return fi.metadata.get(self.TAGS_KEY, "")

@@ -12,6 +12,26 @@ from .datatypes import (BucketInfo, CompletePart, DeletedObject,
                         ObjectInfo, ObjectOptions, PartInfo)
 
 
+class ObjectBody:
+    """The body half of ``get_object_n_info`` for a layer that holds no
+    metadata between its two calls: ``read`` is the layer's own
+    ``get_object``. ErasureObjects returns a handle of its own
+    (``HeldObject``) that reads from the FileInfos its one pass found."""
+
+    __slots__ = ("_layer", "_bucket", "_object", "_opts")
+
+    def __init__(self, layer, bucket: str, object: str,
+                 opts: ObjectOptions = None):
+        self._layer = layer
+        self._bucket = bucket
+        self._object = object
+        self._opts = opts
+
+    def read(self, writer, offset: int = 0, length: int = -1) -> ObjectInfo:
+        return self._layer.get_object(self._bucket, self._object, writer,
+                                      offset, length, self._opts)
+
+
 class ObjectLayer(abc.ABC):
     # --- buckets ------------------------------------------------------------
 
@@ -41,6 +61,16 @@ class ObjectLayer(abc.ABC):
     @abc.abstractmethod
     def get_object_info(self, bucket: str, object: str,
                         opts: ObjectOptions = None) -> ObjectInfo: ...
+
+    def get_object_n_info(self, bucket: str, object: str,
+                          opts: ObjectOptions = None):
+        """(ObjectInfo, body): what a GET needs for its headers, and a
+        handle whose ``read(writer, offset, length)`` streams the bytes
+        that ObjectInfo describes (reference GetObjectNInfo). The erasure
+        layers answer both from one quorum metadata pass; this default
+        is ``get_object_info`` and then ``get_object``."""
+        return (self.get_object_info(bucket, object, opts),
+                ObjectBody(self, bucket, object, opts))
 
     @abc.abstractmethod
     def delete_object(self, bucket: str, object: str,

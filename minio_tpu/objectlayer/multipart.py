@@ -14,6 +14,7 @@ import msgpack
 
 from ..erasure import Erasure, new_bitrot_writer
 from ..erasure.streaming import erasure_encode
+from ..obs import metrics as _mx
 from ..obs import spans as _spans
 from ..storage.datatypes import ErasureInfo, FileInfo, ObjectPartInfo
 from ..storage.xlstorage import META_MULTIPART, META_TMP, new_tmp_id
@@ -105,6 +106,7 @@ class MultipartMixin:
                      ) -> tuple[FileInfo, list, list]:
         upath = upload_path(bucket, object, upload_id)
         disks = self.disks
+        _mx.inc("minio_tpu_objectlayer_quorum_meta_reads_total", op="upload")
         fis, errs = read_all_fileinfo(disks, META_MULTIPART, upath)
         read_quorum, _ = object_quorum_from_meta(fis, errs,
                                                  self.default_parity)
@@ -124,35 +126,43 @@ class MultipartMixin:
                            ) -> MultipartInfo:
         """The upload's own record (reference GetMultipartInfo): what
         CreateMultipartUpload stored, internal keys included, so that the
-        part handler can tell an encrypted upload."""
+        part handler can tell an encrypted upload. It holds the pass it
+        came from: handed back as ``put_object_part(..., upload=)``, the
+        part is written from it."""
         fi, _, _ = self._upload_meta(bucket, object, upload_id)
         return MultipartInfo(bucket=bucket, object=object,
                              upload_id=upload_id, initiated=fi.mod_time,
-                             user_defined=dict(fi.metadata))
+                             user_defined=dict(fi.metadata), held=fi)
 
     def put_object_part(self, bucket: str, object: str, upload_id: str,
                         part_id: int, stream, size: int,
-                        opts: ObjectOptions = None) -> PartInfo:
+                        opts: ObjectOptions = None,
+                        upload: MultipartInfo = None) -> PartInfo:
         """``stream`` may be a HashReader whose ``actual_size`` differs
         from ``size`` (an encrypted part: stored and plaintext size);
         ``opts.user_defined`` is the part's own metadata, kept in its
-        sidecar and carried into ``xl.meta`` at complete."""
+        sidecar and carried into ``xl.meta`` at complete. ``upload`` is
+        this upload's ``get_multipart_info``, where the caller made one:
+        its quorum metadata pass is used and none is made here."""
         from .. import qos as _qos
         from ..obs import attribution as _attr
         with _spans.span("objectlayer.put_object_part", bucket=bucket,
                          object=object), _attr.observed("put"), \
                 _qos.lane_affinity(self._lane_key):
             return self._put_object_part_inner(bucket, object, upload_id,
-                                               part_id, stream, size, opts)
+                                               part_id, stream, size, opts,
+                                               upload)
 
     def _put_object_part_inner(self, bucket: str, object: str,
                                upload_id: str, part_id: int, stream,
-                               size: int, opts: ObjectOptions = None
-                               ) -> PartInfo:
+                               size: int, opts: ObjectOptions = None,
+                               upload: MultipartInfo = None) -> PartInfo:
         from .erasure_objects import to_object_err
         if not 1 <= part_id <= MAX_PARTS:
             raise dt.InvalidPart(bucket, object, str(part_id))
-        fi, fis, _ = self._upload_meta(bucket, object, upload_id)
+        fi = upload.held if upload is not None else None
+        if fi is None:
+            fi, _, _ = self._upload_meta(bucket, object, upload_id)
         upath = upload_path(bucket, object, upload_id)
         disks = self.disks
         data, parity = fi.erasure.data_blocks, fi.erasure.parity_blocks

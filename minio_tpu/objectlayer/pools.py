@@ -78,25 +78,31 @@ class ServerPools(ObjectLayer):
         idx = self._pool_with_object(bucket, object, opts)
         return self.pools[idx if idx is not None else 0]
 
-    def get_object(self, bucket, object, writer, offset=0, length=-1,
-                   opts=None):
+    def _first_pool(self, call, bucket, object):
+        """``call(pool)`` of the first pool that holds the object."""
         last = None
         for p in self.pools:
             try:
-                return p.get_object(bucket, object, writer, offset, length,
-                                    opts)
+                return call(p)
             except (dt.ObjectNotFound, dt.VersionNotFound) as e:
                 last = e
         raise last or dt.ObjectNotFound(bucket, object)
 
+    def get_object(self, bucket, object, writer, offset=0, length=-1,
+                   opts=None):
+        return self._first_pool(
+            lambda p: p.get_object(bucket, object, writer, offset, length,
+                                   opts), bucket, object)
+
     def get_object_info(self, bucket, object, opts=None):
-        last = None
-        for p in self.pools:
-            try:
-                return p.get_object_info(bucket, object, opts)
-            except (dt.ObjectNotFound, dt.VersionNotFound) as e:
-                last = e
-        raise last or dt.ObjectNotFound(bucket, object)
+        return self._first_pool(
+            lambda p: p.get_object_info(bucket, object, opts),
+            bucket, object)
+
+    def get_object_n_info(self, bucket, object, opts=None):
+        return self._first_pool(
+            lambda p: p.get_object_n_info(bucket, object, opts),
+            bucket, object)
 
     def delete_object(self, bucket, object, opts=None):
         # a pool answers the delete of a name it does not hold with
@@ -194,10 +200,10 @@ class ServerPools(ObjectLayer):
         raise dt.NoSuchUpload(bucket, object, upload_id)
 
     def put_object_part(self, bucket, object, upload_id, part_id, stream,
-                        size, opts=None):
+                        size, opts=None, upload=None):
         return self._pool_with_upload(bucket, object, upload_id) \
             .put_object_part(bucket, object, upload_id, part_id, stream,
-                             size, opts)
+                             size, opts, upload)
 
     def get_multipart_info(self, bucket, object, upload_id):
         return self._pool_with_upload(bucket, object, upload_id) \

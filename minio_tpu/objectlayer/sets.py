@@ -89,6 +89,10 @@ class ErasureSets(ObjectLayer):
         return self.get_hashed_set(object).get_object_info(
             bucket, object, opts)
 
+    def get_object_n_info(self, bucket, object, opts=None):
+        return self.get_hashed_set(object).get_object_n_info(
+            bucket, object, opts)
+
     def delete_object(self, bucket, object, opts=None):
         return self.get_hashed_set(object).delete_object(bucket, object, opts)
 
@@ -159,9 +163,9 @@ class ErasureSets(ObjectLayer):
             bucket, object, opts)
 
     def put_object_part(self, bucket, object, upload_id, part_id, stream,
-                        size, opts=None):
+                        size, opts=None, upload=None):
         return self.get_hashed_set(object).put_object_part(
-            bucket, object, upload_id, part_id, stream, size, opts)
+            bucket, object, upload_id, part_id, stream, size, opts, upload)
 
     def get_multipart_info(self, bucket, object, upload_id):
         return self.get_hashed_set(object).get_multipart_info(

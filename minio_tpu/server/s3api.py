@@ -1440,26 +1440,17 @@ class _S3Handler(BaseHTTPRequestHandler):
             parsed = parse_select(req.expression)
         except (ET.ParseError, SQLError) as e:
             return self._error("InvalidRequest", str(e), 400)
-        opts = self._opts()
-        oi = self.s3.obj.get_object_info(self.bucket, self.key, opts)
+        oi, body = self.s3.obj.get_object_n_info(self.bucket, self.key,
+                                                 self._opts())
         sse = self._sse_read_ctx(oi)
-        from ..utils import compress as cz
         import io as iomod
         sink = iomod.BytesIO()
         # BytesScanned = input consumed from storage (ciphertext /
         # compressed); the engine reports the decoded size as
         # BytesProcessed (s3select/message.py events)
         scanned = oi.size
-        if sse:
-            self._sse_write(sse, sink, 0, sse.plain_size, opts)
-        elif oi.internal.get(cz.META_COMPRESSION):
-            # stored bytes are compressed: the SQL engine needs plaintext
-            dz = cz.decompress_writer(
-                oi.internal[cz.META_COMPRESSION], sink)
-            self.s3.obj.get_object(self.bucket, self.key, dz, 0, -1, opts)
-            dz.finish()
-        else:
-            self.s3.obj.get_object(self.bucket, self.key, sink, 0, -1, opts)
+        # the SQL engine needs plaintext
+        self._write_plain(body, oi, sse, sink)
         raw = sink.getvalue()
         self.send_response(200)
         self.send_header("Content-Type",
@@ -2301,18 +2292,41 @@ class _S3Handler(BaseHTTPRequestHandler):
                 oi.internal.get(META_IV, "")), plain_size),)
         return SSERead(streams, plain_size, resp, cipher)
 
-    def _sse_write(self, sse, sink, offset: int, length: int, opts=None):
-        """Decrypt the plaintext range [offset, offset+length) of
-        ``self.bucket/self.key`` into ``sink``: only the stored bytes that
-        cover it are read, every package is verified before release."""
+    def _sse_write(self, sse, sink, offset: int, length: int, body):
+        """Decrypt the plaintext range [offset, offset+length) of the
+        object ``body`` holds (``get_object_n_info``'s handle for
+        ``self.bucket/self.key``) into ``sink``: only the stored bytes
+        that cover it are read, every package is verified before
+        release."""
         from ..crypto import RangeDecryptWriter, plan_range
         enc_off, enc_len, segs = plan_range(sse.streams, offset, length)
         dw = RangeDecryptWriter(sink, segs, sse.cipher, self.bucket,
                                 self.key)
         if enc_len > 0:
-            self.s3.obj.get_object(self.bucket, self.key, dw, enc_off,
-                                   enc_len, opts)
+            body.read(dw, enc_off, enc_len)
         dw.finish()
+
+    def _write_plain(self, body, oi, sse, sink, offset: int = 0,
+                     length: int = -1):
+        """The plaintext range [offset, offset+length) (-1: to the end) of
+        the object ``get_object_n_info`` returned as ``oi`` and ``body``
+        into ``sink``: opened if it is encrypted (``sse`` from
+        ``_sse_read_ctx``), inflated if it is compressed."""
+        from ..utils import compress as cz
+        compressed = oi.internal.get(cz.META_COMPRESSION, "")
+        if sse:
+            if length < 0:
+                length = sse.plain_size - offset
+            self._sse_write(sse, sink, offset, length, body)
+        elif compressed:
+            # inflate the whole stored stream, trim to the requested
+            # plaintext range (reference compressed-range behavior)
+            dz = cz.decompress_writer(compressed, sink, skip=offset,
+                                      limit=length)
+            body.read(dz)
+            dz.finish()
+        else:
+            body.read(sink, offset, length)
 
     def _hash_reader(self, size: int) -> HashReader:
         """Body reader verifying Content-MD5 / x-amz-content-sha256 on the
@@ -2398,9 +2412,11 @@ class _S3Handler(BaseHTTPRequestHandler):
 
     def get_object(self, ak):
         self._authorize(ak, "s3:GetObject")
-        opts = self._opts()
         try:
-            oi = self.s3.obj.get_object_info(self.bucket, self.key, opts)
+            # one quorum metadata pass: the headers below and the body
+            # behind them are the same version (reference GetObjectNInfo)
+            oi, body = self.s3.obj.get_object_n_info(self.bucket, self.key,
+                                                     self._opts())
         except dt.ObjectNotFound:
             # replication proxy: serve from the bucket's remote target
             # when the object hasn't replicated back yet
@@ -2451,19 +2467,7 @@ class _S3Handler(BaseHTTPRequestHandler):
         self.send_header("Content-Length", str(length))
         self.end_headers()
         if length > 0:
-            if sse:
-                self._sse_write(sse, self.wfile, offset, length, opts)
-            elif compressed:
-                # inflate the whole stored stream, trim to the requested
-                # plaintext range (reference compressed-range behavior)
-                dz = cz.decompress_writer(compressed, self.wfile,
-                                          skip=offset, limit=length)
-                self.s3.obj.get_object(self.bucket, self.key, dz, 0, -1,
-                                       opts)
-                dz.finish()
-            else:
-                self.s3.obj.get_object(self.bucket, self.key, self.wfile,
-                                       offset, length, opts)
+            self._write_plain(body, oi, sse, self.wfile, offset, length)
         self._notify("s3:ObjectAccessed:Get", oi)
 
     def _get_transitioned(self, oi):
@@ -2844,13 +2848,12 @@ class _S3Handler(BaseHTTPRequestHandler):
         self._send(200, xu.initiate_multipart_xml(self.bucket, self.key, uid),
                    headers=sse_resp)
 
-    def _upload_internal(self, uid: str) -> dict:
-        """The upload's metadata as CreateMultipartUpload stored it, {} on
+    def _upload_info(self, uid: str):
+        """The upload's record as CreateMultipartUpload stored it
+        (``user_defined``: its metadata, internal keys included), None on
         a backend that keeps none (it refused SSE at initiate)."""
         info = getattr(self.s3.obj, "get_multipart_info", None)
-        if info is None:
-            return {}
-        return info(self.bucket, self.key, uid).user_defined
+        return info(self.bucket, self.key, uid) if info is not None else None
 
     def put_part(self, ak):
         self._authorize(ak, "s3:PutObject")
@@ -2875,7 +2878,10 @@ class _S3Handler(BaseHTTPRequestHandler):
         hr = self._hash_reader(size)
         stream, put_size, opts, sse_resp = hr, size, None, {}
         from ..crypto.sse import META_SCHEME
-        internal = self._upload_internal(uid)
+        # the one quorum metadata pass of this part: the object layer
+        # writes the part from what `upload` holds
+        upload = self._upload_info(uid)
+        internal = upload.user_defined if upload is not None else {}
         scheme = internal.get(META_SCHEME, "")
         if scheme:
             stream, put_size, opts, sse_resp = self._encrypt_part(
@@ -2885,8 +2891,10 @@ class _S3Handler(BaseHTTPRequestHandler):
             raise dt.InvalidRequest(
                 self.bucket, self.key,
                 "the upload was not initiated with SSE-C")
+        held = {} if upload is None else {"upload": upload}
         pi = self.s3.obj.put_object_part(self.bucket, self.key, uid,
-                                         part_id, stream, put_size, opts)
+                                         part_id, stream, put_size, opts,
+                                         **held)
         from ..obs import metrics as mx
         mx.inc("minio_tpu_multipart_parts_total", sse=scheme or "none")
         self._send(200, headers={"ETag": f'"{pi.etag}"', **sse_resp})
@@ -2923,7 +2931,8 @@ class _S3Handler(BaseHTTPRequestHandler):
             int(self.q("part-number-marker", "0") or "0"),
             min(int(self.q("max-parts", "1000") or "1000"), 10_000))
         from ..crypto.sse import META_SCHEME
-        if self._upload_internal(self.q("uploadId")).get(META_SCHEME):
+        upload = self._upload_info(self.q("uploadId"))
+        if upload is not None and upload.user_defined.get(META_SCHEME):
             for p in info.parts:    # listings speak plaintext sizes
                 p.size = p.actual_size
         self._send(200, xu.list_parts_xml(info))

@@ -477,7 +477,7 @@ def handle_download(h, bucket: str, object: str) -> None:
         return h._error("AccessDenied", "invalid token", 401)
     try:
         _check(h, ak, "s3:GetObject", bucket, object)
-        oi = h.s3.obj.get_object_info(bucket, object)
+        oi, body = h.s3.obj.get_object_n_info(bucket, object)
         # same read context as the S3 GET path: decrypt SSE-S3/KMS with
         # the unsealed OEK, inflate compressed objects (SSE-C correctly
         # errors here — a browser download can't carry the customer key)
@@ -494,7 +494,7 @@ def handle_download(h, bucket: str, object: str) -> None:
                   f'attachment; filename="{_disposition_name(object)}"')
     h.end_headers()
     if plain_size > 0:
-        _write_logical(h, bucket, object, oi, sse, h.wfile)
+        h._write_plain(body, oi, sse, h.wfile)
 
 
 def _logical_size(h, oi, sse) -> int:
@@ -503,23 +503,6 @@ def _logical_size(h, oi, sse) -> int:
         return sse.plain_size
     return oi.actual_size if oi.internal.get(cz.META_COMPRESSION) \
         else oi.size
-
-
-def _write_logical(h, bucket: str, object: str, oi, sse, sink) -> None:
-    """Stream the object's PLAINTEXT into sink — the same read context
-    as the S3 GET path (decrypt SSE with the unsealed OEK, inflate
-    compressed objects)."""
-    from ..utils import compress as cz
-    compressed = oi.internal.get(cz.META_COMPRESSION, "")
-    if sse:
-        h.bucket, h.key = bucket, object
-        h._sse_write(sse, sink, 0, sse.plain_size)
-    elif compressed:
-        dz = cz.decompress_writer(compressed, sink)
-        h.s3.obj.get_object(bucket, object, dz)
-        dz.finish()
-    else:
-        h.s3.obj.get_object(bucket, object, sink)
 
 
 def handle_download_zip(h) -> None:
@@ -602,14 +585,14 @@ def handle_download_zip(h) -> None:
                 # multi-select zip too — re-checked lazily as each entry
                 # streams, with metadata/SSE resolved just-in-time
                 _check(h, ak, "s3:GetObject", bucket, key)
-                oi = h.s3.obj.get_object_info(bucket, key)
+                oi, body = h.s3.obj.get_object_n_info(bucket, key)
                 h.bucket, h.key = bucket, key
                 sse = h._sse_read_ctx(oi)
                 arc = key[len(prefix):] if key.startswith(prefix) else key
                 with zf.open(zipfile.ZipInfo(arc or key), "w",
                              force_zip64=True) as entry:
                     if _logical_size(h, oi, sse) > 0:
-                        _write_logical(h, bucket, key, oi, sse, entry)
+                        h._write_plain(body, oi, sse, entry)
     except Exception:  # noqa: BLE001 — mid-stream failure/denial: cut
         h.close_connection = True  # the connection, the client sees EOF
         return
