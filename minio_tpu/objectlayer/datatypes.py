@@ -392,6 +392,10 @@ class HealResultItem:
     data_blocks: int = 0
     before_state: list[str] = field(default_factory=list)
     after_state: list[str] = field(default_factory=list)
+    #: each drive's endpoint ("" for an empty slot), aligned with the
+    #: states (the reference's HealDriveInfo.Endpoint): which drive a
+    #: state names
+    endpoints: list[str] = field(default_factory=list)
     object_size: int = 0
 
 

@@ -244,30 +244,44 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         path that saw shard-level trouble routes through here): corrupt
         shards enqueue a DEEP MRF heal (a normal heal's size-only check
         cannot find a corrupt-but-right-sized shard), missing/failed
-        shards a normal one."""
+        shards a normal one. A drive that is OFFLINE (``DiskNotFound``:
+        an empty slot, the health tracker's fast-fail, a dead node) is
+        no such trouble: a read cannot heal onto it, and the write that
+        missed it has charged the debt already (reference addPartial:
+        heal on errFileNotFound / errFileCorrupt, never on
+        errDiskNotFound)."""
         saw_bitrot = any(isinstance(e, errors.FileCorrupt) for e in errs)
         degraded = extra_degraded or saw_bitrot or any(
-            isinstance(e, (errors.FileNotFound, errors.FaultyDisk,
-                           errors.DiskNotFound))
+            isinstance(e, (errors.FileNotFound, errors.FaultyDisk))
             for e in errs)
         if degraded:
             self._notify_partial(bucket, object, version_id,
                                  scan_mode="deep" if saw_bitrot
                                  else "normal")
+        elif any(isinstance(e, errors.DiskNotFound) for e in errs):
+            _mx.inc("minio_tpu_mrf_charges_total", source="read",
+                    outcome="skipped_offline")
         return degraded
 
     def _notify_partial(self, bucket, object, version_id="",
-                        scan_mode="normal"):
+                        scan_mode="normal", source="read", missed=None):
         """scan_mode='deep' when the caller saw bitrot — a normal heal's
-        size-only check cannot find a corrupt-but-right-sized shard."""
-        if self.on_partial is not None:
+        size-only check cannot find a corrupt-but-right-sized shard.
+        ``missed``: the drives a write did not reach (the MRF parks the
+        debt against them if they are all offline)."""
+        if self.on_partial is None:
+            return
+        more = {"missed": missed} if missed else {}
+        try:
             try:
-                self.on_partial(bucket, object, version_id,
-                                scan_mode=scan_mode)
+                outcome = self.on_partial(bucket, object, version_id,
+                                          scan_mode=scan_mode, **more)
             except TypeError:
-                self.on_partial(bucket, object, version_id)
-            except Exception:  # noqa: BLE001 — MRF is best-effort
-                pass
+                outcome = self.on_partial(bucket, object, version_id)
+        except Exception:  # noqa: BLE001 — MRF is best-effort
+            return
+        _mx.inc("minio_tpu_mrf_charges_total", source=source,
+                outcome=outcome if isinstance(outcome, str) else "queued")
 
     # --- buckets ------------------------------------------------------------
 
@@ -509,7 +523,9 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             raise to_object_err(err, bucket, object)
         if any(e is not None for e in errs):
             self._cleanup_tmp(tmp_id)  # reclaim tmp on the failed minority
-            self._notify_partial(bucket, object, fi.version_id)
+            self._notify_partial(
+                bucket, object, fi.version_id, source="write",
+                missed=[d for d, e in zip(shuffled, errs) if e is not None])
         from ..scanner.tracker import global_tracker
         global_tracker().mark(bucket, object)
         self.metacache.on_write(bucket)
@@ -693,15 +709,20 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
 
         # disks in shard order via each disk's stored erasure index
         per_shard_disk: list = [None] * len(disks)
+        #: a drive that answered the metadata pass holds nothing this
+        #: version can be read from (outdated, deleted there): heal debt
+        stale = False
         for d, dfi in zip(disks, fis):
-            if d is None or dfi is None or dfi.deleted:
+            if d is None or dfi is None:
                 continue
-            if dfi.data_dir != fi.data_dir or \
-                    round(dfi.mod_time, 3) != round(fi.mod_time, 3):
-                continue  # outdated disk
             idx = dfi.erasure.index
-            if 1 <= idx <= len(disks) and per_shard_disk[idx - 1] is None:
-                per_shard_disk[idx - 1] = d
+            if dfi.deleted or dfi.data_dir != fi.data_dir or \
+                    round(dfi.mod_time, 3) != round(fi.mod_time, 3) or \
+                    not 1 <= idx <= len(disks) or \
+                    per_shard_disk[idx - 1] is not None:
+                stale = True  # outdated disk
+                continue
+            per_shard_disk[idx - 1] = d
 
         shard_errs: list = []
         part_start = 0  # start byte of current part within the object
@@ -730,8 +751,12 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                         bucket, f"{object}/{fi.data_dir}/part.{part.number}")
                     readers.append(new_bitrot_reader(
                         src, algo, logical, bitrot_chunk))
-                except Exception:  # noqa: BLE001
+                except Exception as e:  # noqa: BLE001
                     readers.append(None)
+                    # why the shard is not there decides the heal debt
+                    shard_errs.append(
+                        e if isinstance(e, errors.StorageError)
+                        else errors.FaultyDisk(str(e)))
             try:
                 stats = erasure_decode(er, writer, readers, part_offset,
                                        part_length, part.size)
@@ -744,12 +769,15 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                         src.close()
             shard_errs.extend(stats.errs)
         # heal-on-read signal (cmd/erasure-object.go:325-336) through the
-        # single bitrot/degraded funnel: corrupt shards -> deep MRF heal
+        # single bitrot/degraded funnel: corrupt shards -> deep MRF heal.
+        # What a drive that ANSWERED lacks is debt; an offline drive is
+        # not (the funnel says why)
         self._signal_read_faults(
-            bucket, object, fi.version_id, shard_errs,
-            extra_degraded=any(e is not None for e in errs)
-            or any(d is None for d in per_shard_disk[
-                :fi.erasure.data_blocks + fi.erasure.parity_blocks]))
+            bucket, object, fi.version_id, shard_errs + [
+                e for e in errs if isinstance(e, errors.DiskNotFound)],
+            extra_degraded=stale or any(
+                e is not None and not isinstance(e, errors.DiskNotFound)
+                for e in errs))
         return oi
 
     def get_object_bytes(self, bucket: str, object: str,
@@ -832,9 +860,11 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             write_quorum)
         if err is not None:
             raise to_object_err(err, bucket, object)
-        if any(isinstance(e, (errors.DiskNotFound, errors.FaultyDisk))
-               for e in errs):
-            self._notify_partial(bucket, object, fi.version_id)
+        missed = [d for d, e in zip(disks, errs) if isinstance(
+            e, (errors.DiskNotFound, errors.FaultyDisk))]
+        if missed:
+            self._notify_partial(bucket, object, fi.version_id,
+                                 source="delete", missed=missed)
         # second bump AFTER the mutation landed: a cache build that
         # started between the pre-bump and the quorum delete would have
         # captured the old namespace under the new sequence
@@ -1299,7 +1329,9 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         res = HealResultItem(
             bucket=bucket, object=object, version_id=fi.version_id,
             disk_count=n, data_blocks=fi.erasure.data_blocks,
-            parity_blocks=fi.erasure.parity_blocks, object_size=fi.size)
+            parity_blocks=fi.erasure.parity_blocks, object_size=fi.size,
+            endpoints=[d.endpoint() if d is not None else ""
+                       for d in disks])
 
         if fi.deleted:
             # propagate the delete marker to disks missing it

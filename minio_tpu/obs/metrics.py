@@ -655,6 +655,10 @@ def _g_heal(server) -> list[str]:
             f"minio_tpu_heal_mrf_healed_total {st['healed']}",
             "# TYPE minio_tpu_heal_mrf_failed_total counter",
             f"minio_tpu_heal_mrf_failed_total {st['failed']}",
+            "# HELP minio_tpu_mrf_parked_offline heal debt that waits "
+            "for an offline drive to come back",
+            "# TYPE minio_tpu_mrf_parked_offline gauge",
+            f"minio_tpu_mrf_parked_offline {st['parked_offline']}",
         ]
     return lines
 
@@ -802,7 +806,10 @@ def _g_disk_health(server) -> list[str]:
     companion counters ride the store: minio_tpu_fault_injected_total
     {layer,action}, minio_tpu_disk_trips_total{disk},
     minio_tpu_disk_reonline_total{disk}, minio_tpu_hedged_reads_total
-    {outcome}, minio_tpu_mrf_dropped_total."""
+    {outcome}, minio_tpu_mrf_dropped_total, and what became of heal
+    debt while a drive was away: minio_tpu_mrf_charges_total{source,
+    outcome}, minio_tpu_mrf_heal_attempts_total{outcome},
+    minio_tpu_mrf_released_total{reason}."""
     lines = []
     rows = []
     for d in _all_disks(server.obj):

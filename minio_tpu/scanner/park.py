@@ -21,6 +21,12 @@ machinery lives here once:
   invisible until the next deep scanner cycle.
 * **kick()** — a rejoining peer promotes every parked retry to runnable
   NOW (wired into ``dist.node.Node._on_peer_reconnect``).
+* **Offline park** — debt whose only obstacle is a drive that is gone
+  waits for that drive, not for a timer: ``park_offline`` keeps ONE
+  entry per key (so it dedupes however often the key is charged), no
+  attempt count, bounded by ``max_queue`` drop-oldest like the queue;
+  ``release(endpoint)`` (the drive came back) and ``kick()`` (a peer
+  rejoined) make it runnable again.
 * **Persisted journal** — the queued key set mirrors into a small JSON
   document committed via ``durable_write``, so debt recorded before a
   crash is re-enqueued on restart. All journal IO runs on the consumer's
@@ -76,6 +82,10 @@ class DebtQueue:
         #: failed attempts awaiting retry: [(due_monotonic, item, attempt)]
         self._retry: list[tuple[float, tuple, int]] = []
         self._retry_lock = threading.Lock()
+        #: debt that waits for drives to come back, oldest first:
+        #: (bucket, object, version_id) -> (mode, endpoints it waits for;
+        #: none named = any drive's return releases it)
+        self._offline: dict[tuple, tuple[str, frozenset]] = {}
 
     # -- enqueue --------------------------------------------------------------
 
@@ -107,10 +117,7 @@ class DebtQueue:
         if not landed:
             dropped += 1  # both retries lost the race: the NEW entry
         if dropped:
-            self.dropped += dropped
-            if self._dropped_metric:
-                from ..obs import metrics as mx
-                mx.inc(self._dropped_metric, dropped)
+            self._count_dropped(dropped)
         if self._persist_path is not None:
             key = (bucket, object, version_id)
             if landed:
@@ -136,6 +143,12 @@ class DebtQueue:
             # must not pay JSON serialization + strict fsyncs — the
             # consumer's drain loop owns all journal IO; the marks stay
             # dirty until its next pass
+
+    def _count_dropped(self, n: int) -> None:
+        self.dropped += n
+        if self._dropped_metric:
+            from ..obs import metrics as mx
+            mx.inc(self._dropped_metric, n)
 
     # -- persistence ----------------------------------------------------------
 
@@ -185,8 +198,8 @@ class DebtQueue:
         if any(tuple(e[:3]) == key for e in list(self.q.queue)):
             return True
         with self._retry_lock:
-            return any(tuple(item[:3]) == key
-                       for _due, item, _a in self._retry)
+            return key in self._offline or any(
+                tuple(item[:3]) == key for _due, item, _a in self._retry)
 
     def forget(self, key: tuple) -> None:
         """Drop one key from the journal mirror — the debt is paid (or
@@ -237,14 +250,15 @@ class DebtQueue:
 
     # -- retry park -----------------------------------------------------------
 
-    def kick(self) -> None:
-        """Promote every backoff-parked retry to runnable NOW — called
-        when a peer node rejoins (rpc on_reconnect): the debt its
-        absence created should drain immediately, not wait out the
-        exponential backoff."""
+    def kick(self) -> int:
+        """Promote every parked entry to runnable NOW — called when a
+        peer node rejoins (rpc on_reconnect): the debt its absence
+        created should drain immediately, not wait out the exponential
+        backoff. Returns how many entries had waited for a drive."""
         with self._retry_lock:
             self._retry = [(0.0, item, attempt)
                            for _due, item, attempt in self._retry]
+        return self.release()
 
     def park(self, item: tuple, attempt: int, base_s: float,
              cap_s: float) -> None:
@@ -253,6 +267,52 @@ class DebtQueue:
         delay = min(cap_s, base_s * (1 << min(attempt, 5)))
         with self._retry_lock:
             self._retry.append((time.monotonic() + delay, item, attempt))
+
+    def park_offline(self, item: tuple, endpoints=()) -> bool:
+        """Park ``item`` until one of ``endpoints`` comes back (none
+        named: until any drive does). Keyed by (bucket, object,
+        version_id): a key parked again joins its entry (the drives add
+        up, a sticky mode wins) and False is returned. Bounded by
+        ``max_queue``, drop-oldest, counted like the queue's drops. The
+        journal keeps the key while it is parked."""
+        key, mode = tuple(item[:3]), item[3]
+        evicted = []
+        with self._retry_lock:
+            known = self._offline.get(key)
+            if known is not None:
+                if mode not in self._sticky:
+                    mode = known[0]
+                self._offline[key] = (mode,
+                                      known[1] | frozenset(endpoints))
+            else:
+                while len(self._offline) >= self.q.maxsize > 0:
+                    evicted.append(next(iter(self._offline)))
+                    del self._offline[evicted[-1]]
+                self._offline[key] = (mode, frozenset(endpoints))
+        if evicted:
+            self._count_dropped(len(evicted))
+        if self._persist_path is not None:
+            with self._plock:
+                if mode in self._sticky or \
+                        key not in self._persist_entries:
+                    self._persist_entries[key] = mode
+                    self._pdirty = True
+            for ev in evicted:
+                self.forget(ev)
+        return known is None
+
+    def release(self, endpoint: str | None = None) -> int:
+        """The drive at ``endpoint`` is back (None: whatever was away
+        is): what waited for it is runnable NOW, through the retry
+        park, which re-offers an entry the full queue refuses. Returns
+        how many entries were released."""
+        with self._retry_lock:
+            keys = [k for k, (_m, eps) in self._offline.items()
+                    if endpoint is None or not eps or endpoint in eps]
+            for k in keys:
+                mode, _eps = self._offline.pop(k)
+                self._retry.append((0.0, (*k, mode), 0))
+        return len(keys)
 
     def _promote_due_retries(self, repark_s: float) -> None:
         now = time.monotonic()
@@ -291,13 +351,18 @@ class DebtQueue:
 
     def stats(self) -> dict:
         with self._retry_lock:
-            retry_pending = len(self._retry)
+            parked_offline = len(self._offline)
+            retry_pending = len(self._retry) + parked_offline
+        # ``retry_pending`` is everything that is not runnable now: a
+        # caller waiting for the healer to rest subtracts it
         return {"queued": self.q.qsize() + retry_pending,
-                "retry_pending": retry_pending, "dropped": self.dropped}
+                "retry_pending": retry_pending,
+                "parked_offline": parked_offline, "dropped": self.dropped}
 
     def drain(self, timeout: float = 30.0) -> bool:
         """Block until the queue AND the retry park are empty
-        (tests / shutdown). Returns True when drained."""
+        (tests / shutdown); what waits for a drive is not waited for.
+        Returns True when drained."""
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
             with self._retry_lock:

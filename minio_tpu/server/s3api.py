@@ -507,15 +507,20 @@ class S3Server:
         # wire the degraded-path signals into the background plane:
         # partial/bitrot detections enqueue MRF heals, and a health-
         # tracked disk that re-onlines kicks the auto-heal monitor so
-        # the objects it missed get rebuilt promptly
+        # the objects it missed get rebuilt promptly, and releases the
+        # heal debt the MRF parked against it while it was away
         def _disk_state(disk, state, _srv=self):
-            if state == "ok" and getattr(_srv, "autoheal", None) is not None:
+            if state != "ok":
+                return
+            if getattr(_srv, "autoheal", None) is not None:
                 from ..scanner.autoheal import set_healing_tracker
                 try:
                     set_healing_tracker(disk)
                 except Exception:  # noqa: BLE001 — disk may still be sick
                     pass
                 _srv.autoheal.kick()
+            if getattr(_srv, "mrf", None) is not None:
+                _srv.mrf.release(disk.endpoint())
         for layer in self._erasure_layers():
             layer.on_partial = self.mrf.add_partial
             layer.on_disk_state = _disk_state

@@ -218,12 +218,17 @@ class DiskHealthCheck(StorageAPI):
     def _probe_ok(self) -> bool:
         """The reference's diskHealthCheckOK: stat the disk, then prove
         writes land (tmp write + delete under the system volume)."""
-        from .xlstorage import META_BUCKET
+        from .xlstorage import META_TMP
         try:
             self.inner.disk_info()
-            name = f"tmp/.health-probe-{uuid.uuid4().hex[:8]}"
-            self.inner.write_all(META_BUCKET, name, b"health-check")
-            self.inner.delete_path(META_BUCKET, name)
+            # IN the tmp volume, not a path under the system volume: a
+            # delete prunes empty parents up to its volume, and took an
+            # empty ``tmp`` with it, after which no write_all landed on
+            # the drive (the healing tracker of a drive that came back
+            # empty among them)
+            name = f".health-probe-{uuid.uuid4().hex[:8]}"
+            self.inner.write_all(META_TMP, name, b"health-check")
+            self.inner.delete_path(META_TMP, name)
             return True
         except Exception:  # noqa: BLE001 — still sick
             return False
