@@ -355,10 +355,15 @@ class StreamingBitrotWriter:
         self.sink.write(framed if isinstance(
             framed, (bytes, bytearray, memoryview)) else memoryview(framed))
 
-    def close(self):
+    def finish(self):
+        """Everything written is in the sink; closing it is what is left
+        (``close_writers`` closes the sinks of a PUT's drives together)."""
         if self._buf:
             self._emit(bytes(self._buf))
             self._buf.clear()
+
+    def close(self):
+        self.finish()
         self.sink.close()
 
     def abort(self):
@@ -511,6 +516,9 @@ class WholeBitrotWriter:
 
     def digest(self) -> bytes:
         return self._h.digest()
+
+    def finish(self):
+        pass
 
     def close(self):
         self.sink.close()

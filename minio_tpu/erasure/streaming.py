@@ -1227,7 +1227,7 @@ def erasure_heal(erasure: Erasure, writers: list, readers: list,
     (BASELINE config 5)."""
     if total_length == 0:
         # still commit empty shard files through the writers
-        _close_heal_writers(writers)
+        close_writers(writers)
         return [None] * len(readers)
     k = erasure.data_blocks
     bs = erasure.block_size
@@ -1308,23 +1308,64 @@ def erasure_heal(erasure: Erasure, writers: list, readers: list,
             emit(window.popleft())
     while window:
         emit(window.popleft())
-    _close_heal_writers(writers)
+    close_writers(writers)
     return preader.errs
 
 
-def _close_heal_writers(writers: list) -> None:
+def close_writers(writers: list) -> None:
     """Per-writer close with per-disk demotion: close() can raise under
     fsync=always (strict writeback errors), and one disk's EIO must stay
-    that disk's vote — nulling its slot tells heal_object to skip its
-    rename_data — not abort the rebuild of every healthy target (heal
-    write quorum is 1; mirrors the PUT path's per-writer close)."""
+    that disk's vote — nulling its slot tells the caller to skip its
+    commit — not abort the write on every healthy disk (PUT, part PUT
+    and heal alike; heal write quorum is 1). Sinks that can close
+    together (``close_many``: the staged files of the local drives,
+    storage/xlstorage.py) do so in ONE call, a turn at the interpreter
+    lock a request where a close a drive is one a drive."""
+    together: dict = {}
     for t, w in enumerate(writers):
         if w is None:
             continue
         try:
-            w.close()
+            # on the TYPE: a proxy around a sink closes by its own close
+            if hasattr(type(w.sink), "close_many"):
+                w.finish()
+                together.setdefault(type(w.sink), []).append(t)
+            else:
+                w.close()
         except Exception:  # noqa: BLE001 — demoted to a per-disk vote
             writers[t] = None
+    for kind, slots in together.items():
+        errs = kind.close_many([writers[t].sink for t in slots])
+        for t, e in zip(slots, errs):
+            if e is not None:
+                writers[t] = None
+
+
+def close_readers(readers: list) -> None:
+    """Close the shard sources of one read (``None`` slots and readers
+    without a source pass). Local shard files hand over their fds
+    (``detach_fd``, storage/xlstorage.py) and are closed in ONE native
+    call, a turn at the interpreter lock a read where a close a shard is
+    one a shard; any other source closes by its own ``close``."""
+    fds = []
+    for r in readers:
+        src = getattr(r, "src", None)
+        if src is None:
+            continue
+        detach = getattr(src, "detach_fd", None)
+        if detach is not None:
+            fds.append(detach())
+        elif hasattr(src, "close"):
+            src.close()
+    if not fds:
+        return
+    from .. import native
+    if native.available():
+        native.close_fds(fds, False)
+    else:
+        for fd in fds:
+            if fd >= 0:
+                os.close(fd)
 
 
 class BufferSink:

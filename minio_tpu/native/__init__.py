@@ -10,7 +10,11 @@ gf256_simd.cpp + highwayhash.cpp) provides:
 - the fused per-block data-plane calls ``mt_put_block`` / ``mt_get_block``
   (split+encode+hash+frame, verify+assemble) and
   ``mt_get_block_pread_degraded`` (pread+verify+rebuild+assemble) that
-  carry the end-to-end object path on the CPU route.
+  carry the end-to-end object path on the CPU route,
+- a PUT's per-drive file-system sequences ``mt_stage_file`` /
+  ``mt_close_fds`` / ``mt_commit_version`` (storage/xlstorage.py: a shard
+  file staged, a version committed, in one call each) and a read's
+  ``mt_open_shard`` (open + fstat).
 
 All entry points release the GIL (plain ctypes CDLL calls), so concurrent
 requests scale across cores where the host has them.
@@ -214,6 +218,20 @@ def _load_native_locked() -> ctypes.CDLL:
             ctypes.POINTER(ctypes.c_uint32), ctypes.c_char_p, ctypes.c_long,
             ctypes.c_ulonglong, c_u8p]
         lib.md5_finish.restype = None
+        lib.mt_stage_file.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
+        lib.mt_stage_file.restype = ctypes.c_int
+        lib.mt_open_shard.argtypes = [ctypes.c_char_p]
+        lib.mt_open_shard.restype = ctypes.c_int
+        lib.mt_close_fds.argtypes = [
+            ctypes.POINTER(ctypes.c_int), ctypes.c_int, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_int)]
+        lib.mt_close_fds.restype = None
+        lib.mt_commit_version.argtypes = [
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
+            ctypes.c_long, ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_int)]
+        lib.mt_commit_version.restype = ctypes.c_int
         _lib = lib
     return _lib
 
@@ -442,3 +460,56 @@ def verify_framed(framed, plen: int, chunk: int, key: bytes,
     arr = np.frombuffer(framed, dtype=np.uint8)
     return lib.mt_verify_framed(arr.ctypes.data_as(_u8p), plen, chunk, key,
                                 algo)
+
+
+# --- a PUT's file-system sequences (storage/xlstorage.py) -------------------
+
+#: mt_commit_version's steps, as its result names the one that failed
+COMMIT_OBJECT_DIR = 1
+COMMIT_STAGED = 2
+COMMIT_DATA_RENAME = 3
+COMMIT_META_WRITE = 4
+COMMIT_META_RENAME = 5
+COMMIT_FSYNC = 6
+
+
+def stage_file(base: str, rel: str) -> int:
+    """mkdir the directories of ``rel`` below ``base`` (which has to be
+    there) and open the file for writing, in one call. Returns the fd,
+    or ``-errno``."""
+    return load_native().mt_stage_file(os.fsencode(base), os.fsencode(rel))
+
+
+def open_shard(path: str) -> int:
+    """Open a shard file for reading and see that it is no directory, in
+    one call. Returns the fd, or ``-errno`` (``-EISDIR`` for a
+    directory)."""
+    return load_native().mt_open_shard(os.fsencode(path))
+
+
+def close_fds(fds: list[int], do_fsync: bool) -> list[int]:
+    """Close ``fds`` in one call, each fsynced first when ``do_fsync``; an
+    fd below 0 is passed over. Returns the fsyncs' errnos (0 ok); every
+    fd is closed either way."""
+    n = len(fds)
+    errs = (ctypes.c_int * n)()
+    load_native().mt_close_fds((ctypes.c_int * n)(*fds), n, int(do_fsync),
+                               errs)
+    return list(errs)
+
+
+def commit_version(vol: str, obj: str, ddir: str, src: str, tmp_parent: str,
+                   meta: bytes, purge: list[str], do_fsync: bool
+                   ) -> list[int]:
+    """The file-system half of one drive's ``rename_data`` in one call
+    (native/pipeline.cpp mt_commit_version has the steps). Returns [step
+    (0, or the ``COMMIT_*`` step that failed), its errno, the kind of a
+    failed fsync (0 file, 1 dir), file fsyncs made, dir fsyncs made, data
+    directories not purged, tmp parent not removed]."""
+    out = (ctypes.c_int * 7)()
+    names = b"".join(os.fsencode(n) + b"\0" for n in purge)
+    load_native().mt_commit_version(
+        os.fsencode(vol), os.fsencode(obj), os.fsencode(ddir),
+        os.fsencode(src), os.fsencode(tmp_parent), meta, len(meta), names,
+        len(purge), int(do_fsync), out)
+    return list(out)

@@ -33,6 +33,10 @@
 #include <cstdint>
 #include <cstring>
 #include <ctime>
+#include <fcntl.h>
+#include <ftw.h>
+#include <string>
+#include <sys/stat.h>
 #include <unistd.h>
 
 namespace {
@@ -351,6 +355,199 @@ long mt_verify_framed(const uint8_t* framed, long plen, long chunk,
     if (std::memcmp(dig, framed + ci * stride, 32) != 0) return ci;
   }
   return -1;
+}
+
+}  // extern "C"
+
+// --- a PUT's file-system sequences ------------------------------------------
+//
+// The bytes of a PUT move in mt_put_block_fds; what is left of its drive work
+// is file-system choreography: per drive 5 calls to stage a shard file and 21
+// to commit a version, each one a turn at the interpreter lock when made from
+// Python (~2-3 ms beside 20 clients on the chip's host, PERF.md section 6).
+// The entry points below run those sequences with the lock let go once:
+// mt_stage_file (storage/xlstorage.py _StagedFile), mt_close_fds (its
+// close_many) and mt_commit_version (XLStorage.rename_data); mt_open_shard
+// is the readers' one (_FileReadAt: open + fstat). They perform the
+// steps of the Python sequences in the Python sequences' order, fsyncs
+// included; the Python side keeps the policy, the xl.meta logic, the errors
+// and the counters (docs/durability.md "The native sequence").
+namespace {
+
+// mkdir each directory of `rel` below `base` (never `base` itself): 0 or errno.
+// `upto_last` leaves rel's last component alone (it names a file).
+int mkdirs_below(const std::string& base, const char* rel, bool upto_last) {
+  std::string p = base;
+  const char* s = rel;
+  while (*s) {
+    const char* e = std::strchr(s, '/');
+    if (!e) {
+      if (upto_last) break;
+      e = s + std::strlen(s);
+    }
+    if (e > s) {
+      p.push_back('/');
+      p.append(s, (size_t)(e - s));
+      if (mkdir(p.c_str(), 0777) != 0 && errno != EEXIST) return errno;
+    }
+    s = *e ? e + 1 : e;
+  }
+  return 0;
+}
+
+int rm_entry(const char* path, const struct stat*, int, struct FTW*) {
+  return remove(path) == 0 ? 0 : -1;
+}
+
+// shutil.rmtree of one tree: 0 removed, 1 was not there, -1 failed.
+int rm_tree(const char* path) {
+  if (nftw(path, rm_entry, 16, FTW_DEPTH | FTW_PHYS) == 0) return 0;
+  return errno == ENOENT ? 1 : -1;
+}
+
+// durability.fsync_path on a path: a path that cannot be opened is a benign
+// race (0, nothing counted); a failed fsync is errno. ok[kind]++ on success.
+int fsync_at(const char* path, int flags, int* ok) {
+  int fd = open(path, O_RDONLY | O_CLOEXEC | flags);
+  if (fd < 0) return 0;
+  int e = fsync(fd) == 0 ? 0 : errno;
+  close(fd);
+  if (!e) ++*ok;
+  return e;
+}
+
+}  // namespace
+
+extern "C" {
+
+// steps of mt_commit_version, as `out[0]` names the one that failed
+enum {
+  kStepDone = 0,
+  kStepObjectDir = 1,   // mkdir of the object directory below the volume
+  kStepStaged = 2,      // the staged data directory is not a directory
+  kStepDataRename = 3,  // <src> -> <object>/<dataDir>
+  kStepMetaWrite = 4,   // xl.meta written under its tmp name
+  kStepMetaRename = 5,  // tmp name -> <object>/xl.meta
+  kStepFsync = 6,       // an fsync of policy `always` failed; out[2] = kind
+};
+
+// Stage one shard file: mkdir the directories of `rel` below `base` (the
+// volume, which has to be there) and open the file for writing, as
+// os.makedirs + open(path, "wb") do. Returns the fd, or -errno.
+int mt_stage_file(const char* base, const char* rel) {
+  const std::string path = std::string(base) + "/" + rel;
+  const int flags = O_WRONLY | O_CREAT | O_TRUNC | O_CLOEXEC;
+  // parts after the first find their directory there
+  int fd = open(path.c_str(), flags, 0666);
+  if (fd >= 0 || errno != ENOENT) return fd >= 0 ? fd : -errno;
+  const int e = mkdirs_below(base, rel, true);
+  if (e) return -e;
+  fd = open(path.c_str(), flags, 0666);
+  return fd >= 0 ? fd : -errno;
+}
+
+// Open one shard file for reading, as os.open + os.fstat do in
+// _FileReadAt: the fd, or -errno (-EISDIR for a directory, which open(2)
+// itself lets through).
+int mt_open_shard(const char* path) {
+  const int fd = open(path, O_RDONLY | O_CLOEXEC);
+  if (fd < 0) return -errno;
+  struct stat st;
+  if (fstat(fd, &st) == 0 && S_ISDIR(st.st_mode)) {
+    close(fd);
+    return -EISDIR;
+  }
+  return fd;
+}
+
+// Close n staged files; with do_fsync (policy `always`) each is fsynced
+// first. errs[i]: 0, or the errno of the fsync (the file is closed all the
+// same). An fd below 0 (a file closed before) is passed over.
+void mt_close_fds(const int* fds, int n, int do_fsync, int* errs) {
+  for (int i = 0; i < n; i++) {
+    errs[i] = 0;
+    if (fds[i] < 0) continue;
+    if (do_fsync && fsync(fds[i]) != 0) errs[i] = errno ? errno : EIO;
+    close(fds[i]);
+  }
+}
+
+// Commit one version on one drive, the file-system half of rename_data:
+//   1. mkdir <vol>/<obj> (every directory of `obj` below the volume)
+//   2. [always: fsync <src>]  rename <src> -> <vol>/<obj>/<ddir>, removing a
+//      directory found in the way first  [always: fsync <vol>/<obj>]
+//   3. write `meta` to <tmp_parent>/xl.meta  [always: fsync it]
+//      rename it -> <vol>/<obj>/xl.meta      [always: fsync <vol>/<obj>]
+//   4. remove the replaced data directories `purge` (n_purge names,
+//      NUL-separated) of <vol>/<obj>, then <tmp_parent>
+// Returns 0, or the step that failed with out[1] = errno. out[3], out[4]:
+// fsyncs made of kind file / dir; out[5]: data directories that could not be
+// removed; out[6]: 1 when <tmp_parent> could not be. Steps 1-3 stop at the
+// first failure; step 4 is the clean-up of a commit that stands.
+int mt_commit_version(const char* vol, const char* obj, const char* ddir,
+                      const char* src, const char* tmp_parent,
+                      const uint8_t* meta, long meta_len, const char* purge,
+                      int n_purge, int do_fsync, int* out) {
+  for (int i = 0; i < 7; i++) out[i] = 0;
+  auto fail = [&](int step, int e) {
+    out[0] = step;
+    out[1] = e;
+    return step;
+  };
+  auto synced = [&](const char* path, bool dir) {
+    if (!do_fsync) return 0;
+    const int e = fsync_at(path, dir ? O_DIRECTORY : 0, &out[dir ? 4 : 3]);
+    if (e) out[2] = dir ? 1 : 0;
+    return e;
+  };
+  int e = mkdirs_below(vol, obj, false);
+  if (e) return fail(kStepObjectDir, e);
+  const std::string odir = std::string(vol) + "/" + obj;
+  const std::string dst = odir + "/" + ddir;
+  struct stat st;
+  if (stat(src, &st) != 0 || !S_ISDIR(st.st_mode))
+    return fail(kStepStaged, ENOENT);
+  if (stat(dst.c_str(), &st) == 0 && S_ISDIR(st.st_mode) &&
+      rm_tree(dst.c_str()) < 0)
+    return fail(kStepDataRename, errno);
+  if ((e = synced(src, true))) return fail(kStepFsync, e);
+  if (rename(src, dst.c_str()) != 0) return fail(kStepDataRename, errno);
+  if ((e = synced(odir.c_str(), true))) return fail(kStepFsync, e);
+
+  const std::string tmp = std::string(tmp_parent) + "/xl.meta";
+  int fd = open(tmp.c_str(), O_WRONLY | O_CREAT | O_TRUNC | O_CLOEXEC, 0666);
+  if (fd < 0) return fail(kStepMetaWrite, errno);
+  for (long done = 0; done < meta_len;) {
+    ssize_t w = write(fd, meta + done, (size_t)(meta_len - done));
+    if (w < 0 && errno == EINTR) continue;
+    if (w <= 0) {
+      e = w < 0 ? errno : EIO;
+      close(fd);
+      return fail(kStepMetaWrite, e);
+    }
+    done += w;
+  }
+  if (do_fsync) {
+    if (fsync(fd) != 0) {
+      e = errno;
+      close(fd);
+      out[2] = 0;
+      return fail(kStepFsync, e);
+    }
+    out[3]++;
+  }
+  close(fd);
+  const std::string mdst = odir + "/xl.meta";
+  if (rename(tmp.c_str(), mdst.c_str()) != 0)
+    return fail(kStepMetaRename, errno);
+  if ((e = synced(odir.c_str(), true))) return fail(kStepFsync, e);
+
+  for (const char* name = purge; n_purge > 0; n_purge--) {
+    if (rm_tree((odir + "/" + name).c_str()) < 0) out[5]++;
+    name += std::strlen(name) + 1;
+  }
+  if (rm_tree(tmp_parent) < 0) out[6] = 1;
+  return kStepDone;
 }
 
 }  // extern "C"

@@ -25,7 +25,9 @@ from .. import qos as _qos
 from ..erasure.bitrot import (BITROT_CHUNK_KEY, BitrotAlgorithm,
                               pick_bitrot_chunk)
 from ..erasure.codec import ceil_div
-from ..erasure.streaming import erasure_decode, erasure_encode, erasure_heal
+from ..erasure.streaming import (close_readers, close_writers,
+                                 erasure_decode, erasure_encode,
+                                 erasure_heal)
 from ..storage.datatypes import ErasureInfo, FileInfo, ObjectPartInfo
 from ..storage.xlstorage import META_BUCKET, META_TMP, new_tmp_id
 from ..utils import errors
@@ -438,13 +440,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                     w.abort()
             self._cleanup_tmp(tmp_id)
             raise to_object_err(e, bucket, object) from e
-        for j, w in enumerate(writers):
-            if w is None:
-                continue
-            try:
-                w.close()
-            except Exception:  # noqa: BLE001
-                writers[j] = None
+        close_writers(writers)  # a writer that fails to close is None
 
         if size >= 0 and total != size:
             self._cleanup_tmp(tmp_id)
@@ -763,10 +759,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             except Exception as e:  # noqa: BLE001
                 raise to_object_err(e, bucket, object) from e
             finally:
-                for r in readers:
-                    src = getattr(r, "src", None)
-                    if src is not None and hasattr(src, "close"):
-                        src.close()
+                close_readers(readers)
             shard_errs.extend(stats.errs)
         # heal-on-read signal (cmd/erasure-object.go:325-336) through the
         # single bitrot/degraded funnel: corrupt shards -> deep MRF heal.
@@ -1483,10 +1476,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                     func="heal.shard", path=f"{bucket}/{object}",
                     duration_s=dur, input_bytes=shard_bytes,
                     error=heal_err)
-                for r in readers:
-                    src = getattr(r, "src", None)
-                    if src is not None and hasattr(src, "close"):
-                        src.close()
+                close_readers(readers)
         for i in to_heal:
             if i in failed_targets:
                 continue  # incomplete/non-durable tmp shards stay tmp

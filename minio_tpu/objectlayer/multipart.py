@@ -13,7 +13,7 @@ from dataclasses import replace
 import msgpack
 
 from ..erasure import Erasure, new_bitrot_writer
-from ..erasure.streaming import erasure_encode
+from ..erasure.streaming import close_writers, erasure_encode
 from ..obs import metrics as _mx
 from ..obs import spans as _spans
 from ..storage.datatypes import ErasureInfo, FileInfo, ObjectPartInfo
@@ -206,12 +206,7 @@ class MultipartMixin:
                 if w is not None:
                     w.abort()
             raise to_object_err(e, bucket, object) from e
-        for j, w in enumerate(writers):
-            if w is not None:
-                try:
-                    w.close()
-                except Exception:  # noqa: BLE001
-                    writers[j] = None
+        close_writers(writers)  # a writer that fails to close is None
         if size >= 0 and total != size:
             raise dt.IncompleteBody(bucket, object)
 

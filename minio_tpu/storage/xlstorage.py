@@ -17,21 +17,26 @@ host file I/O syscalls.
 """
 from __future__ import annotations
 
+import errno
 import os
 import shutil
+import stat
 import threading
 import time
 import uuid
 from typing import Iterator
 
 from .. import fault as _fault
+from .. import native as _native
 from ..obs import latency as _lat
+from ..obs import metrics as _mx
 from ..obs import spans as _spans
 from ..obs import trace as _trc
 from ..utils import errors
 from .datatypes import DiskInfo, FileInfo, VolInfo
-from .durability import (durable_replace, durable_replace_dir,
-                         fsync_after_write)
+from .durability import (FSYNC_ALWAYS, FSYNC_BATCHED, durable_replace,
+                         durable_replace_dir, flusher, fsync_after_write,
+                         fsync_mode, fsync_path)
 from .interface import StorageAPI
 from .xlmeta import XL_META_CORRUPT_FILE, XL_META_FILE, XLMeta
 
@@ -93,8 +98,20 @@ def _minted_by_live_peer(name: str) -> bool:
         return True  # EPERM etc.: exists under another uid — alive
 
 
+def _native_fs() -> bool:
+    """The rule that picks the route of a PUT's file-system sequences
+    (stage a shard file, commit a version): one native call each when the
+    native library is loaded and no disk fault is armed, else the Python
+    sequence, a system call a turn at the interpreter lock. Nothing
+    steers it: the Python sequence is the only one without a compiler,
+    the one the crash points (``_write_step``) live in, and the
+    reference the native one is tested against."""
+    return not _fault.armed("disk") and _native.available()
+
+
 class _FileWriter:
-    """Streaming file writer with abort support."""
+    """Streaming file writer with abort support: the Python sequence
+    (``_StagedFile`` is its native twin)."""
 
     def __init__(self, path: str):
         os.makedirs(os.path.dirname(path), exist_ok=True)
@@ -118,7 +135,6 @@ class _FileWriter:
         # ``batched`` must NOT enqueue this soon-to-be-renamed tmp path
         # — rename_data enqueues the files at their committed location
         # instead (durable_replace_dir's tree marker)
-        from .durability import FSYNC_ALWAYS, fsync_mode, fsync_path
         if fsync_mode() == FSYNC_ALWAYS:
             # strict: a failed shard writeback fails THIS disk's write;
             # quorum routes around it instead of committing air
@@ -132,6 +148,69 @@ class _FileWriter:
             pass
 
 
+class _StagedFile:
+    """A shard's staging file whose directories were made and which was
+    opened in ONE native call (``native.stage_file``), where
+    ``_FileWriter`` takes five turns at the interpreter lock: holds the
+    raw fd ``mt_put_block_fds`` writes to. ``write``, ``close`` (fsynced
+    first under ``always``) and ``abort`` mean what ``_FileWriter``'s do;
+    ``close_many`` closes the files of all a PUT's drives in one call
+    (erasure/streaming.py ``close_writers``)."""
+
+    __slots__ = ("_path", "_fd")
+
+    def __init__(self, path: str, fd: int):
+        self._path = path
+        self._fd = fd
+
+    def write(self, b: bytes):
+        mv = memoryview(b).cast("B")
+        while mv.nbytes:
+            mv = mv[os.write(self._fd, mv):]
+
+    def fileno(self) -> int:
+        return self._fd
+
+    def close(self):
+        err = self.close_many([self])[0]
+        if err is not None:
+            raise err
+
+    @staticmethod
+    def close_many(files: list["_StagedFile"]) -> list[OSError | None]:
+        """Close every file (one native call); per file None, or the
+        error of its fsync under ``always``: strict as in
+        ``_FileWriter.close``, and that file's drive's alone."""
+        always = fsync_mode() == FSYNC_ALWAYS
+        fds = [f._fd for f in files]  # below 0: closed before
+        out: list[OSError | None] = []
+        for f, fd, e in zip(files, fds, _native.close_fds(fds, always)):
+            f._fd = -1
+            if e:
+                _mx.inc("minio_tpu_durability_fsync_failed_total",
+                        kind="file")
+                out.append(OSError(e, os.strerror(e), f._path))
+                continue
+            if always and fd >= 0:
+                _mx.inc("minio_tpu_durability_fsync_total", kind="file")
+            out.append(None)
+        return out
+
+    def abort(self):
+        self._drop()
+        try:
+            os.unlink(self._path)
+        except OSError:
+            pass
+
+    def _drop(self):
+        if self._fd >= 0:
+            os.close(self._fd)
+            self._fd = -1
+
+    __del__ = _drop  # a raw fd has no GC finalizer
+
+
 class _FileReadAt:
     """Positional reads over one shard file (reference odirectReader /
     ReadFileStream, cmd/xl-storage.go:1381). Raw os.open, not io.open:
@@ -140,9 +219,16 @@ class _FileReadAt:
     under concurrent reads."""
 
     def __init__(self, path: str, endpoint: str = ""):
-        self._fd = -1  # __del__ runs even when os.open below raises
+        self._fd = -1  # __del__ runs even when the open below raises
         self._endpoint = endpoint
         try:
+            if _native_fs():
+                # open + fstat in one turn at the interpreter lock
+                fd = _native.open_shard(path)
+                if fd < 0:
+                    raise OSError(-fd, os.strerror(-fd), path)
+                self._fd = fd
+                return
             self._fd = os.open(path, os.O_RDONLY)
         except FileNotFoundError:
             raise errors.FileNotFound(path) from None
@@ -150,8 +236,7 @@ class _FileReadAt:
             raise errors.IsNotRegular(path) from None
         # os.open(dir) succeeds on Linux where io.open raised — keep the
         # IsNotRegular contract
-        import stat as _stat
-        if _stat.S_ISDIR(os.fstat(self._fd).st_mode):
+        if stat.S_ISDIR(os.fstat(self._fd).st_mode):
             os.close(self._fd)
             self._fd = -1
             raise errors.IsNotRegular(path)
@@ -177,6 +262,13 @@ class _FileReadAt:
         if self._fd >= 0:
             os.close(self._fd)
             self._fd = -1
+
+    def detach_fd(self) -> int:
+        """Hand the fd over (this reader is closed from here on): whoever
+        closes the shard files of one read closes them together
+        (erasure/streaming.py ``close_readers``). Below 0: closed before."""
+        fd, self._fd = self._fd, -1
+        return fd
 
     def __del__(self):  # belt-and-braces: raw fds have no GC finalizer
         self.close()
@@ -335,10 +427,14 @@ class XLStorage(StorageAPI):
 
     def stat_vol(self, volume: str) -> VolInfo:
         with self._op("stat_vol", volume):
-            p = self._abs(volume)
-            if not os.path.isdir(p):
+            # one stat, not isdir + stat: every request pays this turn
+            try:
+                st = os.stat(self._abs(volume))
+            except OSError:
+                raise errors.VolumeNotFound(volume) from None
+            if not stat.S_ISDIR(st.st_mode):
                 raise errors.VolumeNotFound(volume)
-            return VolInfo(name=volume, created=os.stat(p).st_ctime)
+            return VolInfo(name=volume, created=st.st_ctime)
 
     def delete_vol(self, volume: str, force: bool = False) -> None:
         with self._op("delete_vol", volume):
@@ -386,16 +482,23 @@ class XLStorage(StorageAPI):
             sp.out_bytes = len(out)
             return out
 
-    def _read_all_inner(self, volume: str, path: str) -> bytes:
+    def _read_all_inner(self, volume: str, path: str,
+                        probe_volume: bool = True) -> bytes:
         """Untraced read_all for composite ops (xl.meta loads) — keeps
         one logical storage call = one span/window observation. Raw
-        os.open/os.read, not io.open: xl.meta reads run 20x per GET on a
-        16+4 set and the BufferedReader construction was measurable GIL
-        time under concurrent requests."""
+        os.open/os.read, not io.open: one xl.meta read is four turns at
+        the interpreter lock (open, fstat, read, close), a quorum pass
+        49 of them on a 12-drive set and 25 on a 6-drive one, and on the
+        chip's host a turn costs ~3 ms (12 drives) or ~2 ms (6) beside 20
+        clients (PERF.md section 6, PRs 30, 31): a STAT that moves no
+        byte takes 147-160 ms at 12 drives, 53-60 ms at 6. A missing
+        file is told from a missing volume by a probe AFTER the failure;
+        ``probe_volume=False`` leaves even that out for a caller whose
+        next step fails on a missing volume anyway (rename_data)."""
         try:
             fd = os.open(self._abs(volume, path), os.O_RDONLY)
         except FileNotFoundError:
-            if not os.path.isdir(self._abs(volume)):
+            if probe_volume and not os.path.isdir(self._abs(volume)):
                 raise errors.VolumeNotFound(volume) from None
             raise errors.FileNotFound(path) from None
         except IsADirectoryError:
@@ -421,11 +524,29 @@ class XLStorage(StorageAPI):
         with self._op("write_all", volume, path, in_bytes=len(data)):
             self._write_all_inner(volume, path, data)
 
+    def _make_parent(self, volume: str, dst: str) -> None:
+        """mkdir -p of ``dst``'s directory, below the volume and never
+        the volume itself. One mkdir when the directory is there or only
+        it is missing; the VolumeNotFound probe comes after a failure
+        (before PR 36 every write paid an ``isdir`` of the volume and
+        ``makedirs``' own ``stat`` first: three turns, now one)."""
+        parent = os.path.dirname(dst)
+        vol_root = self._abs(volume)
+        if parent != vol_root:
+            try:
+                os.mkdir(parent)
+                return
+            except FileExistsError:
+                return
+            except (FileNotFoundError, NotADirectoryError):
+                pass  # a volume that is no directory is "not found" too
+        if not os.path.isdir(vol_root):
+            raise errors.VolumeNotFound(volume)
+        os.makedirs(parent, exist_ok=True)
+
     def _write_all_inner(self, volume: str, path: str, data: bytes) -> None:
         dst = self._abs(volume, path)
-        if not os.path.isdir(self._abs(volume)):
-            raise errors.VolumeNotFound(volume)
-        os.makedirs(os.path.dirname(dst), exist_ok=True)
+        self._make_parent(volume, dst)
         tmp = self._abs(META_TMP, new_tmp_id())
         with open(tmp, "wb") as f:
             f.write(data)
@@ -445,7 +566,19 @@ class XLStorage(StorageAPI):
     def create_file_writer(self, volume: str, path: str):
         if _fault.armed("disk"):
             _fault.inject("disk", self._endpoint, "create_file_writer")
-        return _FileWriter(self._abs(volume, path))
+        full = self._abs(volume, path)
+        if _native_fs():
+            fd = _native.stage_file(self._abs(volume), path)
+            if fd >= 0:
+                _mx.inc("minio_tpu_storage_staged_files_total",
+                        route="native")
+                return _StagedFile(full, fd)
+            if fd != -errno.ENOENT:
+                raise OSError(-fd, os.strerror(-fd), full)
+            # the volume is not there: makedirs below makes it, as ever
+        w = _FileWriter(full)
+        _mx.inc("minio_tpu_storage_staged_files_total", route="python")
+        return w
 
     def read_file_at(self, volume: str, path: str):
         if _fault.armed("disk"):
@@ -512,10 +645,12 @@ class XLStorage(StorageAPI):
     def _meta_path(self, volume: str, path: str) -> str:
         return self._abs(volume, path, XL_META_FILE)
 
-    def _load_meta(self, volume: str, path: str) -> XLMeta:
+    def _load_meta(self, volume: str, path: str,
+                   probe_volume: bool = True) -> XLMeta:
         # untraced inner read: the calling meta op owns the span
         try:
-            blob = self._read_all_inner(volume, f"{path}/{XL_META_FILE}")
+            blob = self._read_all_inner(volume, f"{path}/{XL_META_FILE}",
+                                        probe_volume)
         except errors.FileNotFound:
             raise errors.FileNotFound(path) from None
         try:
@@ -551,8 +686,7 @@ class XLStorage(StorageAPI):
                 durable_replace(src, dst)  # graftlint: disable=GL021
             except OSError:
                 return False
-        from ..obs import metrics as mx
-        mx.inc("minio_tpu_durability_quarantined_meta_total")
+        _mx.inc("minio_tpu_durability_quarantined_meta_total")
         return True
 
     def _store_meta(self, volume: str, path: str, meta: XLMeta) -> None:
@@ -567,9 +701,30 @@ class XLStorage(StorageAPI):
                     dst_volume: str, dst_path: str) -> None:
         """Commit a freshly written object version: move
         ``<src>/<dataDir>`` under the object dir and add the version to
-        xl.meta atomically w.r.t. this disk (reference RenameData)."""
+        xl.meta atomically w.r.t. this disk (reference RenameData).
+
+        Shard files (``fi.data`` is None) commit through ONE native call
+        when ``_native_fs()`` says so (``_commit_native``); inline data
+        and every run with a disk fault armed take the Python sequence
+        below, where the crash points are. Both leave the same tree and
+        issue the same fsyncs in the same order (docs/durability.md,
+        tests/test_put_turns.py). Why: counted from Python the sequence
+        is 21 file-system calls a drive, each a turn at the interpreter
+        lock of ~3 ms beside 20 clients on the chip's host (12 drives;
+        ~2 ms at 6); with the staging before it a 10 MiB PUT made 314
+        such calls at 12 drives and 158 at 6 (PERF.md section 6, PR 36).
+        Now a drive's commit is 2 (the ``xl.meta`` read, the native
+        call) and a PUT 38 and 20."""
+        native_route = bool(fi.data_dir) and fi.data is None \
+            and _native_fs()
+        _mx.inc("minio_tpu_storage_commits_total",
+                route="native" if native_route else "python")
         with self._op("rename_data", dst_volume, dst_path), \
                 self._meta_lock:
+            if native_route:
+                self._commit_native(src_volume, src_path, fi,  # graftlint: disable=GL021
+                                    dst_volume, dst_path)
+                return
             try:
                 meta = self._load_meta(dst_volume, dst_path)  # graftlint: disable=GL021
             except errors.FileNotFound:
@@ -604,8 +759,61 @@ class XLStorage(StorageAPI):
         except FileNotFoundError:
             pass
         except OSError:
-            from ..obs import metrics as mx
-            mx.inc("minio_tpu_durability_purge_failed_total", kind="tmp")
+            _mx.inc("minio_tpu_durability_purge_failed_total", kind="tmp")
+
+    def _commit_native(self, src_volume: str, src_path: str, fi: FileInfo,
+                       dst_volume: str, dst_path: str) -> None:
+        """rename_data's file-system sequence as one native call
+        (native/pipeline.cpp mt_commit_version: object directory, data
+        rename, xl.meta under its tmp name and renamed over, replaced
+        data directories and the tmp parent removed; under ``always``
+        the Python sequence's fsyncs at the Python sequence's places).
+        The journal logic, the policy, the errors and the counters stay
+        here. The volume is probed only after a failure: a missing one
+        fails the native call's first mkdir."""
+        try:
+            meta = self._load_meta(dst_volume, dst_path, probe_volume=False)
+        except errors.FileNotFound:
+            meta = XLMeta()
+        old_ddirs = meta.add_version(fi)
+        dst = self._abs(dst_volume, dst_path, fi.data_dir)
+        mode = fsync_mode()
+        step, err, sync_kind, file_syncs, dir_syncs, ddirs_left, tmp_left = \
+            _native.commit_version(
+                self._abs(dst_volume), dst_path, fi.data_dir,
+                self._abs(src_volume, src_path, fi.data_dir),
+                self._abs(src_volume, src_path.split("/")[0]),
+                meta.dump(), old_ddirs, mode == FSYNC_ALWAYS)
+        if file_syncs:
+            _mx.inc("minio_tpu_durability_fsync_total", file_syncs,
+                    kind="file")
+        if dir_syncs:
+            _mx.inc("minio_tpu_durability_fsync_total", dir_syncs,
+                    kind="dir")
+        if mode == FSYNC_BATCHED:
+            # the markers durable_replace_dir and durable_replace leave,
+            # for as far as the sequence came
+            if step == 0 or step > _native.COMMIT_DATA_RENAME:
+                flusher().enqueue_tree(dst)
+            if step == 0:
+                flusher().enqueue(self._meta_path(dst_volume, dst_path))
+        if step == 0:
+            if ddirs_left:
+                _mx.inc("minio_tpu_durability_purge_failed_total",
+                        ddirs_left, kind="ddir")
+            if tmp_left:
+                _mx.inc("minio_tpu_durability_purge_failed_total",
+                        kind="tmp")
+            return
+        if step == _native.COMMIT_STAGED:
+            raise errors.FileNotFound(src_path)
+        if step == _native.COMMIT_OBJECT_DIR and err == errno.ENOENT \
+                and not os.path.isdir(self._abs(dst_volume)):
+            raise errors.VolumeNotFound(dst_volume)
+        if step == _native.COMMIT_FSYNC:
+            _mx.inc("minio_tpu_durability_fsync_failed_total",
+                    kind="dir" if sync_kind else "file")
+        raise OSError(err, os.strerror(err), dst)
 
     def _purge_ddirs(self, volume: str, path: str, ddirs: list[str]):
         """Remove data dirs of replaced versions (overwrite cleanup).
@@ -617,9 +825,8 @@ class XLStorage(StorageAPI):
             except FileNotFoundError:
                 pass
             except OSError:
-                from ..obs import metrics as mx
-                mx.inc("minio_tpu_durability_purge_failed_total",
-                       kind="ddir")
+                _mx.inc("minio_tpu_durability_purge_failed_total",
+                        kind="ddir")
 
     def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
         with self._op("write_metadata", volume, path), self._meta_lock:

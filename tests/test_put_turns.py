@@ -1,0 +1,589 @@
+"""A PUT's drive work takes a turn at the interpreter lock a drive (ISSUE
+36): shard files are staged (``native.stage_file``) and a version is
+committed (``native.commit_version``) in one native call each, where the
+Python sequences make a file-system call a step.
+
+* the count: every ``os.*`` file-system function, ``builtins.open`` and the
+  three native sequences are wrapped and counted by thread while one 10 MiB
+  object is PUT under a new key over 12 and over 6 ``XLStorage`` drives
+  (the tree before read 62 on the request's thread and 21 a drive's
+  commit, 314 / 158 a PUT; a STAT read 50 / 26 by the same method, a GET
+  86 / 44: the readers' own cut took those to 49 / 25 and 62 / 32);
+* the native and the Python sequence leave the same tree, the same
+  ``xl.meta`` bytes, the same fsyncs (``always``) and flusher markers
+  (``batched``);
+* they raise the same errors; a drive whose path is a regular file fails
+  with the error the health tracker fences on and leaves nothing staged;
+* a disk fault armed anywhere moves the process onto the Python sequence
+  (where the crash points are), and the route counters say so."""
+import builtins
+import collections
+import io
+import os
+import threading
+import uuid
+from dataclasses import replace
+
+import numpy as np
+import pytest
+
+from minio_tpu import fault, native
+from minio_tpu.objectlayer import ErasureObjects
+from minio_tpu.obs import metrics as mx
+from minio_tpu.storage import (ErasureInfo, FileInfo, ObjectPartInfo,
+                               XLStorage)
+from minio_tpu.storage import durability
+from minio_tpu.storage.health import DiskHealthCheck
+from minio_tpu.storage.xlmeta import XL_META_FILE
+from minio_tpu.storage.xlstorage import META_TMP, _FileWriter, _StagedFile
+from minio_tpu.utils import errors
+
+pytestmark = pytest.mark.skipif(not native.available(),
+                                reason="no native library")
+
+#: every os function that names or holds a file: each lets go of the
+#: interpreter lock and waits for it again
+OS_CALLS = (
+    "stat", "lstat", "fstat", "statvfs", "access", "open", "close", "read",
+    "write", "pread", "pwrite", "fsync", "fdatasync", "mkdir", "rmdir",
+    "unlink", "remove", "rename", "replace", "scandir", "listdir", "link",
+    "symlink", "readlink", "truncate", "ftruncate", "utime", "chmod")
+NATIVE_CALLS = ("stage_file", "close_fds", "commit_version", "open_shard")
+
+#: a rule that matches no drive: arming it is what moves the process onto
+#: the Python sequences
+NO_DRIVE = "disk:no-such-drive:read_at:delay(1)"
+
+
+@pytest.fixture(autouse=True)
+def _clean():
+    fault.clear()
+    yield
+    fault.clear()
+
+
+def _body(n, seed=7):
+    return np.random.default_rng(seed).integers(
+        0, 256, n, dtype=np.uint8).tobytes()
+
+
+def _layer(root, n, parity):
+    return ErasureObjects(
+        [XLStorage(os.path.join(root, f"d{i:02d}")) for i in range(n)],
+        default_parity=parity)
+
+
+def _route_counters():
+    snap = mx.counters_snapshot()
+    return {(fam, route): snap.get(
+        f'minio_tpu_storage_{fam}_total{{route="{route}"}}', 0)
+        for fam in ("staged_files", "commits")
+        for route in ("native", "python")}
+
+
+def _route_delta(before):
+    after = _route_counters()
+    return {k: after[k] - before[k] for k in after}
+
+
+# --- (a) the count ----------------------------------------------------------
+
+class _Turns:
+    """Counts the wrapped calls by (thread, drive of the rename_data the
+    thread is inside, name) while ``on``: those of the thread that made
+    it (the request's), those inside a ``rename_data``, and any other
+    thread's that name a path under ``root`` (a thread another test of the
+    process left running is none of the PUT's)."""
+
+    def __init__(self, monkeypatch, root):
+        self.calls = collections.Counter()
+        self.on = False
+        self.root = root
+        self.request = threading.get_ident()
+        self._drive = threading.local()
+        for name in OS_CALLS:
+            self._wrap(monkeypatch, os, name)
+        self._wrap(monkeypatch, builtins, "open")
+        for name in NATIVE_CALLS:
+            self._wrap(monkeypatch, native, name)
+        turns, orig = self, XLStorage.rename_data
+
+        def rename_data(disk, *a, **kw):
+            turns._drive.base = disk.base
+            try:
+                return orig(disk, *a, **kw)
+            finally:
+                turns._drive.base = None
+
+        monkeypatch.setattr(XLStorage, "rename_data", rename_data)
+
+    def _wrap(self, monkeypatch, mod, name):
+        orig = getattr(mod, name)
+
+        def counted(*a, **kw):
+            if self.on:
+                tid = threading.get_ident()
+                drive = getattr(self._drive, "base", None)
+                if tid == self.request or drive is not None or (
+                        a and isinstance(a[0], str)
+                        and a[0].startswith(self.root)):
+                    self.calls[(tid, drive, f"{mod.__name__}.{name}")] += 1
+            return orig(*a, **kw)
+
+        monkeypatch.setattr(mod, name, counted)
+
+    def __enter__(self):
+        self.calls.clear()
+        self.on = True
+        return self
+
+    def __exit__(self, *exc):
+        self.on = False
+
+    def total(self):
+        return sum(self.calls.values())
+
+    def of_request(self):
+        return sum(v for (t, _, _), v in self.calls.items()
+                   if t == self.request)
+
+    def by_commit(self):
+        out = collections.Counter()
+        for (_, base, _), v in self.calls.items():
+            if base is not None:
+                out[base] += v
+        return out
+
+
+@pytest.mark.parametrize("n,parity,whole", [(12, 4, 60), (6, 2, 34)])
+def test_put_takes_a_turn_a_drive(tmp_path, monkeypatch, n, parity, whole):
+    """ISSUE 36's ceilings: the request's thread at most 16 calls, a
+    drive's commit at most 3, the whole PUT at most 60 (12 drives) / 34
+    (6): the tree before read 62, 21, 314 / 158."""
+    ol = _layer(str(tmp_path), n, parity)
+    ol.make_bucket("b")
+    body = _body(10 << 20)
+    ol.put_object("b", "warm", io.BytesIO(body), len(body))
+    turns = _Turns(monkeypatch, str(tmp_path))
+    before = _route_counters()
+    with turns:
+        ol.put_object("b", "k/new", io.BytesIO(body), len(body))
+    assert ol.get_object_bytes("b", "k/new") == body
+    assert turns.of_request() <= 16, turns.calls
+    commits = turns.by_commit()
+    assert len(commits) == n
+    assert max(commits.values()) <= 3, turns.calls
+    assert turns.total() <= whole, turns.calls
+    assert _route_delta(before) == {
+        ("staged_files", "native"): n, ("commits", "native"): n,
+        ("staged_files", "python"): 0, ("commits", "python"): 0}
+    # the readers, whose remaining turns the writers' cut made dearer: a
+    # STAT is the bucket's stat (two before) and a quorum pass of four
+    # calls a drive (50 / 26 before); a GET adds one open a shard file
+    # (with its fstat: one native call) and one close of them all (86 /
+    # 44 before)
+    with turns:
+        ol.get_object_info("b", "k/new")
+    assert turns.total() == 4 * n + 1, turns.calls
+    with turns:
+        assert ol.get_object_bytes("b", "k/new") == body
+    assert turns.total() <= 5 * n + 2, turns.calls
+
+
+# --- (b) the two sequences leave the same tree ------------------------------
+
+def _fi(vid="", ddir=None, data=None, size=11):
+    return FileInfo(
+        volume="bucket", name="obj", version_id=vid,
+        data_dir=ddir if ddir is not None else str(uuid.uuid4()),
+        mod_time=1700000000.0 + size, size=size, data=data,
+        metadata={"content-type": "text/plain", "etag": "e" * 32},
+        parts=[ObjectPartInfo(number=1, size=size, actual_size=size)],
+        erasure=ErasureInfo(data_blocks=4, parity_blocks=2,
+                            block_size=1 << 20, index=1,
+                            distribution=list(range(1, 7))))
+
+
+def _tree(root):
+    """Every directory and file below ``root`` with the files' bytes."""
+    out = {}
+    for d, dirs, files in os.walk(root):
+        rel = os.path.relpath(d, root)
+        out[rel + "/"] = None
+        for f in files:
+            with open(os.path.join(d, f), "rb") as fh:
+                out[os.path.join(rel, f)] = fh.read()
+    return out
+
+
+def _fsyncs():
+    snap = mx.counters_snapshot()
+    return {k: snap.get(f'minio_tpu_durability_fsync_total{{kind="{k}"}}', 0)
+            for k in ("file", "dir")}
+
+
+def _commit(disk, key, fi, shard=b"shard-bytes", parts=(1,)):
+    """Stage ``parts`` and commit ``fi`` the way put_object does."""
+    tmp_id = f"{os.getpid()}-{uuid.uuid4()}"
+    if fi.data is None:
+        for p in parts:
+            w = disk.create_file_writer(
+                META_TMP, f"{tmp_id}/{fi.data_dir}/part.{p}")
+            w.write(shard)
+            w.write(memoryview(np.frombuffer(shard, dtype=np.uint8)))
+            w.close()
+    disk.rename_data(META_TMP, tmp_id, fi, "bucket", key)
+
+
+#: name -> (key, the versions committed one after another)
+CASES = {
+    "new_key": ("obj", lambda: [_fi()]),
+    "overwrite": ("obj", lambda: [_fi(size=11), _fi(size=12)]),
+    "versioned": ("obj", lambda: [_fi(vid=str(uuid.uuid4()), size=11),
+                                  _fi(vid=str(uuid.uuid4()), size=12)]),
+    "nested_prefix": ("a/b/c/obj", lambda: [_fi()]),
+    "same_data_dir": ("obj", lambda: (lambda f: [f, replace(f, size=12)])(
+        _fi())),
+    "inline_data": ("obj", lambda: [_fi(ddir="", data=b"tiny")]),
+}
+
+
+@pytest.mark.parametrize("mode", ["off", "batched", "always"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_native_and_python_sequences_leave_the_same_tree(
+        tmp_path, monkeypatch, case, mode):
+    monkeypatch.setenv("MINIO_TPU_FSYNC", mode)
+    markers = []
+    fl = durability.flusher()
+    monkeypatch.setattr(fl, "enqueue_tree",
+                        lambda p: markers.append(("tree", p)))
+    monkeypatch.setattr(fl, "enqueue", lambda p: markers.append(("file", p)))
+    key, versions = CASES[case]
+    versions = versions()
+    seen = {}
+    for route in ("native", "python"):
+        disk = XLStorage(str(tmp_path / route))
+        disk.make_vol("bucket")
+        if route == "python":
+            fault.arm(NO_DRIVE)
+        del markers[:]
+        syncs, routes = _fsyncs(), _route_counters()
+        for fi in versions:
+            _commit(disk, key, fi, parts=(1, 2))
+        after = _fsyncs()
+        seen[route] = {
+            "tree": _tree(disk.base),
+            "fsyncs": {k: after[k] - syncs[k] for k in after},
+            "markers": [(k, os.path.relpath(p, disk.base))
+                        for k, p in markers],
+            "routes": _route_delta(routes)}
+        fault.clear()
+    nat, py = seen["native"], seen["python"]
+    inline = case == "inline_data"
+    n = len(versions)
+    # the rule: shard files commit natively, inline data never does
+    assert nat["routes"] == {
+        ("staged_files", "native"): 0 if inline else 2 * n,
+        ("commits", "native"): 0 if inline else n,
+        ("staged_files", "python"): 0,
+        ("commits", "python"): n if inline else 0}
+    assert py["routes"][("commits", "python")] == n
+    assert py["routes"][("commits", "native")] == 0
+    assert nat["tree"] == py["tree"]
+    tree = nat["tree"]
+    assert ".minio.sys/tmp/" in tree
+    assert not [p for p in tree if p.startswith(".minio.sys/tmp/")
+                and p != ".minio.sys/tmp/"], "staging left behind"
+    assert tree[f"bucket/{key}/{XL_META_FILE}"]
+    live = {v.data_dir for v in (versions if case == "versioned"
+                                 else versions[-1:]) if v.data_dir}
+    ddirs = {p.split("/")[-2] for p in tree
+             if p.startswith(f"bucket/{key}/") and p.endswith("/")
+             and p != f"bucket/{key}/"}
+    assert ddirs == live  # a replaced version's data directory is purged
+    for d in live:
+        assert tree[f"bucket/{key}/{d}/part.2"] == b"shard-bytes" * 2
+    assert nat["fsyncs"] == py["fsyncs"]
+    assert nat["markers"] == py["markers"]
+    if mode == "always" and not inline:
+        # a version: 2 shard files + xl.meta's tmp; the staged directory,
+        # the object directory after each of the two renames
+        assert nat["fsyncs"] == {"file": 3 * n, "dir": 3 * n}
+    elif mode != "always":
+        assert nat["fsyncs"] == {"file": 0, "dir": 0}
+    if mode == "batched" and not inline:
+        assert nat["markers"] == [
+            m for v in versions
+            for m in (("tree", f"bucket/{key}/{v.data_dir}"),
+                      ("file", f"bucket/{key}/{XL_META_FILE}"))]
+    elif mode != "batched":
+        assert nat["markers"] == []
+    got = XLStorage(str(tmp_path / "native")).read_version("bucket", key)
+    assert got.size == versions[-1].size
+
+
+_SHIM = r"""
+#define _GNU_SOURCE
+#include <dlfcn.h>
+#include <stdio.h>
+#include <stdlib.h>
+#include <unistd.h>
+static void note(const char* op, const char* a, const char* b) {
+  const char* log = getenv("FS_ORDER_LOG");
+  if (!log) return;
+  FILE* f = fopen(log, "a");
+  if (!f) return;
+  fprintf(f, "%s %s %s\n", op, a, b);
+  fclose(f);
+}
+int fsync(int fd) {
+  static int (*real)(int);
+  if (!real) real = (int (*)(int))dlsym(RTLD_NEXT, "fsync");
+  char link[64], path[4096];
+  snprintf(link, sizeof link, "/proc/self/fd/%d", fd);
+  ssize_t n = readlink(link, path, sizeof path - 1);
+  path[n > 0 ? n : 0] = 0;
+  note("fsync", path, "-");
+  return real(fd);
+}
+int rename(const char* a, const char* b) {
+  static int (*real)(const char*, const char*);
+  if (!real) real = (int (*)(const char*, const char*))dlsym(RTLD_NEXT,
+                                                             "rename");
+  note("rename", a, b);
+  return real(a, b);
+}
+"""
+
+_ORDER_DRIVER = """
+import os, sys
+from minio_tpu import fault
+from minio_tpu.storage import XLStorage
+sys.path.insert(0, os.path.dirname(sys.argv[1]))
+import test_put_turns as t
+route, base = sys.argv[2], sys.argv[3]
+disk = XLStorage(base)
+disk.make_vol("bucket")
+if route == "python":
+    fault.arm(t.NO_DRIVE)
+os.environ["FS_ORDER_LOG"] = base + ".log"
+for fi in (t._fi(ddir="dd-one", size=11), t._fi(ddir="dd-two", size=12)):
+    t._commit(disk, "a/obj", fi)
+"""
+
+
+def _staged_name(path, base):
+    """``path`` below the drive ``base``; what is staged under
+    ``.minio.sys/tmp`` by its last component (the ids are minted), and
+    ``xl.meta``'s tmp, which has a name of its own on either route, as
+    ``tmp/xl.meta``."""
+    if path == "-":
+        return path
+    rel = os.path.relpath(path, base)
+    if not rel.startswith(".minio.sys/tmp/"):
+        return rel
+    last = rel.split("/")[-1]
+    return "tmp/" + (last if last in ("part.1", "dd-one", "dd-two")
+                     else "xl.meta")
+
+
+def test_always_issues_the_python_sequences_fsyncs_in_their_order(tmp_path):
+    """Under ``always`` the native sequence fsyncs what the Python one
+    fsyncs, between the same renames: both are watched from below, by a
+    shim around libc's ``fsync`` and ``rename``."""
+    import shutil
+    import subprocess
+    import sys
+    if shutil.which("gcc") is None:
+        pytest.skip("no gcc for the shim")
+    shim = tmp_path / "shim.so"
+    (tmp_path / "shim.c").write_text(_SHIM)
+    subprocess.run(["gcc", "-shared", "-fPIC", "-o", str(shim),
+                    str(tmp_path / "shim.c"), "-ldl"], check=True)
+    logs = {}
+    for route in ("native", "python"):
+        base = str(tmp_path / route)
+        env = dict(os.environ, LD_PRELOAD=str(shim), MINIO_TPU_FSYNC="always",
+                   JAX_PLATFORMS="cpu")
+        env.pop("FS_ORDER_LOG", None)
+        subprocess.run([sys.executable, "-c", _ORDER_DRIVER, __file__, route,
+                        base], check=True, env=env, timeout=120,
+                       cwd=os.path.dirname(os.path.dirname(__file__)))
+        with open(base + ".log") as f:
+            logs[route] = [(op, _staged_name(a, base), _staged_name(b, base))
+                           for op, a, b in map(str.split, f)]
+    one = [("fsync", "tmp/part.1", "-"),
+           ("fsync", "tmp/dd-one", "-"),
+           ("rename", "tmp/dd-one", "bucket/a/obj/dd-one"),
+           ("fsync", "bucket/a/obj", "-"),
+           ("fsync", "tmp/xl.meta", "-"),
+           ("rename", "tmp/xl.meta", "bucket/a/obj/xl.meta"),
+           ("fsync", "bucket/a/obj", "-")]
+    assert logs["native"] == logs["python"]
+    assert logs["native"][:7] == one
+    assert len(logs["native"]) == 14
+
+
+def test_staged_file_means_what_file_writer_means(tmp_path, monkeypatch):
+    """write, close, abort and a second close of the native twin."""
+    disk = XLStorage(str(tmp_path / "d"))
+    w = disk.create_file_writer(META_TMP, "t1/dd/part.1")
+    assert isinstance(w, _StagedFile)
+    os.pwrite(w.fileno(), b"abc", 0)
+    w.close()
+    w.close()  # harmless, and closes nobody else's fd
+    assert disk.read_all(META_TMP, "t1/dd/part.1") == b"abc"
+    w = disk.create_file_writer(META_TMP, "t1/dd/part.2")  # directory there
+    w.write(b"x")
+    w.abort()
+    assert disk.list_dir(META_TMP, "t1/dd") == ["part.1"]
+    # the files of a PUT's drives close in one call; one fsync that fails
+    # under ``always`` is that file's error alone
+    monkeypatch.setenv("MINIO_TPU_FSYNC", "always")
+    ws = [disk.create_file_writer(META_TMP, f"t2/dd/part.{i}")
+          for i in range(3)]
+    monkeypatch.setattr(native, "close_fds", lambda fds, sync: (
+        [os.close(fd) for fd in fds], [0, 5, 0])[1])
+    errs = _StagedFile.close_many(ws)
+    assert [type(e) for e in errs] == [type(None), OSError, type(None)]
+    assert errs[1].errno == 5 and errs[1].filename.endswith("t2/dd/part.1")
+    fault.arm(NO_DRIVE)
+    assert isinstance(disk.create_file_writer(META_TMP, "t3/dd/part.1"),
+                      _FileWriter)
+
+
+# --- (c) the errors ---------------------------------------------------------
+
+def _outcome(fn):
+    try:
+        fn()
+    except Exception as e:  # noqa: BLE001 — the outcome IS the error
+        return type(e), getattr(e, "errno", None)
+    return None
+
+
+@pytest.mark.parametrize("route", ["native", "python"])
+def test_commit_errors_are_the_same_on_both_routes(tmp_path, route):
+    disk = XLStorage(str(tmp_path / "d"))
+    disk.make_vol("bucket")
+    if route == "python":
+        fault.arm(NO_DRIVE)
+
+    def staged(tmp_id, fi):
+        w = disk.create_file_writer(META_TMP,
+                                    f"{tmp_id}/{fi.data_dir}/part.1")
+        w.write(b"s")
+        w.close()
+
+    # the volume is not there
+    fi = _fi()
+    staged("t-vol", fi)
+    with pytest.raises(errors.VolumeNotFound):
+        disk.rename_data(META_TMP, "t-vol", fi, "nobucket", "obj")
+    assert not os.path.exists(os.path.join(disk.base, "nobucket"))
+    # nothing was staged
+    with pytest.raises(errors.FileNotFound):
+        disk.rename_data(META_TMP, "t-none", _fi(), "bucket", "obj")
+    with pytest.raises(errors.VolumeNotFound):
+        disk.write_all("nobucket", "a/b", b"x")
+    with pytest.raises(errors.VolumeNotFound):
+        disk.write_all("nobucket", "top", b"x")
+    assert not os.path.exists(os.path.join(disk.base, "nobucket"))
+    disk.write_all("bucket", "deep/er/file", b"x")  # parents made
+    disk.write_all("bucket", "deep/er/file", b"y")
+    assert disk.read_all("bucket", "deep/er/file") == b"y"
+    # a whole-file read, and what it says of what is not there
+    big = bytes(range(256)) * 300
+    disk.write_all("bucket", "deep/big", big)
+    assert disk.read_all("bucket", "deep/big") == big
+    assert disk.read_all("bucket", "deep/er/file") == b"y"
+    with pytest.raises(errors.FileNotFound):
+        disk.read_all("bucket", "deep/none")
+    with pytest.raises(errors.VolumeNotFound):
+        disk.read_all("nobucket", "deep/none")
+    with pytest.raises(errors.IsNotRegular):
+        disk.read_all("bucket", "deep/er")
+    # a reader's open: open + fstat, one native call or two from Python
+    disk.write_all("bucket", "deep/er/file", b"0123456789")
+    r = disk.read_file_at("bucket", "deep/er/file")
+    assert r.read_at(2, 3) == b"234" and os.pread(r.fileno(), 2, 8) == b"89"
+    r.close()
+    r.close()
+    with pytest.raises(errors.IsNotRegular):
+        disk.read_file_at("bucket", "deep/er")
+    with pytest.raises(errors.FileNotFound):
+        disk.read_file_at("bucket", "deep/er/none")
+    # the object's directory is in the way as a regular file
+    disk.write_all("bucket", "file", b"x")
+    fi = _fi()
+    staged("t-file", fi)
+    out = _outcome(lambda: disk.rename_data(META_TMP, "t-file", fi,
+                                            "bucket", "file/obj"))
+    assert out[0] is NotADirectoryError, out
+
+
+@pytest.mark.parametrize("route", ["native", "python"])
+def test_dead_drive_fails_with_what_the_tracker_fences_on(tmp_path, route):
+    """``xl-8p4-12d-1down``'s dead drive: a regular file at the drive's
+    path. Staging and committing there fail with ENOTDIR, which the
+    health tracker counts (it is no benign answer), the PUT is
+    acknowledged at quorum and nothing is left staged anywhere."""
+    ol = ErasureObjects(
+        [DiskHealthCheck(XLStorage(str(tmp_path / f"d{i:02d}")),
+                         trip_threshold=1000) for i in range(6)],
+        default_parity=2)
+    ol.make_bucket("b")
+    dead = ol.disks[2]
+    os.rename(dead.inner.base, dead.inner.base + ".aside")
+    with open(dead.inner.base, "wb"):
+        pass
+    if route == "python":
+        fault.arm(NO_DRIVE)
+    for fn in (lambda: dead.inner.create_file_writer(META_TMP, "t/d/part.1"),
+               lambda: dead.inner.read_file_at("b", "o/d/part.1"),
+               lambda: dead.inner.read_all("b", "o/xl.meta"),
+               lambda: dead.inner.rename_data(META_TMP, "t", _fi(), "b",
+                                              "o")):
+        assert _outcome(fn) == (NotADirectoryError, 20)
+    # a whole-file write there still answers "no such volume", as it did
+    for path in ("top", "deep/er/file"):
+        assert _outcome(lambda: dead.inner.write_all("b", path, b"x"))[0] \
+            is errors.VolumeNotFound
+    errs = dead.total_errors
+    body = _body(3 << 20)
+    ol.put_object("b", "k", io.BytesIO(body), len(body))
+    assert dead.total_errors > errs
+    assert ol.get_object_bytes("b", "k") == body
+    for d in ol.disks:
+        if d is not dead:
+            assert d.list_dir(META_TMP, "") == []
+
+
+# --- (d) a fault armed: the Python sequence, and the counters say so --------
+
+def test_armed_fault_takes_the_python_route(tmp_path):
+    ol = _layer(str(tmp_path), 6, 2)
+    ol.make_bucket("b")
+    body = _body(3 << 20)
+    before = _route_counters()
+    ol.put_object("b", "native", io.BytesIO(body), len(body))
+    assert _route_delta(before) == {
+        ("staged_files", "native"): 6, ("commits", "native"): 6,
+        ("staged_files", "python"): 0, ("commits", "python"): 0}
+    fault.arm(NO_DRIVE)
+    before = _route_counters()
+    ol.put_object("b", "python", io.BytesIO(body), len(body))
+    assert _route_delta(before) == {
+        ("staged_files", "native"): 0, ("commits", "native"): 0,
+        ("staged_files", "python"): 6, ("commits", "python"): 6}
+    fault.clear()
+    trees = [_tree(os.path.join(d.base, "b")) for d in ol.disks]
+    for t in trees:
+        # the same shape under both keys: xl.meta and one data directory
+        # holding part.1
+        shape = {k: sorted(p.split("/")[-1] for p in t
+                           if p.startswith(k + "/") and not p.endswith("/"))
+                 for k in ("native", "python")}
+        assert shape["native"] == shape["python"] == ["part.1", "xl.meta"]
+    assert ol.get_object_bytes("b", "python") == body
+    assert ol.get_object_bytes("b", "native") == body
