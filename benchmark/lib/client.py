@@ -9,10 +9,13 @@ receives (SHA-256), and times every operation with ``time.monotonic`` (one
 clock for all processes of a machine).
 
 A ``run`` command carries one plan per thread; plans run concurrently and
-the reply holds each thread's records in order. Plan types:
+the reply holds each thread's records in order. The measured window's plans
+come under ``prepare`` (bodies made and hashed, ``ready`` answered) and
+start at the ``go`` that fixes their times. Plan types:
 
 ``ops``   a fixed list of operations, run one after another at once.
-``loop``  a closed loop from ``t_start`` to ``t_end``: operations drawn from
+``loop``  a closed loop from ``t_start`` (at once, where the plan has none)
+          to ``t_end``: operations drawn from
           shuffled copies of ``deck`` over the thread's own live keys; a PUT
           writes a new key with the next of the thread's ``bodies``, which
           were made and hashed before ``t_start``.
@@ -204,18 +207,21 @@ def run_ops(cfg: dict, plan: dict) -> list[dict]:
 def run_loop(cfg: dict, plan: dict) -> list[dict]:
     """Closed loop: the next request goes out when the last has been read
     to its end. A loop that could not start by ``t_start`` says so in a
-    record that fails the run."""
+    record that fails the run; a plan without ``t_start`` (a warm-up, which
+    nothing measures) starts at once."""
     s3 = S3(cfg)
     rng = np.random.default_rng(plan["rng"])
     bucket, live = plan["bucket"], list(plan["keys"])
     deck, puts = list(plan["deck"]), plan["_puts"]
     min_live = plan.get("min_live", 2)
     out, n_put = [], 0
-    late = time.monotonic() - plan["t_start"]
-    if late > 0:
-        return [{"op": "PLAN", "key": "loop", "status": -1, "t0": 0.0,
-                 "t1": 0.0, "err": f"started {late:.3f} s after t_start"}]
-    time.sleep(-late)
+    if "t_start" in plan:
+        late = time.monotonic() - plan["t_start"]
+        if late > 0:
+            return [{"op": "PLAN", "key": "loop", "status": -1, "t0": 0.0,
+                     "t1": 0.0,
+                     "err": f"started {late:.3f} s after t_start"}]
+        time.sleep(-late)
     while True:
         for i in rng.permutation(len(deck)):
             if time.monotonic() >= plan["t_end"]:
@@ -318,31 +324,54 @@ def run_heal(cfg: dict, plan: dict) -> list[dict]:
 RUNNERS = {"ops": run_ops, "loop": run_loop, "heal": run_heal}
 
 
+def run_plans(cfg: dict, plans: list[dict]) -> list:
+    """Prepared plans, one thread each, concurrently; each thread's
+    records in the plans' order."""
+    results: list = [None] * len(plans)
+
+    def work(i, plan):
+        try:
+            results[i] = RUNNERS[plan["type"]](cfg, plan)
+        except Exception as e:  # noqa: BLE001 — reported, not lost
+            results[i] = [{"op": "PLAN", "key": plan["type"],
+                           "status": -1, "t0": 0.0, "t1": 0.0,
+                           "err": f"{type(e).__name__}: {e}"}]
+    ts = [threading.Thread(target=work, args=(i, p))
+          for i, p in enumerate(plans)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+    return results
+
+
 def main() -> int:
+    """``run`` prepares its plans and runs them at once. ``prepare`` only
+    prepares and answers ``ready``; the ``go`` that follows carries the
+    ``t_start`` from which those plans' ``t_start`` and ``t_end`` count: the
+    window's times are fixed once every process is ready, so however long
+    the bodies took, no loop starts late."""
     cfg: dict = {}
+    held: list[dict] = []
     for line in sys.stdin:
         cmd = json.loads(line)
         if cmd["cmd"] == "init":
             cfg = cmd["cfg"]
             reply = {"ok": True, "pid": os.getpid()}
         elif cmd["cmd"] == "run":
-            results: list = [None] * len(cmd["threads"])
             prepare(cfg, cmd["threads"])
-
-            def work(i, plan):
-                try:
-                    results[i] = RUNNERS[plan["type"]](cfg, plan)
-                except Exception as e:  # noqa: BLE001 — reported, not lost
-                    results[i] = [{"op": "PLAN", "key": plan["type"],
-                                   "status": -1, "t0": 0.0, "t1": 0.0,
-                                   "err": f"{type(e).__name__}: {e}"}]
-            ts = [threading.Thread(target=work, args=(i, p))
-                  for i, p in enumerate(cmd["threads"])]
-            for t in ts:
-                t.start()
-            for t in ts:
-                t.join()
-            reply = {"ok": True, "threads": results}
+            reply = {"ok": True, "threads": run_plans(cfg, cmd["threads"])}
+        elif cmd["cmd"] == "prepare":
+            held = cmd["threads"]
+            prepare(cfg, held)
+            reply = {"ok": True, "ready": len(held)}
+        elif cmd["cmd"] == "go":
+            for plan in held:
+                for at in ("t_start", "t_end"):
+                    if at in plan:
+                        plan[at] += cmd["t_start"]
+            reply = {"ok": True, "threads": run_plans(cfg, held)}
+            held = []
         elif cmd["cmd"] == "quit":
             return 0
         else:

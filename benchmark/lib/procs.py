@@ -39,10 +39,9 @@ class ClientPool:
             raise RuntimeError(f"client process {p.pid}: {reply}")
         return reply
 
-    def start(self, plans: list[dict]) -> list[int]:
-        """Start ``plans`` (one per thread), each on the process its
-        ``proc`` names, the rest dealt round-robin; ``finish`` collects.
-        Returns each plan's process."""
+    def _deal(self, cmd: str, plans: list[dict]) -> None:
+        """Send ``plans`` (one per thread) under ``cmd``, each to the
+        process its ``proc`` names, the rest dealt round-robin."""
         per = [[] for _ in self.procs]
         self._where = []
         for i, plan in enumerate(plans):
@@ -51,17 +50,31 @@ class ClientPool:
             per[j].append(plan)
         self._busy = [j for j, t in enumerate(per) if t]
         for j in self._busy:
-            self._send(self.procs[j], {"cmd": "run", "threads": per[j]})
-        return [j for j, _ in self._where]
+            self._send(self.procs[j], {"cmd": cmd, "threads": per[j]})
+
+    def prepare(self, plans: list[dict]) -> None:
+        """Hand out plans whose ``t_start`` and ``t_end`` count from a
+        start that is not fixed yet, and wait until every process has made
+        and hashed its bodies and says so. ``go`` fixes the start."""
+        self._deal("prepare", plans)
+        for j in self._busy:
+            self._recv(self.procs[j])
+
+    def go(self, t_start: float) -> None:
+        """Start the prepared plans: their times count from ``t_start``
+        (``time.monotonic``, one clock for all processes of a machine)."""
+        for j in self._busy:
+            self._send(self.procs[j], {"cmd": "go", "t_start": t_start})
 
     def finish(self) -> list[list[dict]]:
-        """Records of every plan handed to ``start``, in the plans' order."""
+        """Records of every plan dealt last, in the plans' order."""
         replies = {j: self._recv(self.procs[j])["threads"]
                    for j in self._busy}
         return [replies[j][t] for j, t in self._where]
 
     def run(self, plans: list[dict]) -> list[list[dict]]:
-        self.start(plans)
+        """Run ``plans`` at once; their records, in the plans' order."""
+        self._deal("run", plans)
         return self.finish()
 
     def close(self) -> None:

@@ -60,6 +60,44 @@ def scratch_root() -> str:
     return tempfile.mkdtemp(prefix="bench-drives-", dir=base or None)
 
 
+def whole(dirs: list[str], bucket: str, keys: list[str],
+          seconds: float = 90.0) -> None:
+    """Before a read-back that takes shards away: wait until every drive of
+    ``dirs`` that the health tracker fenced is online again (its trip and
+    re-online counters) and every key has its ``xl.meta`` on every one of
+    them. A host that stands still for some seconds makes the operations in
+    flight miss the tracker's deadline: drives are fenced for their
+    cool-down, a PUT meanwhile is acknowledged on the drives that are left,
+    and the program's own heal makes it whole once the drive is back (no
+    heal is asked for here). Until then the object is short of shards by the
+    host's doing, and a read-back less ``parity`` FURTHER drives asks for
+    more than the set promises: that answer is late, not wrong. After
+    ``seconds`` this goes on all the same, and the read-back says what it
+    finds."""
+    import counter_edges
+    trips, back = "minio_tpu_disk_trips_total", "minio_tpu_disk_reonline_total"
+
+    def fenced() -> list[str]:
+        n = dict.fromkeys(dirs, 0.0)
+        for k, v in counter_edges.snapshot((trips, back)).items():
+            d = counter_edges.label(k, "disk")
+            if d in n:
+                n[d] += v if k.startswith(trips) else -v
+        return [os.path.basename(d) for d, v in n.items() if v > 0]
+
+    def short() -> list[str]:
+        return [k for k in keys if not all(os.path.exists(os.path.join(
+            d, bucket, k, "xl.meta")) for d in dirs)]
+
+    t0 = time.monotonic()
+    while (fenced() or short()) and time.monotonic() - t0 < seconds:
+        time.sleep(0.2)
+    if time.monotonic() - t0 >= 0.2:
+        say(f"NOTE whole: waited {time.monotonic() - t0:.1f} s before the "
+            f"read-back less shards; drives still fenced {fenced()}, keys "
+            f"still short of a drive {short()}")
+
+
 class Served:
     """server/__main__.build_server (expand_endpoints -> pick_set_layout ->
     XLStorage -> ErasureObjects -> S3Server, background services on),

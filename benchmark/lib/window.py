@@ -16,22 +16,30 @@ def records(run: dict, op: str) -> list[dict]:
             if r["op"] == op]
 
 
+def rank(n: int, q: float) -> int:
+    """The nearest rank (from 1) of the q-th percentile of ``n`` values;
+    ``n - rank`` values lie beyond it."""
+    return min(n, max(1, math.ceil(q * n)))
+
+
 def percentile(values: list[float], q: float) -> float | None:
     """Nearest-rank percentile of all values; None when there are none."""
     if not values:
         return None
-    s = sorted(values)
-    return s[min(len(s) - 1, max(0, math.ceil(q * len(s)) - 1))]
+    return sorted(values)[rank(len(values), q) - 1]
 
 
 def latency_ms(run: dict, op: str, q: float) -> float | None:
     """q-th percentile of the latency of every ``op`` of the window, in
     ms; an operation that failed or was refused counts with the time it
-    took (it missed any limit). The sample count goes on a line of its
-    own."""
+    took (it missed any limit). The sample count, the percentiles around
+    the one read and how many samples lie beyond it go on a line of their
+    own: a tail that rests on few samples of a flat stretch shows there."""
     lat = [(r["t1"] - r["t0"]) * 1e3 for r in records(run, op)]
-    say(f"SAMPLES {op} n={len(lat)} p50="
-        f"{percentile(lat, 0.5)} p{int(q * 100)}={percentile(lat, q)} ms")
+    around = " ".join(f"p{int(p * 100)}={percentile(lat, p)}"
+                      for p in sorted({0.5, 0.9, q, 0.99}))
+    say(f"SAMPLES {op} n={len(lat)} {around} ms; beyond="
+        f"{len(lat) - rank(len(lat), q)} of p{int(q * 100)}")
     return percentile(lat, q)
 
 

@@ -95,20 +95,29 @@ class Ctx(types.SimpleNamespace):
     the two ways to run plans: ``ctx.pool.run`` at once, ``ctx.timed`` as
     the measured window."""
 
+    edge_reader = None    # a kind's own counters, read at the window's edges
+
     def timed(self, make_plans, seconds: float) -> list[list[dict]]:
-        """The measured window: ``make_plans(t_start, t_end)`` gives one
-        plan per client thread; this thread only sleeps (and, in a traced
-        run, holds the profiler over a steady sub-window)."""
-        self.before = served.observe()
+        """The measured window. ``make_plans(t_start, t_end)`` gives one
+        plan per client thread, its times counted from the window's start
+        (it is called with 0.0 and ``seconds``): the client processes make
+        and hash their bodies and say that they are ready, and only then is
+        the start fixed, ``lead_s`` from that moment, and sent to them; no
+        request goes out between. A kind that set ``ctx.edge_reader`` finds
+        what it returned at the window's two edges in ``ctx.edges``. This
+        thread only sleeps (and, in a traced run, holds the profiler over a
+        steady sub-window)."""
+        self.pool.prepare(make_plans(0.0, seconds))
+        read = self.edge_reader or (lambda: None)
+        self.before, edge0 = served.observe(), read()
         cpu0 = time.process_time()
-        # the clients make and hash their bodies before t_start
         t_start = time.monotonic() + self.mix.get("lead_s", 0.5)
         t_end = t_start + seconds
-        self.pool.start(make_plans(t_start, t_end))
+        self.pool.go(t_start)
         if self.tracer is not None:
             self.tracer.during(t_start, t_end)
         time.sleep(max(0.0, t_end - time.monotonic()))
-        self.after = served.observe()
+        self.after, self.edges = served.observe(), (edge0, read())
         # the server's own CPU seconds (all threads) over the window: a
         # window that got less work done on the same CPU waited elsewhere
         self.server_cpu_s = time.process_time() - cpu0

@@ -4,10 +4,11 @@ but its look for a chip. For the rehearsal tests only; its numbers are the
 CPU's and are never reported.
 
     JAX_PLATFORMS=cpu python cpu_run.py <checkout> <run.py's arguments>
-                                        [--break get-byte|heal-noop]
+                                        [--break get-byte|heal-noop|fence]
 
 ``--break`` breaks the timed path underneath the harness before the run, to
-show that ``correct`` comes out false."""
+show that ``correct`` comes out false; ``fence`` breaks nothing of the program
+(a host's standstill, stood in for) and has to leave it true."""
 from __future__ import annotations
 
 import os
@@ -53,7 +54,30 @@ def break_heal_noop() -> None:
     ErasureObjects.heal_object = heal_object
 
 
-BREAKS = {"get-byte": break_get_byte, "heal-noop": break_heal_noop}
+def fence_two_drives() -> None:
+    """No break of the program: what a host that stands still does to it.
+    Half-way through the window two drives' health trackers are given four
+    timeouts in a row: they are fenced for their cool-down and PUTs
+    meanwhile are acknowledged on the drives that are left. The run has to
+    come out correct all the same (``served.whole``)."""
+    import threading
+
+    import run
+    orig = run.Ctx.timed
+
+    def timed(self, make_plans, seconds):
+        def trip():
+            for d in self.served.obj.disks[:2]:
+                for _ in range(4):
+                    d._record(False, 3.0, True)
+        threading.Timer(self.mix.get("lead_s", 0.5) + 0.5 * seconds,
+                        trip).start()
+        return orig(self, make_plans, seconds)
+    run.Ctx.timed = timed
+
+
+BREAKS = {"get-byte": break_get_byte, "heal-noop": break_heal_noop,
+          "fence": fence_two_drives}
 
 
 class FakeTracer:

@@ -108,3 +108,15 @@ def test_refuses_to_run_off_a_tpu():
         timeout=300)
     assert p.returncode == 2 and "refusing to run" in p.stdout
     assert not p.stdout.strip().splitlines()[-1].startswith("{")
+
+
+def test_drives_fenced_in_the_window_are_waited_for(copy):
+    """A host that stood still: two of the six drives fenced half-way
+    through the window, so the window's last PUTs are on four drives. The
+    read-back less ``parity`` drives' shards waits until the tracker has the
+    drives online again and the program's own heal has made the sample
+    whole; without that wait it asks for more than 4+2 promises and reads
+    503 SlowDownRead (PERF.md section 6, PR 38)."""
+    last, out = run(copy, MIXED, "--break", "fence")
+    assert last["correct"] is True and last["failed"] == 0, out[-3000:]
+    assert "NOTE whole: waited" in out

@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import served
+
 BUCKET, WARM_BUCKET = "bench", "bench-warm"
 
 
@@ -95,6 +97,7 @@ def verify(ctx) -> None:
     sample = [str(k) for k in
               rng.permutation(new)[: ctx.mix["readback_sample"]]]
     drives = rng.permutation(len(ctx.served.dirs))[: ctx.cfg["parity"]]
+    served.whole(ctx.served.dirs, BUCKET, sample)
     ops = [{"op": "EMPTY", "paths": [
         os.path.join(ctx.served.dirs[d], BUCKET, k)
         for d in drives for k in sample]}]

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import importlib.util
 import os
-import threading
 import time
 import types
 
@@ -35,6 +34,7 @@ import numpy as np
 
 import counter_edges
 import refmodel
+import served
 from served import say
 
 _spec = importlib.util.spec_from_file_location(
@@ -101,29 +101,12 @@ def warm(ctx) -> None:
 
 
 def window(ctx, seconds: float) -> None:
-    """``mixed``'s window, with the program's counters read as its plans
-    are made (the clients then prepare their bodies for ``lead_s``; no
-    request is sent between) and at ``t_end``."""
-    edges = {}
-    read = lambda: counter_edges.snapshot(FAMILIES)  # noqa: E731
-    timed = ctx.timed
-
-    def timed_with_edges(make_plans, secs):
-        def plans(t_start, t_end):
-            edges["c0"] = read()
-            timer = threading.Timer(
-                max(0.0, t_end - time.monotonic()),
-                lambda: edges.setdefault("c1", read()))
-            timer.daemon = True
-            timer.start()
-            return make_plans(t_start, t_end)
-        return timed(plans, secs)
-    ctx.timed = timed_with_edges
-    try:
-        mixed.window(ctx, seconds)
-    finally:
-        del ctx.timed
-    c0, c1 = edges["c0"], edges.get("c1") or read()
+    """``mixed``'s window, with the program's counters read at its two
+    edges (``ctx.edge_reader``: once the clients are ready, no request
+    sent yet, and at ``t_end``)."""
+    ctx.edge_reader = lambda: counter_edges.snapshot(FAMILIES)
+    mixed.window(ctx, seconds)
+    c0, c1 = ctx.edges
     ctx.window["counters"] = (c0, c1)
     delta = {k.removeprefix("minio_tpu_"): round(v - c0.get(k, 0.0), 3)
              for k, v in sorted(c1.items()) if v != c0.get(k, 0.0)}
@@ -139,7 +122,10 @@ def window(ctx, seconds: float) -> None:
 
 
 def _readback(ctx, bucket: str, keys: list[str], drives) -> None:
-    """``keys`` GET after their shards were removed from ``drives``."""
+    """``keys`` GET after their shards were removed from ``drives``, once
+    they are whole on every drive that is mounted."""
+    served.whole([d for d in ctx.served.dirs if os.path.isdir(d)], bucket,
+                 keys)
     ops = [{"op": "EMPTY", "paths": [
         os.path.join(ctx.served.dirs[d], bucket, k)
         for d in drives for k in keys]}]

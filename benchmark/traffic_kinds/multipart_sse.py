@@ -27,7 +27,6 @@ import os
 import subprocess
 import sys
 import threading
-import time
 
 import numpy as np
 
@@ -147,17 +146,11 @@ def warm(ctx) -> None:
 
 def window(ctx, seconds: float) -> None:
     mix = ctx.mix
-    edges = {}
+    # the program's SSE counters at the window's two edges, for the
+    # per-layer readers (run.py's own snapshots do not hold them)
+    ctx.edge_reader = sse_counters
 
     def plans(t_start, t_end):
-        # the program's SSE counters at the window's two edges, for the
-        # per-layer readers (run.py's own snapshots do not hold them)
-        edges["c0"] = sse_counters()
-        timer = threading.Timer(
-            max(0.0, t_end - time.monotonic()),
-            lambda: edges.setdefault("c1", sse_counters()))
-        timer.daemon = True
-        timer.start()
         ups = [{"type": "upload_loop", "bucket": BUCKET,
                 "prefix": f"ring-{t:02d}", "ring": mix["ring"],
                 "bodies": _specs(ctx, 2, t), "part_bytes": mix["part_bytes"],
@@ -169,7 +162,7 @@ def window(ctx, seconds: float) -> None:
                 for t in range(mix["get_threads"])]
         return ups + gets
     ctx.timed(plans, seconds)
-    c0, c1 = edges["c0"], edges.get("c1") or sse_counters()
+    c0, c1 = ctx.edges
     ctx.window["sse_counters"] = (c0, c1)
     say("COUNTERS moved in the window: " + str(
         {k.removeprefix("minio_tpu_"): round(v - c0.get(k, 0.0), 3)
@@ -337,6 +330,7 @@ def verify(ctx) -> None:
     _big(ctx)
     sample = pick(ring or ctx.pool_keys, mix["degraded_sample"])
     drives = rng.permutation(len(ctx.served.dirs))[: ctx.cfg["parity"]]
+    served.whole(ctx.served.dirs, BUCKET, sample)
     ops = [{"op": "EMPTY", "paths": [
         os.path.join(ctx.served.dirs[d], BUCKET, k)
         for d in drives for k in sample]}]
