@@ -346,6 +346,40 @@ class _OrderedWriter:
         return out
 
 
+class _DirectWriter:
+    """``_OrderedWriter``'s twin for a sink in memory (``BufferSink``: an
+    inline version's shard, objectlayer/erasure_objects.py): the write is
+    a copy of a few KiB, made where it is asked for. Nothing waits on a
+    drive, so nothing goes to the io pool: a hop there is a turn at the
+    interpreter lock a drive, which is what an inline PUT saves."""
+
+    def __init__(self, writer):
+        self.writer = writer
+
+    def write_async(self, data: bytes) -> Future:
+        return _run_now(self.writer.write, data)
+
+    def write_framed_async(self, framed) -> Future:
+        return _run_now(self.writer.write_framed, framed)
+
+
+def _run_now(op, *args) -> Future:
+    """A pool's ``submit``, run at once on the caller's thread."""
+    out: Future = Future()
+    try:
+        out.set_result(op(*args))
+    except Exception as e:  # noqa: BLE001 — a vote, as on the pool
+        out.set_exception(e)
+    return out
+
+
+def _in_memory(writers: list) -> bool:
+    """True when every live writer's sink is a ``BufferSink``."""
+    live = [w for w in writers if w is not None]
+    return bool(live) and all(
+        isinstance(getattr(w, "sink", None), BufferSink) for w in live)
+
+
 def erasure_encode(erasure: Erasure, stream, writers: list,
                    write_quorum: int, etag=None) -> int:
     """Read the stream block by block, erasure-encode on device, fan shards
@@ -381,7 +415,12 @@ def erasure_encode(erasure: Erasure, stream, writers: list,
     only when _framed_writers matches (the object layer's eligibility
     gate)."""
     total = 0
-    owriters = [None if w is None else _OrderedWriter(w) for w in writers]
+    #: sinks in memory (an inline version's shards): the block is encoded
+    #: and its spans are copied out on the caller's thread, pool-free
+    in_memory = _in_memory(writers)
+    owriters = [None if w is None else
+                _DirectWriter(w) if in_memory else _OrderedWriter(w)
+                for w in writers]
     # per-block entries: [kind, fut, shard_len, buf, digs]
     enc_window: deque = deque()
     write_window: deque = deque()  # per-block (kind, payload)
@@ -502,7 +541,9 @@ def erasure_encode(erasure: Erasure, stream, writers: list,
                 return ["fd", encode_pool().submit(fd_block, buf, buf_len,  # graftlint: disable=GL005
                                                    shard_len, off),
                         shard_len, buf_arr, None]
-            fut = encode_pool().submit(  # graftlint: disable=GL005 — pure kernel compute
+            # sinks in memory: a few KiB, encoded where they are asked for
+            run = _run_now if in_memory else encode_pool().submit
+            fut = run(  # graftlint: disable=GL005 — pure kernel compute
                 nat_block, buf, buf_len, shard_len,
                 pool.get((k + m) * native.framed_len(shard_len, chunk)))
             return ["nat", fut, shard_len, buf_arr, None]
@@ -1210,6 +1251,87 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
     while window:
         emit(window.popleft())
     stats.hedged = preader.hedged
+    return stats
+
+
+def erasure_decode_inline(erasure: Erasure, writer, shards: list,
+                          offset: int, length: int, total_length: int,
+                          algo, chunk: int) -> DecodeStats:
+    """``erasure_decode`` for a version whose shards came with the
+    metadata pass (xl.meta's ``Data``, a shard a drive): ``shards[i]`` is
+    shard i's bitrot-framed bytes, what ``part.1`` would hold, or None.
+    Every chunk digest of a shard used is verified; a data shard that is
+    missing or corrupt is rebuilt from parity (on the dispatch queue, as
+    the ``plain`` route of ``erasure_decode``: its CPU route compiles and
+    loads nothing); a range is cut from the decoded block. Nothing is
+    opened and nothing waits on a drive, so a whole version is served on
+    the caller's thread. Returns the per-shard error votes for
+    heal-on-read (``FileCorrupt`` for a digest mismatch)."""
+    if offset < 0 or length < 0 or offset + length > total_length:
+        raise ValueError("invalid decode range")
+    from .bitrot import HIGHWAY_KEY, native_algo_id, new_bitrot_reader
+    stats = DecodeStats()
+    k, n = erasure.data_blocks, len(shards)
+    bs = erasure.block_size
+    logical = erasure.shard_file_size(total_length)
+    readers = [None if s is None else new_bitrot_reader(
+        BufferSource(s), algo, logical, chunk) for s in shards]
+    errs = stats.errs = [errors.DiskNotFound() if r is None else None
+                         for r in readers]
+    if length == 0:
+        return stats
+    algo_id = native_algo_id(algo)
+    from .. import native
+    fast = algo_id is not None and erasure.shard_size() % chunk == 0 \
+        and native.available()
+    for b in range(offset // bs, (offset + length - 1) // bs + 1):
+        block_data_len = min(bs, total_length - b * bs)
+        boff = max(offset - b * bs, 0)
+        blen = min(offset + length - b * bs, block_data_len) - boff
+        shard_len = ceil_div(block_data_len, k)
+        shard_offset = b * erasure.shard_size()
+        _mx.inc("minio_tpu_pipeline_get_blocks_total", route="inline")
+        block = None
+        if fast and all(r is not None for r in readers[:k]):
+            # the k data shards are there: verify + assemble in one call
+            try:
+                block, bad = native.get_block(
+                    [r.read_framed(shard_offset, shard_len)
+                     for r in readers[:k]],
+                    k, shard_len, chunk, HIGHWAY_KEY, algo_id)
+            except errors.StorageError:
+                bad = -2  # a shard cut short: the loop below names it
+            if bad >= 0:
+                errs[bad] = errors.FileCorrupt("bitrot hash mismatch")
+                readers[bad] = None
+            if bad != -1:
+                block = None
+        if block is None:
+            got: list = [None] * n
+            have = 0
+            for i in range(n):  # data before parity
+                if have == k:
+                    break
+                if readers[i] is None:
+                    continue
+                try:
+                    got[i] = np.frombuffer(
+                        readers[i].read_at(shard_offset, shard_len),
+                        dtype=np.uint8)
+                    have += 1
+                except Exception as e:  # noqa: BLE001 — a vote
+                    errs[i] = e if isinstance(e, errors.StorageError) \
+                        else errors.FaultyDisk(str(e))
+                    readers[i] = None
+            if have < k:
+                err = errors.reduce_read_quorum_errs(
+                    errs, errors.BASE_IGNORED_ERRS, k)
+                raise err if err is not None else errors.ErasureReadQuorum()
+            blocks = got[:k] if all(g is not None for g in got[:k]) \
+                else erasure.decode_data_blocks_async(got).result()
+            block = np.concatenate(blocks[:k])
+        writer.write(memoryview(block)[boff: boff + blen])
+        stats.bytes_written += blen
     return stats
 
 

@@ -12,9 +12,9 @@ gf256_simd.cpp + highwayhash.cpp) provides:
   ``mt_get_block_pread_degraded`` (pread+verify+rebuild+assemble) that
   carry the end-to-end object path on the CPU route,
 - a PUT's per-drive file-system sequences ``mt_stage_file`` /
-  ``mt_close_fds`` / ``mt_commit_version`` (storage/xlstorage.py: a shard
-  file staged, a version committed, in one call each) and a read's
-  ``mt_open_shard`` (open + fstat).
+  ``mt_close_fds`` / ``mt_commit_version`` / ``mt_commit_inline``
+  (storage/xlstorage.py: a shard file staged, a version committed, in one
+  call each) and a read's ``mt_open_shard`` (open + fstat).
 
 All entry points release the GIL (plain ctypes CDLL calls), so concurrent
 requests scale across cores where the host has them.
@@ -232,6 +232,11 @@ def _load_native_locked() -> ctypes.CDLL:
             ctypes.c_long, ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
             ctypes.POINTER(ctypes.c_int)]
         lib.mt_commit_version.restype = ctypes.c_int
+        lib.mt_commit_inline.argtypes = [
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
+            ctypes.c_char_p, ctypes.c_long, ctypes.c_char_p, ctypes.c_int,
+            ctypes.c_int, ctypes.POINTER(ctypes.c_int)]
+        lib.mt_commit_inline.restype = ctypes.c_int
         _lib = lib
     return _lib
 
@@ -464,7 +469,8 @@ def verify_framed(framed, plen: int, chunk: int, key: bytes,
 
 # --- a PUT's file-system sequences (storage/xlstorage.py) -------------------
 
-#: mt_commit_version's steps, as its result names the one that failed
+#: mt_commit_version's and mt_commit_inline's steps, as the result names the
+#: one that failed
 COMMIT_OBJECT_DIR = 1
 COMMIT_STAGED = 2
 COMMIT_DATA_RENAME = 3
@@ -512,4 +518,19 @@ def commit_version(vol: str, obj: str, ddir: str, src: str, tmp_parent: str,
         os.fsencode(vol), os.fsencode(obj), os.fsencode(ddir),
         os.fsencode(src), os.fsencode(tmp_parent), meta, len(meta), names,
         len(purge), int(do_fsync), out)
+    return list(out)
+
+
+def commit_inline(vol: str, obj: str, tmp: str, meta: bytes,
+                  purge: list[str], do_fsync: bool) -> list[int]:
+    """``commit_version`` for a version whose shard rides in ``meta``
+    (xl.meta's ``Data``): the object directory, ``meta`` written to
+    ``tmp`` and renamed over ``xl.meta``, the replaced data directories
+    removed; no data directory, nothing staged. The result reads as
+    ``commit_version``'s."""
+    out = (ctypes.c_int * 7)()
+    names = b"".join(os.fsencode(n) + b"\0" for n in purge)
+    load_native().mt_commit_inline(
+        os.fsencode(vol), os.fsencode(obj), os.fsencode(tmp), meta,
+        len(meta), names, len(purge), int(do_fsync), out)
     return list(out)

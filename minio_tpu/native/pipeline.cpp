@@ -416,11 +416,8 @@ int fsync_at(const char* path, int flags, int* ok) {
   return e;
 }
 
-}  // namespace
-
-extern "C" {
-
-// steps of mt_commit_version, as `out[0]` names the one that failed
+// steps of mt_commit_version and mt_commit_inline, as `out[0]` names the one
+// that failed
 enum {
   kStepDone = 0,
   kStepObjectDir = 1,   // mkdir of the object directory below the volume
@@ -430,6 +427,64 @@ enum {
   kStepMetaRename = 5,  // tmp name -> <object>/xl.meta
   kStepFsync = 6,       // an fsync of policy `always` failed; out[2] = kind
 };
+
+int commit_failed(int* out, int step, int e) {
+  out[0] = step;
+  out[1] = e;
+  return step;
+}
+
+// durable_replace of a new xl.meta: `meta` written to `tmp`  [always: fsync
+// it], renamed over <odir>/xl.meta  [always: fsync <odir>]. 0, or the step
+// that failed as commit_failed left it in `out`.
+int commit_meta(const std::string& odir, const std::string& tmp,
+                const uint8_t* meta, long meta_len, int do_fsync, int* out) {
+  int e;
+  int fd = open(tmp.c_str(), O_WRONLY | O_CREAT | O_TRUNC | O_CLOEXEC, 0666);
+  if (fd < 0) return commit_failed(out, kStepMetaWrite, errno);
+  for (long done = 0; done < meta_len;) {
+    ssize_t w = write(fd, meta + done, (size_t)(meta_len - done));
+    if (w < 0 && errno == EINTR) continue;
+    if (w <= 0) {
+      e = w < 0 ? errno : EIO;
+      close(fd);
+      return commit_failed(out, kStepMetaWrite, e);
+    }
+    done += w;
+  }
+  if (do_fsync) {
+    if (fsync(fd) != 0) {
+      e = errno;
+      close(fd);
+      out[2] = 0;
+      return commit_failed(out, kStepFsync, e);
+    }
+    out[3]++;
+  }
+  close(fd);
+  const std::string mdst = odir + "/xl.meta";
+  if (rename(tmp.c_str(), mdst.c_str()) != 0)
+    return commit_failed(out, kStepMetaRename, errno);
+  if (do_fsync && (e = fsync_at(odir.c_str(), O_DIRECTORY, &out[4]))) {
+    out[2] = 1;
+    return commit_failed(out, kStepFsync, e);
+  }
+  return kStepDone;
+}
+
+// the replaced versions' data directories (n_purge names, NUL-separated)
+// removed from <odir>; out[5] counts those that could not be
+void purge_ddirs(const std::string& odir, const char* purge, int n_purge,
+                 int* out) {
+  for (const char* name = purge; n_purge > 0; n_purge--) {
+    if (rm_tree((odir + "/" + name).c_str()) < 0) out[5]++;
+    name += std::strlen(name) + 1;
+  }
+}
+
+}  // namespace
+
+extern "C" {
 
 // Stage one shard file: mkdir the directories of `rel` below `base` (the
 // volume, which has to be there) and open the file for writing, as
@@ -489,11 +544,7 @@ int mt_commit_version(const char* vol, const char* obj, const char* ddir,
                       const uint8_t* meta, long meta_len, const char* purge,
                       int n_purge, int do_fsync, int* out) {
   for (int i = 0; i < 7; i++) out[i] = 0;
-  auto fail = [&](int step, int e) {
-    out[0] = step;
-    out[1] = e;
-    return step;
-  };
+  auto fail = [&](int step, int e) { return commit_failed(out, step, e); };
   auto synced = [&](const char* path, bool dir) {
     if (!do_fsync) return 0;
     const int e = fsync_at(path, dir ? O_DIRECTORY : 0, &out[dir ? 4 : 3]);
@@ -514,39 +565,31 @@ int mt_commit_version(const char* vol, const char* obj, const char* ddir,
   if (rename(src, dst.c_str()) != 0) return fail(kStepDataRename, errno);
   if ((e = synced(odir.c_str(), true))) return fail(kStepFsync, e);
 
-  const std::string tmp = std::string(tmp_parent) + "/xl.meta";
-  int fd = open(tmp.c_str(), O_WRONLY | O_CREAT | O_TRUNC | O_CLOEXEC, 0666);
-  if (fd < 0) return fail(kStepMetaWrite, errno);
-  for (long done = 0; done < meta_len;) {
-    ssize_t w = write(fd, meta + done, (size_t)(meta_len - done));
-    if (w < 0 && errno == EINTR) continue;
-    if (w <= 0) {
-      e = w < 0 ? errno : EIO;
-      close(fd);
-      return fail(kStepMetaWrite, e);
-    }
-    done += w;
-  }
-  if (do_fsync) {
-    if (fsync(fd) != 0) {
-      e = errno;
-      close(fd);
-      out[2] = 0;
-      return fail(kStepFsync, e);
-    }
-    out[3]++;
-  }
-  close(fd);
-  const std::string mdst = odir + "/xl.meta";
-  if (rename(tmp.c_str(), mdst.c_str()) != 0)
-    return fail(kStepMetaRename, errno);
-  if ((e = synced(odir.c_str(), true))) return fail(kStepFsync, e);
-
-  for (const char* name = purge; n_purge > 0; n_purge--) {
-    if (rm_tree((odir + "/" + name).c_str()) < 0) out[5]++;
-    name += std::strlen(name) + 1;
-  }
+  if (commit_meta(odir, std::string(tmp_parent) + "/xl.meta", meta, meta_len,
+                  do_fsync, out))
+    return out[0];
+  purge_ddirs(odir, purge, n_purge, out);
   if (rm_tree(tmp_parent) < 0) out[6] = 1;
+  return kStepDone;
+}
+
+// Commit one version whose shard rides in `meta` (xl.meta's Data) on one
+// drive, the file-system half of rename_data for an inline version: no data
+// directory, nothing staged:
+//   1. mkdir <vol>/<obj> (every directory of `obj` below the volume)
+//   2. write `meta` to `tmp` (a name under .minio.sys/tmp)  [always: fsync it]
+//      rename it -> <vol>/<obj>/xl.meta  [always: fsync <vol>/<obj>]
+//   3. remove the replaced data directories `purge` of <vol>/<obj>
+// `out` as mt_commit_version's (out[6] stays 0).
+int mt_commit_inline(const char* vol, const char* obj, const char* tmp,
+                     const uint8_t* meta, long meta_len, const char* purge,
+                     int n_purge, int do_fsync, int* out) {
+  for (int i = 0; i < 7; i++) out[i] = 0;
+  const int e = mkdirs_below(vol, obj, false);
+  if (e) return commit_failed(out, kStepObjectDir, e);
+  const std::string odir = std::string(vol) + "/" + obj;
+  if (commit_meta(odir, tmp, meta, meta_len, do_fsync, out)) return out[0];
+  purge_ddirs(odir, purge, n_purge, out);
   return kStepDone;
 }
 

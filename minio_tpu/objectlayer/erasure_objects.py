@@ -25,10 +25,12 @@ from .. import qos as _qos
 from ..erasure.bitrot import (BITROT_CHUNK_KEY, BitrotAlgorithm,
                               pick_bitrot_chunk)
 from ..erasure.codec import ceil_div
-from ..erasure.streaming import (close_readers, close_writers,
-                                 erasure_decode, erasure_encode,
+from ..erasure.streaming import (BufferSink, BufferSource, close_readers,
+                                 close_writers, erasure_decode,
+                                 erasure_decode_inline, erasure_encode,
                                  erasure_heal)
 from ..storage.datatypes import ErasureInfo, FileInfo, ObjectPartInfo
+from ..storage.xlmeta import SMALL_FILE_THRESHOLD
 from ..storage.xlstorage import META_BUCKET, META_TMP, new_tmp_id
 from ..utils import errors
 from ..utils.hashreader import HashReader
@@ -55,6 +57,20 @@ DEFAULT_BLOCK_SIZE = 4 << 20
 
 BITROT_KEY = "x-minio-internal-bitrot"
 ACTUAL_SIZE_KEY = "x-minio-internal-actual-size"
+
+
+def _count_inline(op: str, nbytes: int, versions: int = 1) -> None:
+    """A version kept as erasure shards inside its drives' xl.meta was
+    written (``put``), served (``get``) or rebuilt (``heal``)."""
+    _mx.inc("minio_tpu_objectlayer_inline_versions_total", versions, op=op)
+    _mx.inc("minio_tpu_objectlayer_inline_bytes_total", nbytes, op=op)
+
+
+# there from the start, at 0: a share of a window in which nothing was
+# inline reads 0, where a program without the path has nothing to read
+for _op in ("put", "get", "heal"):
+    _count_inline(_op, 0, 0)
+_mx.inc("minio_tpu_pipeline_get_blocks_total", 0, route="inline")
 
 
 def to_object_err(err: BaseException, bucket: str = "", object: str = ""):
@@ -418,13 +434,22 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                                     shard_size=er.shard_size())
         tmp_id = new_tmp_id()
         shuffled = shuffle_disks_by_distribution(disks, distribution)
+        #: a small body of known size (smallFileThreshold,
+        #: cmd/xl-storage.go:67) is encoded and framed into memory by the
+        #: same encode, and each drive's commit carries THAT DRIVE'S shard
+        #: into its xl.meta (``fi.data``): no data directory, no part.1,
+        #: nothing staged, so nothing for ``_cleanup_tmp`` to visit
+        inline = 0 < size <= SMALL_FILE_THRESHOLD \
+            and self.bitrot_algo.streaming
+        cleanup_tmp = (lambda: None) if inline \
+            else (lambda: self._cleanup_tmp(tmp_id))
         writers = []
         for j, d in enumerate(shuffled):
             if d is None:
                 writers.append(None)
                 continue
             try:
-                sink = d.create_file_writer(
+                sink = BufferSink() if inline else d.create_file_writer(
                     META_TMP, f"{tmp_id}/{fi.data_dir}/part.1")
                 writers.append(new_bitrot_writer(
                     sink, self.bitrot_algo, bitrot_chunk))
@@ -438,12 +463,12 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             for w in writers:
                 if w is not None:
                     w.abort()
-            self._cleanup_tmp(tmp_id)
+            cleanup_tmp()
             raise to_object_err(e, bucket, object) from e
         close_writers(writers)  # a writer that fails to close is None
 
         if size >= 0 and total != size:
-            self._cleanup_tmp(tmp_id)
+            cleanup_tmp()
             raise dt.IncompleteBody(bucket, object)
 
         etag = user_defined.pop("etag", "")
@@ -454,7 +479,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                 # armed but never fed — an eligibility-gate bug, and the
                 # MD5 chain was disabled: fail loudly, never serve the
                 # constant empty-stream ETag for a non-empty object
-                self._cleanup_tmp(tmp_id)
+                cleanup_tmp()
                 raise dt.ObjectAPIError(
                     bucket, object, "fused ETag collector starved")
             etag = collector.etag() if collector is not None \
@@ -483,7 +508,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             lock_cm.__enter__()
         except dt.ObjectAPIError:
             # lock contention after the data upload: reclaim tmp shards
-            self._cleanup_tmp(tmp_id)
+            cleanup_tmp()
             raise
         try:
             futs = {}
@@ -492,7 +517,9 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                     errs[j] = errors.DiskNotFound()
                     continue
                 fij = replace(fi, erasure=replace(fi.erasure, index=j + 1),
-                              metadata=dict(fi.metadata))
+                              metadata=dict(fi.metadata),
+                              data=writers[j].sink.getvalue() if inline
+                              else None)
                 futs[j] = meta_pool().submit(
                     _spans.wrap_ctx(d.rename_data), META_TMP, tmp_id, fij,
                     bucket, object)
@@ -515,13 +542,16 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                         d.delete_version(bucket, object, fi)
                     except errors.StorageError:
                         pass
-            self._cleanup_tmp(tmp_id)
+            cleanup_tmp()
             raise to_object_err(err, bucket, object)
         if any(e is not None for e in errs):
-            self._cleanup_tmp(tmp_id)  # reclaim tmp on the failed minority
+            cleanup_tmp()  # reclaim tmp on the failed minority
             self._notify_partial(
                 bucket, object, fi.version_id, source="write",
                 missed=[d for d, e in zip(shuffled, errs) if e is not None])
+        if inline:
+            _count_inline("put", total)
+            _spans.annotate(inline=True)
         from ..scanner.tracker import global_tracker
         global_tracker().mark(bucket, object)
         self.metacache.on_write(bucket)
@@ -691,10 +721,6 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             # the decode path can scatter blocks zero-copy via reserve()
             hint(length)
 
-        if fi.data is not None and len(fi.data) == fi.size:
-            writer.write(fi.data[offset: offset + length])
-            return oi
-
         disks = self.disks
         er = Erasure(fi.erasure.data_blocks, fi.erasure.parity_blocks,
                      fi.erasure.block_size)
@@ -705,6 +731,9 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
 
         # disks in shard order via each disk's stored erasure index
         per_shard_disk: list = [None] * len(disks)
+        #: the shard a drive's xl.meta carried (an inline version: the
+        #: metadata pass brought it, XLMeta.to_fileinfo), in shard order
+        per_shard_data: list = [None] * len(disks)
         #: a drive that answered the metadata pass holds nothing this
         #: version can be read from (outdated, deleted there): heal debt
         stale = False
@@ -719,10 +748,31 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                 stale = True  # outdated disk
                 continue
             per_shard_disk[idx - 1] = d
+            per_shard_data[idx - 1] = dfi.data
 
         shard_errs: list = []
+        inline = any(b is not None for b in per_shard_data)
+        if inline:
+            # decoded in memory from what the pass brought: no shard file
+            # is opened, a GET makes the calls of a STAT. A drive that
+            # holds the version without its shard owes a heal like a
+            # drive that lost a part file
+            stale = stale or any(
+                d is not None and b is None
+                for d, b in zip(per_shard_disk, per_shard_data))
+            try:
+                stats = erasure_decode_inline(
+                    er, writer, per_shard_data, offset, length, fi.size,
+                    algo, bitrot_chunk)
+            except Exception as e:  # noqa: BLE001
+                raise to_object_err(e, bucket, object) from e
+            shard_errs.extend(
+                e for e, b in zip(stats.errs, per_shard_data)
+                if b is not None)
+            _count_inline("get", length)
+            _spans.annotate(inline=True)
         part_start = 0  # start byte of current part within the object
-        for part in fi.parts:
+        for part in () if inline else fi.parts:
             part_end = part_start + part.size
             if part_end <= offset:
                 part_start = part_end
@@ -1111,7 +1161,12 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             for d, dfi in zip(self.disks, fis):
                 if d is None or dfi is None:
                     continue
-                fid = replace(fi, erasure=dfi.erasure, metadata=dict(meta))
+                # data=None: an inline version's shard stays where it
+                # is (XLMeta.add_version keeps the Data entry of a data
+                # directory that does not change); the quorum pick's
+                # would be ANOTHER drive's shard
+                fid = replace(fi, erasure=dfi.erasure, metadata=dict(meta),
+                              data=None)
                 try:
                     d.update_metadata(bucket, object, fid)
                 except errors.StorageError:
@@ -1290,7 +1345,11 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         disks = self.disks
         n = len(disks)
         vid = "" if version_id in ("", "null") else version_id
-        fis, errs = read_all_fileinfo(disks, bucket, object, vid)
+        # read_data: an inline version's shards come with the pass; they
+        # are what check_parts / verify_file look at and what the rebuild
+        # reads
+        fis, errs = read_all_fileinfo(disks, bucket, object, vid,
+                                      read_data=True)
         read_quorum, _ = object_quorum_from_meta(fis, errs,
                                                  self.default_parity)
 
@@ -1380,18 +1439,6 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             res.after_state = list(state)
             return res
 
-        if fi.data is not None:
-            # inlined object: just rewrite xl.meta on broken disks
-            for i in to_heal:
-                fih = replace(fi, metadata=dict(fi.metadata))
-                try:
-                    disks[i].write_metadata(bucket, object, fih)
-                    state[i] = DRIVE_STATE_OK
-                except errors.StorageError:
-                    pass
-            res.after_state = state
-            return res
-
         er = Erasure(fi.erasure.data_blocks, fi.erasure.parity_blocks,
                      fi.erasure.block_size)
         algo = BitrotAlgorithm(fi.metadata.get(
@@ -1401,12 +1448,20 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
 
         # shard-ordered source disks (state OK only) and their FileInfos
         shard_disk: list = [None] * n
+        #: an inline version: the sources' shards, as their xl.meta
+        #: handed them back with the FileInfos above
+        shard_data: list = [None] * n
         for i, (d, f) in enumerate(zip(disks, fis)):
             if state[i] != DRIVE_STATE_OK or f is None:
                 continue
             idx = f.erasure.index
             if 1 <= idx <= n and shard_disk[idx - 1] is None:
                 shard_disk[idx - 1] = d
+                shard_data[idx - 1] = f.data
+        #: k surviving shards -> the block -> each broken drive's OWN
+        #: shard and digests, committed with the version into that
+        #: drive's xl.meta: byte-equal to what the PUT wrote there
+        inline = any(b is not None for b in shard_data)
         # target shard index per healed disk: reuse the quorum distribution
         dist = fi.erasure.distribution or hash_order(f"{bucket}/{object}", n)
         tmp_id = new_tmp_id()
@@ -1415,17 +1470,22 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         # data is incomplete or not durably written — committing it via
         # rename_data would heal in bad shards
         failed_targets: set = set()
+        #: an inline version (one part): the sink each target's rebuilt
+        #: shard is framed into
+        sinks: dict = {}
         for part in fi.parts:
             logical = er.shard_file_size(part.size)
             readers = []
             for j in range(n):
                 d = shard_disk[j]
-                if d is None:
+                if d is None or (inline and shard_data[j] is None):
                     readers.append(None)
                     continue
                 try:
-                    src = d.read_file_at(
-                        bucket, f"{object}/{fi.data_dir}/part.{part.number}")
+                    src = BufferSource(shard_data[j]) if inline \
+                        else d.read_file_at(
+                            bucket,
+                            f"{object}/{fi.data_dir}/part.{part.number}")
                     readers.append(new_bitrot_reader(
                         src, algo, logical, bitrot_chunk))
                 except Exception:  # noqa: BLE001
@@ -1434,9 +1494,10 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             for i in to_heal:
                 shard_idx = dist[i]
                 try:
-                    sink = disks[i].create_file_writer(
-                        META_TMP,
-                        f"{tmp_id}/{fi.data_dir}/part.{part.number}")
+                    sink = sinks[i] = BufferSink() if inline \
+                        else disks[i].create_file_writer(
+                            META_TMP,
+                            f"{tmp_id}/{fi.data_dir}/part.{part.number}")
                     writers[shard_idx - 1] = new_bitrot_writer(
                         sink, algo, bitrot_chunk)
                 except Exception:  # noqa: BLE001
@@ -1482,12 +1543,15 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                 continue  # incomplete/non-durable tmp shards stay tmp
             shard_idx = dist[i]
             fih = replace(fi, erasure=replace(fi.erasure, index=shard_idx),
-                          metadata=dict(fi.metadata))
+                          metadata=dict(fi.metadata),
+                          data=sinks[i].getvalue() if inline else None)
             try:
                 disks[i].rename_data(META_TMP, tmp_id, fih, bucket, object)
                 state[i] = DRIVE_STATE_OK
             except Exception:  # noqa: BLE001
                 pass
+        if inline:
+            _count_inline("heal", fi.size)
         if scan_mode != "deep" and any(
                 isinstance(e, errors.FileCorrupt) for e in src_errs):
             # a SOURCE shard turned out bitrot-corrupt mid-heal: this

@@ -55,6 +55,8 @@ class SpanContext:
     span_id: str
     parent_span_id: str = ""
     sampled: bool = True
+    #: attributes learned inside the span (``annotate``), recorded with it
+    extra: dict | None = None
 
 
 _current: contextvars.ContextVar[SpanContext | None] = \
@@ -64,6 +66,15 @@ _current: contextvars.ContextVar[SpanContext | None] = \
 def current() -> SpanContext | None:
     """The calling context's span, or None outside any traced request."""
     return _current.get()
+
+
+def annotate(**attrs) -> None:
+    """Add attributes to the span being recorded around the caller (what
+    a ``span`` learns only inside: the route a PUT took). Free when
+    nothing is traced."""
+    ctx = _current.get()
+    if ctx is not None and ctx.sampled:
+        ctx.extra = {**(ctx.extra or {}), **attrs}
 
 
 def new_trace_id() -> str:
@@ -273,7 +284,8 @@ def span(name: str, **attrs):
                     "time": t_wall,
                     "duration_s": round(time.perf_counter() - t0, 6),
                     "error": err,
-                    "attrs": {k: v for k, v in attrs.items()
+                    "attrs": {k: v for k, v in
+                              {**attrs, **(child.extra or {})}.items()
                               if v not in ("", None)}})
         except Exception:  # noqa: BLE001 — obs never fails the work
             pass

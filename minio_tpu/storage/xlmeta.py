@@ -6,7 +6,11 @@ role as the reference's ``XL2 `` + version ``1   `` at
 cmd/xl-storage-format-v2.go:33-38) followed by one msgpack map:
 
     {"Versions": [ {"Type": 1|2, "ModTime": f64, "V": {...}} ... ],
-     "Data": {dataDir?: inlined bytes}}          # small-object inlining (A.4)
+     "Data": {dataDir?: THIS DRIVE'S bitrot-framed erasure shard}}   # (A.4)
+
+A version of an erasure set at or under ``SMALL_FILE_THRESHOLD`` has no data
+directory: ``Data[dataDir]`` holds what ``<dataDir>/part.1`` would, byte for
+byte (``[32-byte digest][chunk]``...), a shard a drive, never a whole copy.
 
 New blobs write format version 2 (``XLT2 2  ``) and end with a
 ``XLC1`` + CRC32 torn-write detector (PR 6; see XL_TRAILER_MAGIC
@@ -51,7 +55,8 @@ TYPE_OBJECT = 1
 TYPE_DELETE_MARKER = 2
 
 #: Objects <= this inline their single part into xl.meta (smallFileThreshold,
-#: cmd/xl-storage.go:67).
+#: cmd/xl-storage.go:67): an erasure set keeps each drive's shard of it there
+#: (objectlayer/erasure_objects.py), FS mode the body (fs.py).
 SMALL_FILE_THRESHOLD = 128 << 10
 
 #: Null-version sentinel used in version maps.
@@ -141,16 +146,18 @@ class XLMeta:
         """Insert/replace a version (AddVersion,
         cmd/xl-storage-format-v2.go). Replacement key: version_id. Returns
         the dataDir uuids of any replaced versions so the caller can delete
-        their part files (otherwise unversioned overwrites leak data dirs)."""
+        their part files (otherwise unversioned overwrites leak data dirs);
+        a replaced version that was inline loses its ``Data`` entry here
+        and has no directory to return."""
         vid = fi.version_id
         old_ddirs: list[str] = []
         kept = []
         for d in self.versions:
             if d.get("V", {}).get("id", "") == vid:
                 ddir = d.get("V", {}).get("ddir", "")
-                if ddir and ddir != fi.data_dir:
+                if ddir and ddir != fi.data_dir and \
+                        self.data.pop(ddir, None) is None:
                     old_ddirs.append(ddir)
-                    self.data.pop(ddir, None)
             else:
                 kept.append(d)
         self.versions = kept
@@ -162,7 +169,8 @@ class XLMeta:
 
     def delete_version(self, fi: FileInfo) -> str:
         """Remove a version; returns its dataDir uuid (for part cleanup) or
-        "". If fi.deleted, a delete marker is *added* instead."""
+        "" (none, or an inline version, whose ``Data`` entry goes here).
+        If fi.deleted, a delete marker is *added* instead."""
         if fi.deleted:
             self.add_version(fi)
             return ""
@@ -179,8 +187,8 @@ class XLMeta:
         if not found:
             raise errors.FileVersionNotFound(vid)
         self.versions = kept
-        if ddir and ddir in self.data:
-            del self.data[ddir]
+        if ddir and self.data.pop(ddir, None) is not None:
+            return ""
         return ddir
 
     def find_version(self, version_id: str) -> dict:
@@ -195,14 +203,17 @@ class XLMeta:
         raise errors.FileVersionNotFound(version_id)
 
     def to_fileinfo(self, volume: str, name: str, version_id: str = "",
-                    ) -> FileInfo:
+                    read_data: bool = True) -> FileInfo:
+        """The version as a FileInfo; with ``read_data`` an inline
+        version's shard rides along in ``fi.data`` (a reference, no
+        copy: what a GET decodes from and a heal verifies)."""
         if not self.versions:
             raise errors.FileNotFound(name)
         d = self.find_version(version_id)
         fi = _version_to_fileinfo(d, volume, name)
         fi.is_latest = d is self.versions[0]
         fi.num_versions = len(self.versions)
-        if fi.data_dir and fi.data_dir in self.data:
+        if read_data and fi.data_dir and fi.data_dir in self.data:
             fi.data = self.data[fi.data_dir]
         return fi
 

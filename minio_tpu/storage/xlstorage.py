@@ -9,8 +9,9 @@ Write discipline mirrors the reference: shard data streams into
 (rename_data); xl.meta updates write-to-tmp + ``durable_replace`` (the
 fsync-policy commit primitive, storage/durability.py — docs/durability.md
 has the crash-consistency story, WRITE_STEPS below the crash-point
-catalogue). Small objects
-inline their data into xl.meta (A.4). O_DIRECT is intentionally not used —
+catalogue). A small object's
+shard rides in xl.meta itself (``Data``, A.4: no data directory, nothing
+staged; storage/xlmeta.py). O_DIRECT is intentionally not used —
 Python buffered I/O + the OS page cache stand in for the reference's
 hand-rolled aligned reads; the TPU hot path cares about device dispatch, not
 host file I/O syscalls.
@@ -703,10 +704,13 @@ class XLStorage(StorageAPI):
         ``<src>/<dataDir>`` under the object dir and add the version to
         xl.meta atomically w.r.t. this disk (reference RenameData).
 
-        Shard files (``fi.data`` is None) commit through ONE native call
-        when ``_native_fs()`` says so (``_commit_native``); inline data
-        and every run with a disk fault armed take the Python sequence
-        below, where the crash points are. Both leave the same tree and
+        Shard files (``fi.data`` is None) and an inline version
+        (``fi.data`` is THIS drive's framed shard, kept in xl.meta's
+        ``Data``: no data directory, nothing staged under ``src``) commit
+        through ONE native call each when ``_native_fs()`` says so
+        (``_commit_native``, ``_commit_inline_native``); every run with a
+        disk fault armed takes the Python sequence below, where the crash
+        points are. Both leave the same tree and
         issue the same fsyncs in the same order (docs/durability.md,
         tests/test_put_turns.py). Why: counted from Python the sequence
         is 21 file-system calls a drive, each a turn at the interpreter
@@ -714,13 +718,17 @@ class XLStorage(StorageAPI):
         ~2 ms at 6); with the staging before it a 10 MiB PUT made 314
         such calls at 12 drives and 158 at 6 (PERF.md section 6, PR 36).
         Now a drive's commit is 2 (the ``xl.meta`` read, the native
-        call) and a PUT 38 and 20."""
-        native_route = bool(fi.data_dir) and fi.data is None \
-            and _native_fs()
+        call), a PUT 38 and 20, and an inline PUT, which stages nothing,
+        25 and 13."""
+        inline = fi.data is not None
+        native_route = bool(fi.data_dir) and _native_fs()
         _mx.inc("minio_tpu_storage_commits_total",
                 route="native" if native_route else "python")
         with self._op("rename_data", dst_volume, dst_path), \
                 self._meta_lock:
+            if native_route and inline:
+                self._commit_inline_native(fi, dst_volume, dst_path)  # graftlint: disable=GL021
+                return
             if native_route:
                 self._commit_native(src_volume, src_path, fi,  # graftlint: disable=GL021
                                     dst_volume, dst_path)
@@ -729,7 +737,7 @@ class XLStorage(StorageAPI):
                 meta = self._load_meta(dst_volume, dst_path)  # graftlint: disable=GL021
             except errors.FileNotFound:
                 meta = XLMeta()
-            if fi.data_dir and fi.data is None:
+            if fi.data_dir and not inline:
                 src = self._abs(src_volume, src_path, fi.data_dir)
                 if not os.path.isdir(src):
                     raise errors.FileNotFound(src_path)
@@ -751,6 +759,8 @@ class XLStorage(StorageAPI):
             self._store_meta(dst_volume, dst_path, meta)  # graftlint: disable=GL021
             self._write_step("post_meta_write")
             self._purge_ddirs(dst_volume, dst_path, old_ddirs)
+        if inline:
+            return  # nothing was staged
         # clean the tmp parent dir; a failure here leaks tmp space until
         # the janitor reclaims it — make that visible, not silent
         # (already-gone is success: a prior call or the janitor won)
@@ -778,12 +788,44 @@ class XLStorage(StorageAPI):
         old_ddirs = meta.add_version(fi)
         dst = self._abs(dst_volume, dst_path, fi.data_dir)
         mode = fsync_mode()
-        step, err, sync_kind, file_syncs, dir_syncs, ddirs_left, tmp_left = \
+        self._commit_result(
             _native.commit_version(
                 self._abs(dst_volume), dst_path, fi.data_dir,
                 self._abs(src_volume, src_path, fi.data_dir),
                 self._abs(src_volume, src_path.split("/")[0]),
-                meta.dump(), old_ddirs, mode == FSYNC_ALWAYS)
+                meta.dump(), old_ddirs, mode == FSYNC_ALWAYS),
+            mode, dst_volume, dst_path, dst, src_path)
+
+    def _commit_inline_native(self, fi: FileInfo, dst_volume: str,
+                              dst_path: str) -> None:
+        """rename_data's sequence for an inline version as one native
+        call (native/pipeline.cpp mt_commit_inline): object directory,
+        the new xl.meta (the shard in its ``Data``) under a tmp name and
+        renamed over, replaced data directories removed; the fsyncs and
+        markers are durable_replace's. Journal logic, policy, errors and
+        counters stay here, as in ``_commit_native``."""
+        try:
+            meta = self._load_meta(dst_volume, dst_path, probe_volume=False)
+        except errors.FileNotFound:
+            meta = XLMeta()
+        old_ddirs = meta.add_version(fi)
+        mode = fsync_mode()
+        self._commit_result(
+            _native.commit_inline(
+                self._abs(dst_volume), dst_path,
+                self._abs(META_TMP, new_tmp_id()), meta.dump(), old_ddirs,
+                mode == FSYNC_ALWAYS),
+            mode, dst_volume, dst_path, None, dst_path)
+
+    def _commit_result(self, result: list, mode: str, dst_volume: str,
+                       dst_path: str, ddir_dst: str | None,
+                       src_path: str) -> None:
+        """What a native commit reports, turned into the counters, the
+        flusher's markers (for as far as the sequence came) and the typed
+        errors of the Python sequence. ``ddir_dst``: the committed data
+        directory, None for an inline version."""
+        step, err, sync_kind, file_syncs, dir_syncs, ddirs_left, tmp_left = \
+            result
         if file_syncs:
             _mx.inc("minio_tpu_durability_fsync_total", file_syncs,
                     kind="file")
@@ -793,8 +835,9 @@ class XLStorage(StorageAPI):
         if mode == FSYNC_BATCHED:
             # the markers durable_replace_dir and durable_replace leave,
             # for as far as the sequence came
-            if step == 0 or step > _native.COMMIT_DATA_RENAME:
-                flusher().enqueue_tree(dst)
+            if ddir_dst is not None and (
+                    step == 0 or step > _native.COMMIT_DATA_RENAME):
+                flusher().enqueue_tree(ddir_dst)
             if step == 0:
                 flusher().enqueue(self._meta_path(dst_volume, dst_path))
         if step == 0:
@@ -813,7 +856,8 @@ class XLStorage(StorageAPI):
         if step == _native.COMMIT_FSYNC:
             _mx.inc("minio_tpu_durability_fsync_failed_total",
                     kind="dir" if sync_kind else "file")
-        raise OSError(err, os.strerror(err), dst)
+        raise OSError(err, os.strerror(err),
+                      ddir_dst or self._meta_path(dst_volume, dst_path))
 
     def _purge_ddirs(self, volume: str, path: str, ddirs: list[str]):
         """Remove data dirs of replaced versions (overwrite cleanup).
@@ -848,12 +892,14 @@ class XLStorage(StorageAPI):
     def read_version(self, volume: str, path: str, version_id: str = "",
                      read_data: bool = False) -> FileInfo:
         # Inline data (fi.data) comes ONLY from xl.meta's Data section
-        # written at put time, as in the reference (cmd/xl-storage.go:1138).
-        # part.N files hold bitrot-framed SHARD bytes, never object bytes,
-        # so inlining them here would serve digest||shard as object data.
+        # written at commit time, as in the reference
+        # (cmd/xl-storage.go:1138): this drive's bitrot-framed shard of a
+        # version at or under SMALL_FILE_THRESHOLD, what part.1 would hold.
+        # Without ``read_data`` (a STAT) it stays behind: a remote drive
+        # would ship it for nothing.
         with self._op("read_version", volume, path):
             meta = self._load_meta(volume, path)
-            return meta.to_fileinfo(volume, path, version_id)
+            return meta.to_fileinfo(volume, path, version_id, read_data)
 
     def list_versions(self, volume: str, path: str) -> list[FileInfo]:
         with self._op("list_versions", volume, path):
@@ -874,11 +920,11 @@ class XLStorage(StorageAPI):
 
     def check_parts(self, volume: str, path: str, fi: FileInfo) -> None:
         """Verify all parts exist with the expected shard file size
-        (reference CheckParts)."""
+        (reference CheckParts). An inline version (``fi.data``: this
+        drive's shard as its xl.meta handed it back) is held to the
+        framed length of its one part."""
         from ..erasure.bitrot import (BITROT_CHUNK_KEY, BitrotAlgorithm,
                                       bitrot_shard_file_size)
-        if fi.data is not None:
-            return
         with self._op("check_parts", volume, path):
             algo = BitrotAlgorithm(fi.metadata.get(
                 "x-minio-internal-bitrot", "blake2b256S"))
@@ -888,14 +934,34 @@ class XLStorage(StorageAPI):
                 p = f"{path}/{fi.data_dir}/part.{part.number}"
                 want = bitrot_shard_file_size(
                     fi.erasure.shard_file_size(part.size), chunk, algo)
-                if self._stat_file_size_inner(volume, p) != want:
+                have, _ = self._shard_size(volume, path, fi, p)
+                if have != want:
                     raise errors.FileCorrupt(p)
+
+    def _shard_size(self, volume: str, path: str, fi: FileInfo,
+                    part_path: str) -> tuple[int, bytes | None]:
+        """(stored bytes of this drive's shard of one part, the shard
+        itself when it is inline). Inline is ``fi.data``, or, for a
+        ``fi`` that was read without its data, what this drive's own
+        journal holds under the version's data directory: looked for only
+        after the part file was not found, so shard files pay nothing."""
+        if fi.data is not None:
+            return len(fi.data), fi.data
+        try:
+            return self._stat_file_size_inner(volume, part_path), None
+        except errors.FileNotFound:
+            try:
+                shard = self._load_meta(volume, path).data.get(fi.data_dir)
+            except errors.StorageError:
+                shard = None
+            if shard is None:
+                raise
+            return len(shard), shard
 
     def verify_file(self, volume: str, path: str, fi: FileInfo) -> None:
         """Deep bitrot scan of every part on this disk (reference
-        VerifyFile / bitrotVerify)."""
-        if fi.data is not None:
-            return
+        VerifyFile / bitrotVerify); of an inline version, of the shard in
+        ``fi.data``: bitrot inside xl.meta is looked for like any other."""
         with self._op("verify_file", volume, path):
             self._verify_file_inner(volume, path, fi)
 
@@ -907,14 +973,16 @@ class XLStorage(StorageAPI):
             "x-minio-internal-bitrot", "blake2b256S"))
         chunk = int(fi.metadata.get(BITROT_CHUNK_KEY,
                                     str(fi.erasure.shard_size())))
+        from ..erasure.streaming import BufferSource
         for part in fi.parts:
             p = f"{path}/{fi.data_dir}/part.{part.number}"
-            fsize = self._stat_file_size_inner(volume, p)
+            fsize, shard = self._shard_size(volume, path, fi, p)
             logical = bitrot_logical_size(fsize, chunk, algo)
             want = fi.erasure.shard_file_size(part.size)
             if logical != want:
                 raise errors.FileCorrupt(p)
-            src = self.read_file_at(volume, p)
+            src = BufferSource(shard) if shard is not None \
+                else self.read_file_at(volume, p)
             try:
                 r = new_bitrot_reader(src, algo, logical, chunk)
                 # verify in multi-chunk spans: read_at does one backing
@@ -926,7 +994,8 @@ class XLStorage(StorageAPI):
                     r.read_at(off, n)
                     off += n
             finally:
-                src.close()
+                if shard is None:
+                    src.close()
 
     # --- crash recovery -----------------------------------------------------
 

@@ -133,6 +133,44 @@ def test_crash_matrix_put(tmp_path, step):
     assert ol2.get_object_bytes("b", "o") == data
 
 
+#: the steps an inline version's commit passes (ISSUE 39): no data
+#: directory moves, so the journal's write is the whole commit
+INLINE_STEPS = ("pre_meta_write", "pre_replace", "post_replace",
+                "post_meta_write")
+
+
+@pytest.mark.parametrize("step", INLINE_STEPS)
+def test_crash_matrix_put_inline(tmp_path, step):
+    """A 64 KiB object lives in its drives' xl.meta, a shard a drive. A
+    crash at any step of its commit leaves the old version or the new,
+    never neither and never a mix: xl.meta changes by one rename."""
+    root = str(tmp_path)
+    small = 64 << 10
+    body1, body2 = _body(3)[:small], _body(4)[:small]
+    ol = _layer(root)
+    ol.make_bucket("b")
+    ol.put_object("b", "o", io.BytesIO(body1), small)
+    for d in ol.disks:
+        assert d.list_dir("b", "o") == ["xl.meta"]
+
+    fault.arm(f"disk:*:{step}:crash")
+    with pytest.raises(fault.SimulatedCrash):
+        ol.put_object("b", "o", io.BytesIO(body2), small)
+    _settle()
+    fault.clear()
+
+    ol2, _kicks = _restart(root)
+    data = _read_or_absent(ol2, "b", "o")
+    assert data in (body1, body2), "neither version after the crash"
+    if step.startswith("pre_"):
+        assert data == body1  # no drive's journal had been renamed over
+    _assert_tmp_clean(ol2)
+    ol2.heal_object("b", "o")
+    assert ol2.get_object_bytes("b", "o") == data
+    for d in ol2.disks:
+        assert d.list_dir("b", "o") == ["xl.meta"]
+
+
 @pytest.mark.parametrize("step", MP_STEPS)
 def test_crash_matrix_multipart_complete(tmp_path, step):
     root = str(tmp_path)

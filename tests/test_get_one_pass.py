@@ -45,8 +45,9 @@ def passes(op: str) -> float:
 
 
 PLAIN = body_of(1, MIB + 17)
-#: under the 128 KiB a drive would inline at (this layer writes shard files
-#: at every size; ``fi.data`` is served when an xl.meta carries it)
+#: under the 128 KiB an object is kept at as a shard a drive inside
+#: xl.meta: the metadata pass brings the shards, the body is decoded from
+#: them in memory
 INLINE = body_of(2, 1000)
 TEXT = b"compressible line of text\n" * 8000  # ~200 KB, stored compressed
 PARTS = [body_of(10 + i, n) for i, n in enumerate(PART_SIZES)]
@@ -289,21 +290,28 @@ def test_a_held_handle_never_serves_another_version(ol, first, second):
 
 
 def test_data_inlined_in_xl_meta_rides_in_the_handle(ol):
-    """An xl.meta that carries the object's bytes (``fi.data``) is served
-    from the pass that read it: an overwrite after it changes nothing."""
+    """A version at or under 128 KiB lives in its drives' xl.meta, a shard
+    a drive (never the whole body), and the pass that read the journals
+    brought the shards: a held handle still serves the version it read
+    after an overwrite, which a handle over shard files cannot (its data
+    directory is purged under it)."""
     a, b = body_of(44, 1000), body_of(45, 2000)
     ol.put_object("b", "k", io.BytesIO(a), len(a))
-    for d in ol.disks:
-        fi = d.read_version("b", "k", "")
-        fi.data = a
-        d.update_metadata("b", "k", fi)
+    before = mx.counters_snapshot().get(
+        'minio_tpu_pipeline_get_blocks_total{route="inline"}', 0.0)
     oi, held = ol.get_object_n_info("b", "k")
-    assert held.fi.data == a
+    shards = [f.data for f in held.fis]
+    assert all(s is not None and len(s) < len(a) for s in shards)
+    assert len(set(shards)) == len(shards)  # each drive its OWN shard
+    for d in ol.disks:
+        assert [e for e in d.list_dir("b", "k")] == ["xl.meta"]
     ol.put_object("b", "k", io.BytesIO(b), len(b))
     sink = Collect()
     held.read(sink, 10, 500)
     assert bytes(sink.got) == a[10:510] and oi.size == len(a)
     assert ol.get_object_bytes("b", "k") == b
+    assert mx.counters_snapshot()[
+        'minio_tpu_pipeline_get_blocks_total{route="inline"}'] - before == 2
 
 
 def test_handle_reads_ranges_repeatedly_and_checks_them(ol):

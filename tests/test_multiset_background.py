@@ -7,6 +7,7 @@ import os
 import shutil
 
 import numpy as np
+import pytest
 
 from minio_tpu.objectlayer.pools import ServerPools
 from minio_tpu.objectlayer.sets import ErasureSets
@@ -21,13 +22,18 @@ def _sets(tmp_path, tag, set_count=2, drives=4):
     return ErasureSets(disks, set_count, drives, default_parity=2), disks
 
 
-def test_global_heal_covers_all_sets(tmp_path):
+@pytest.mark.parametrize("size", [8 << 10, 160 << 10],
+                         ids=["inline", "files"])
+def test_global_heal_covers_all_sets(tmp_path, size):
+    """At both layouts: shard files (over 128 KiB) and a shard a drive
+    inside xl.meta (at or under it), which ``check_parts`` holds to its
+    framed length wherever it lies."""
     sets, disks = _sets(str(tmp_path), "s")
     sets.make_bucket("mb")
     rng = np.random.default_rng(0)
     names = [f"obj-{i:02d}" for i in range(24)]
     for n in names:
-        b = rng.integers(0, 256, 8 << 10, dtype=np.uint8).tobytes()
+        b = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
         sets.put_object("mb", n, io.BytesIO(b), len(b))
     # confirm both sets actually own objects (hash placement)
     owners = {sets.get_hashed_set_index(n) for n in names}
@@ -44,13 +50,18 @@ def test_global_heal_covers_all_sets(tmp_path):
     set1_names = [n for n in names if sets.get_hashed_set_index(n) == 1]
     for disk, name in ((disks[1], set0_names[0]),
                        (disks[6], set1_names[0])):
-        fi = disk.read_version("mb", name)
+        fi = disk.read_version("mb", name, read_data=True)
+        assert (fi.data is not None) == (size <= 128 << 10)
+        assert os.path.isdir(os.path.join(
+            disk.base, "mb", name, fi.data_dir)) == (fi.data is None)
         disk.check_parts("mb", name, fi)
+        # a FileInfo read without its data is checked against the journal
+        disk.check_parts("mb", name, disk.read_version("mb", name))
     # and the full objects decode end-to-end
     for n in names:
         sink = io.BytesIO()
         sets.get_object("mb", n, sink)
-        assert len(sink.getvalue()) == 8 << 10
+        assert len(sink.getvalue()) == size
 
 
 def test_scanner_usage_covers_pools(tmp_path):

@@ -72,15 +72,26 @@ def test_range_get(ol):
         ol.get_object("bucket", "o", sink, len(data), 10)
 
 
-def test_overwrite(ol):
-    ol.put_object("bucket", "o", io.BytesIO(b"first"), 5)
-    ol.put_object("bucket", "o", io.BytesIO(b"second!"), 7)
-    assert ol.get_object_bytes("bucket", "o") == b"second!"
-    # the replaced version's dataDir must be reclaimed on every disk
+@pytest.mark.parametrize("pad", [0, 200 << 10], ids=["inline", "files"])
+def test_overwrite(ol, pad):
+    """What a replaced version held is reclaimed on every disk: its data
+    directory (shard files, over 128 KiB), or its entry in xl.meta's
+    ``Data`` (an inline version, which has no directory at all)."""
+    from minio_tpu.storage.xlmeta import XLMeta
+    first, second = b"first" + b"1" * pad, b"second!" + b"2" * pad
+    ol.put_object("bucket", "o", io.BytesIO(first), len(first))
+    ol.put_object("bucket", "o", io.BytesIO(second), len(second))
+    assert ol.get_object_bytes("bucket", "o") == second
     for d in ol.disks:
         entries = [e for e in d.list_dir("bucket", "o")
                    if e.endswith("/")]
-        assert len(entries) == 1, f"leaked data dirs: {entries}"
+        held = XLMeta.load(d.read_all("bucket", "o/xl.meta")).data
+        if pad:
+            assert len(entries) == 1, f"leaked data dirs: {entries}"
+            assert not held
+        else:
+            assert not entries, f"an inline version has no dir: {entries}"
+            assert len(held) == 1, f"leaked Data entries: {list(held)}"
 
 
 def test_delete(ol):

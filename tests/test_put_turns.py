@@ -15,7 +15,11 @@ Python sequences make a file-system call a step.
 * they raise the same errors; a drive whose path is a regular file fails
   with the error the health tracker fences on and leaves nothing staged;
 * a disk fault armed anywhere moves the process onto the Python sequence
-  (where the crash points are), and the route counters say so."""
+  (where the crash points are), and the route counters say so;
+* an object at or under 128 KiB (ISSUE 39) stages nothing: its shards ride
+  in the drives' xl.meta, a PUT is 2 calls a drive (the xl.meta read, one
+  native commit) and a GET makes exactly the calls of a STAT; its native
+  and its Python commit leave the same tree, bytes, fsyncs and markers."""
 import builtins
 import collections
 import io
@@ -48,7 +52,8 @@ OS_CALLS = (
     "write", "pread", "pwrite", "fsync", "fdatasync", "mkdir", "rmdir",
     "unlink", "remove", "rename", "replace", "scandir", "listdir", "link",
     "symlink", "readlink", "truncate", "ftruncate", "utime", "chmod")
-NATIVE_CALLS = ("stage_file", "close_fds", "commit_version", "open_shard")
+NATIVE_CALLS = ("stage_file", "close_fds", "commit_version", "commit_inline",
+                "open_shard")
 
 #: a rule that matches no drive: arming it is what moves the process onto
 #: the Python sequences
@@ -190,6 +195,51 @@ def test_put_takes_a_turn_a_drive(tmp_path, monkeypatch, n, parity, whole):
     assert turns.total() <= 5 * n + 2, turns.calls
 
 
+@pytest.mark.parametrize("n,parity,whole", [(12, 4, 26), (6, 2, 14)])
+def test_inline_put_and_get_take_the_turns_of_a_stat(tmp_path, monkeypatch,
+                                                     n, parity, whole):
+    """ISSUE 39's ceilings: a 64 KiB PUT under a new key makes at most 26
+    | 14 calls (12 | 6 drives) where a PUT of shard files makes 38 | 20:
+    the bucket's stat, and a drive the xl.meta read (which finds none)
+    and ONE native commit; nothing is staged, closed or cleaned. A GET of
+    it makes exactly the calls of a STAT (49 | 25 where a GET of shard
+    files makes 62 | 32): no shard file is opened."""
+    ol = _layer(str(tmp_path), n, parity)
+    ol.make_bucket("b")
+    body = _body(64 << 10)
+    ol.put_object("b", "warm", io.BytesIO(body), len(body))
+    turns = _Turns(monkeypatch, str(tmp_path))
+    before = _route_counters()
+    with turns:
+        ol.put_object("b", "k/new", io.BytesIO(body), len(body))
+    assert turns.of_request() <= 2, turns.calls
+    commits = turns.by_commit()
+    assert len(commits) == n and max(commits.values()) <= 2, turns.calls
+    assert turns.total() <= whole, turns.calls
+    assert sum(v for (_, _, name), v in turns.calls.items()
+               if name.endswith("commit_inline")) == n
+    assert _route_delta(before) == {
+        ("staged_files", "native"): 0, ("commits", "native"): n,
+        ("staged_files", "python"): 0, ("commits", "python"): 0}
+    for d in ol.disks:
+        assert os.listdir(os.path.join(d.base, "b", "k", "new")) == [
+            XL_META_FILE]
+        assert os.listdir(os.path.join(d.base, META_TMP)) == []
+    with turns:
+        ol.get_object_info("b", "k/new")
+    stat = turns.total()
+    assert stat == 4 * n + 1, turns.calls
+    with turns:
+        assert ol.get_object_bytes("b", "k/new") == body
+    assert turns.total() == stat, turns.calls
+    # an overwrite of it: the xl.meta read finds one (4 calls), the commit
+    # is still one; the replaced version had no directory to purge
+    with turns:
+        ol.put_object("b", "k/new", io.BytesIO(body[::-1]), len(body))
+    assert turns.total() <= 5 * n + 1, turns.calls
+    assert ol.get_object_bytes("b", "k/new") == body[::-1]
+
+
 # --- (b) the two sequences leave the same tree ------------------------------
 
 def _fi(vid="", ddir=None, data=None, size=11):
@@ -245,6 +295,18 @@ CASES = {
     "same_data_dir": ("obj", lambda: (lambda f: [f, replace(f, size=12)])(
         _fi())),
     "inline_data": ("obj", lambda: [_fi(ddir="", data=b"tiny")]),
+    # an inline version (ISSUE 39): this drive's shard in xl.meta's Data
+    "inline_shard": ("obj", lambda: [_fi(data=b"framed-shard" * 700)]),
+    "inline_nested": ("a/b/c/obj", lambda: [_fi(data=b"s" * 33)]),
+    "inline_over_inline": ("obj", lambda: [_fi(size=11, data=b"one" * 99),
+                                           _fi(size=12, data=b"two" * 99)]),
+    "inline_over_files": ("obj", lambda: [_fi(size=11),
+                                          _fi(size=12, data=b"two" * 99)]),
+    "files_over_inline": ("obj", lambda: [_fi(size=11, data=b"one" * 99),
+                                          _fi(size=12)]),
+    "inline_versioned": ("obj", lambda: [
+        _fi(vid=str(uuid.uuid4()), size=11, data=b"one" * 99),
+        _fi(vid=str(uuid.uuid4()), size=12, data=b"two" * 99)]),
 }
 
 
@@ -281,9 +343,12 @@ def test_native_and_python_sequences_leave_the_same_tree(
     nat, py = seen["native"], seen["python"]
     inline = case == "inline_data"
     n = len(versions)
-    # the rule: shard files commit natively, inline data never does
+    files = sum(1 for v in versions if v.data is None)
+    # the rule: a version with a data directory commits natively, its
+    # shard in files or in xl.meta; FS mode's whole copy (no data
+    # directory) never does
     assert nat["routes"] == {
-        ("staged_files", "native"): 0 if inline else 2 * n,
+        ("staged_files", "native"): 2 * files,
         ("commits", "native"): 0 if inline else n,
         ("staged_files", "python"): 0,
         ("commits", "python"): n if inline else 0}
@@ -295,27 +360,37 @@ def test_native_and_python_sequences_leave_the_same_tree(
     assert not [p for p in tree if p.startswith(".minio.sys/tmp/")
                 and p != ".minio.sys/tmp/"], "staging left behind"
     assert tree[f"bucket/{key}/{XL_META_FILE}"]
-    live = {v.data_dir for v in (versions if case == "versioned"
-                                 else versions[-1:]) if v.data_dir}
+    kept = versions if case.endswith("versioned") else versions[-1:]
+    live = {v.data_dir for v in kept if v.data_dir and v.data is None}
     ddirs = {p.split("/")[-2] for p in tree
              if p.startswith(f"bucket/{key}/") and p.endswith("/")
              and p != f"bucket/{key}/"}
     assert ddirs == live  # a replaced version's data directory is purged
     for d in live:
         assert tree[f"bucket/{key}/{d}/part.2"] == b"shard-bytes" * 2
+    # an inline version lives in the journal's Data and nowhere else, and
+    # a replaced one's entry is gone with it
+    from minio_tpu.storage.xlmeta import XLMeta
+    held = XLMeta.load(tree[f"bucket/{key}/{XL_META_FILE}"]).data
+    assert held == {v.data_dir: v.data for v in kept
+                    if v.data_dir and v.data is not None}
     assert nat["fsyncs"] == py["fsyncs"]
     assert nat["markers"] == py["markers"]
     if mode == "always" and not inline:
-        # a version: 2 shard files + xl.meta's tmp; the staged directory,
-        # the object directory after each of the two renames
-        assert nat["fsyncs"] == {"file": 3 * n, "dir": 3 * n}
+        # a version of shard files: 2 of them + xl.meta's tmp; the staged
+        # directory, the object directory after each of the two renames.
+        # An inline one: xl.meta's tmp; the object directory after its
+        # rename (docs/durability.md)
+        assert nat["fsyncs"] == {"file": 3 * files + (n - files),
+                                 "dir": 3 * files + (n - files)}
     elif mode != "always":
         assert nat["fsyncs"] == {"file": 0, "dir": 0}
     if mode == "batched" and not inline:
         assert nat["markers"] == [
             m for v in versions
-            for m in (("tree", f"bucket/{key}/{v.data_dir}"),
-                      ("file", f"bucket/{key}/{XL_META_FILE}"))]
+            for m in ([("tree", f"bucket/{key}/{v.data_dir}")]
+                      if v.data is None else [])
+            + [("file", f"bucket/{key}/{XL_META_FILE}")]]
     elif mode != "batched":
         assert nat["markers"] == []
     got = XLStorage(str(tmp_path / "native")).read_version("bucket", key)
@@ -367,7 +442,8 @@ disk.make_vol("bucket")
 if route == "python":
     fault.arm(t.NO_DRIVE)
 os.environ["FS_ORDER_LOG"] = base + ".log"
-for fi in (t._fi(ddir="dd-one", size=11), t._fi(ddir="dd-two", size=12)):
+for fi in (t._fi(ddir="dd-one", size=11), t._fi(ddir="dd-two", size=12),
+           t._fi(ddir="dd-three", size=13, data=b"framed-shard" * 9)):
     t._commit(disk, "a/obj", fi)
 """
 
@@ -421,7 +497,9 @@ def test_always_issues_the_python_sequences_fsyncs_in_their_order(tmp_path):
            ("fsync", "bucket/a/obj", "-")]
     assert logs["native"] == logs["python"]
     assert logs["native"][:7] == one
-    assert len(logs["native"]) == 14
+    # the third version is inline: durable_replace of xl.meta and no more
+    assert logs["native"][14:] == one[4:]
+    assert len(logs["native"]) == 17
 
 
 def test_staged_file_means_what_file_writer_means(tmp_path, monkeypatch):
@@ -585,5 +663,41 @@ def test_armed_fault_takes_the_python_route(tmp_path):
                            if p.startswith(k + "/") and not p.endswith("/"))
                  for k in ("native", "python")}
         assert shape["native"] == shape["python"] == ["part.1", "xl.meta"]
+    assert ol.get_object_bytes("b", "python") == body
+    assert ol.get_object_bytes("b", "native") == body
+
+
+def test_armed_fault_takes_the_python_route_for_an_inline_version(tmp_path):
+    """A 64 KiB PUT commits natively; with a disk fault armed (any: the
+    rule matches no drive) the Python sequence with its crash points runs,
+    the route counter says so, and both leave xl.meta alone in the
+    object's directory, holding that drive's shard."""
+    ol = _layer(str(tmp_path), 6, 2)
+    ol.make_bucket("b")
+    body = _body(64 << 10)
+    before = _route_counters()
+    ol.put_object("b", "native", io.BytesIO(body), len(body))
+    assert _route_delta(before) == {
+        ("staged_files", "native"): 0, ("commits", "native"): 6,
+        ("staged_files", "python"): 0, ("commits", "python"): 0}
+    fault.arm(NO_DRIVE)
+    before = _route_counters()
+    ol.put_object("b", "python", io.BytesIO(body), len(body))
+    assert _route_delta(before) == {
+        ("staged_files", "native"): 0, ("commits", "native"): 0,
+        ("staged_files", "python"): 0, ("commits", "python"): 6}
+    assert ol.get_object_bytes("b", "python") == body
+    fault.clear()
+    from minio_tpu.storage.xlmeta import XLMeta
+    for d in ol.disks:
+        t = _tree(os.path.join(d.base, "b"))
+        assert sorted(p for p in t if not p.endswith("/")) == [
+            "native/xl.meta", "python/xl.meta"]
+        shards = [next(iter(XLMeta.load(t[f"{k}/xl.meta"]).data.values()))
+                  for k in ("native", "python")]
+        # one body, one place in the distribution? no: the keys differ,
+        # so the shard a drive holds differs; the framed LENGTH is one
+        assert len(shards[0]) == len(shards[1]) > (64 << 10) // 4
+        assert os.listdir(os.path.join(d.base, META_TMP)) == []
     assert ol.get_object_bytes("b", "python") == body
     assert ol.get_object_bytes("b", "native") == body
