@@ -14,7 +14,8 @@ gf256_simd.cpp + highwayhash.cpp) provides:
 - a PUT's per-drive file-system sequences ``mt_stage_file`` /
   ``mt_close_fds`` / ``mt_commit_version`` / ``mt_commit_inline``
   (storage/xlstorage.py: a shard file staged, a version committed, in one
-  call each) and a read's ``mt_open_shard`` (open + fstat).
+  call each), a read's ``mt_open_shard`` (open + fstat) and
+  ``mt_read_file`` (a whole file, an ``xl.meta``: open, fstat, read, close).
 
 All entry points release the GIL (plain ctypes CDLL calls), so concurrent
 requests scale across cores where the host has them.
@@ -222,6 +223,10 @@ def _load_native_locked() -> ctypes.CDLL:
         lib.mt_stage_file.restype = ctypes.c_int
         lib.mt_open_shard.argtypes = [ctypes.c_char_p]
         lib.mt_open_shard.restype = ctypes.c_int
+        lib.mt_read_file.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
+                                     ctypes.c_long,
+                                     ctypes.POINTER(ctypes.c_long)]
+        lib.mt_read_file.restype = ctypes.c_long
         lib.mt_close_fds.argtypes = [
             ctypes.POINTER(ctypes.c_int), ctypes.c_int, ctypes.c_int,
             ctypes.POINTER(ctypes.c_int)]
@@ -467,7 +472,7 @@ def verify_framed(framed, plen: int, chunk: int, key: bytes,
                                 algo)
 
 
-# --- a PUT's file-system sequences (storage/xlstorage.py) -------------------
+# --- a request's file-system sequences (storage/xlstorage.py) ---------------
 
 #: mt_commit_version's and mt_commit_inline's steps, as the result names the
 #: one that failed
@@ -491,6 +496,33 @@ def open_shard(path: str) -> int:
     one call. Returns the fd, or ``-errno`` (``-EISDIR`` for a
     directory)."""
     return load_native().mt_open_shard(os.fsencode(path))
+
+
+#: what ``read_file`` reads in ONE call: an ``xl.meta`` that carries an
+#: inline shard is 8.5 KiB a drive for a 64 KiB object at 8+4 and ~33 KiB
+#: at the 128 KiB threshold on 4+2
+READ_FILE_ONE_CALL = 64 << 10
+
+_read_buf = threading.local()
+
+
+def read_file(path: str) -> bytes | int:
+    """A whole file (open, fstat, read to the size fstat gave, close) in
+    one call: its bytes, or ``-errno`` (``-EISDIR`` for a directory). A
+    file of up to ``READ_FILE_ONE_CALL`` bytes (64 KiB) lands in a buffer
+    the thread keeps; a larger one costs a second call, into a buffer of
+    its size."""
+    buf = getattr(_read_buf, "buf", None)
+    if buf is None:
+        buf = _read_buf.buf = ctypes.create_string_buffer(READ_FILE_ONE_CALL)
+    lib, raw, size = load_native(), os.fsencode(path), ctypes.c_long()
+    while True:
+        n = lib.mt_read_file(raw, buf, len(buf), ctypes.byref(size))
+        if n < 0:
+            return n
+        if size.value <= len(buf):
+            return ctypes.string_at(buf, n)
+        buf = ctypes.create_string_buffer(size.value)
 
 
 def close_fds(fds: list[int], do_fsync: bool) -> list[int]:

@@ -359,7 +359,7 @@ long mt_verify_framed(const uint8_t* framed, long plen, long chunk,
 
 }  // extern "C"
 
-// --- a PUT's file-system sequences ------------------------------------------
+// --- a request's file-system sequences --------------------------------------
 //
 // The bytes of a PUT move in mt_put_block_fds; what is left of its drive work
 // is file-system choreography: per drive 5 calls to stage a shard file and 21
@@ -367,11 +367,13 @@ long mt_verify_framed(const uint8_t* framed, long plen, long chunk,
 // Python (~2-3 ms beside 20 clients on the chip's host, PERF.md section 6).
 // The entry points below run those sequences with the lock let go once:
 // mt_stage_file (storage/xlstorage.py _StagedFile), mt_close_fds (its
-// close_many) and mt_commit_version (XLStorage.rename_data); mt_open_shard
-// is the readers' one (_FileReadAt: open + fstat). They perform the
-// steps of the Python sequences in the Python sequences' order, fsyncs
-// included; the Python side keeps the policy, the xl.meta logic, the errors
-// and the counters (docs/durability.md "The native sequence").
+// close_many) and mt_commit_version (XLStorage.rename_data). The readers'
+// are mt_open_shard (_FileReadAt: open + fstat) and mt_read_file
+// (_read_all_inner: open, fstat, read to the end, close: an xl.meta read,
+// so a quorum metadata pass is a turn a drive where it was four). They
+// perform the steps of the Python sequences in the Python sequences' order,
+// fsyncs included; the Python side keeps the policy, the xl.meta logic, the
+// errors and the counters (docs/durability.md "The native sequence").
 namespace {
 
 // mkdir each directory of `rel` below `base` (never `base` itself): 0 or errno.
@@ -513,6 +515,38 @@ int mt_open_shard(const char* path) {
     return -EISDIR;
   }
   return fd;
+}
+
+// Read one whole file, as os.open + os.fstat + os.read to the size fstat
+// gave + os.close do in _read_all_inner: the bytes read into `buf`, or
+// -errno (-EISDIR for a directory). `*size` is the size fstat gave: a file
+// larger than `cap` is not read at all (0 is returned), and the caller asks
+// again with a buffer of that size. It writes nothing and fsyncs nothing.
+long mt_read_file(const char* path, uint8_t* buf, long cap, long* size) {
+  *size = 0;
+  const int fd = open(path, O_RDONLY | O_CLOEXEC);
+  if (fd < 0) return -errno;
+  struct stat st;
+  long got = 0;
+  if (fstat(fd, &st) != 0) {
+    got = -errno;
+  } else if (S_ISDIR(st.st_mode)) {
+    got = -EISDIR;
+  } else if ((*size = (long)st.st_size) <= cap) {
+    while (got < *size) {
+      const ssize_t r = read(fd, buf + got, (size_t)(*size - got));
+      if (r > 0) {
+        got += r;
+      } else if (r == 0) {
+        break;  // the file shrank under the read: what is there
+      } else if (errno != EINTR) {
+        got = -errno;
+        break;
+      }
+    }
+  }
+  close(fd);
+  return got;
 }
 
 // Close n staged files; with do_fsync (policy `always`) each is fsynced

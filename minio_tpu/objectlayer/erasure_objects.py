@@ -545,10 +545,14 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             cleanup_tmp()
             raise to_object_err(err, bucket, object)
         if any(e is not None for e in errs):
-            cleanup_tmp()  # reclaim tmp on the failed minority
+            missed = [d for d, e in zip(shuffled, errs) if e is not None]
+            if not inline:
+                # reclaim tmp on the failed minority alone: a drive that
+                # committed removed its own staging (rename_data), and a
+                # visit is two more turns at the interpreter lock a drive
+                self._cleanup_tmp(tmp_id, missed)
             self._notify_partial(
-                bucket, object, fi.version_id, source="write",
-                missed=[d for d, e in zip(shuffled, errs) if e is not None])
+                bucket, object, fi.version_id, source="write", missed=missed)
         if inline:
             _count_inline("put", total)
             _spans.annotate(inline=True)
@@ -621,8 +625,10 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         mx.inc("minio_tpu_pipeline_etag_total", mode="fused")
         return PipelineETag()
 
-    def _cleanup_tmp(self, tmp_id: str):
-        for d in self.disks:
+    def _cleanup_tmp(self, tmp_id: str, disks: list | None = None):
+        """Remove what a PUT staged under ``tmp_id``, on every drive or on
+        ``disks`` alone."""
+        for d in self.disks if disks is None else disks:
             if d is None:
                 continue
             try:

@@ -100,8 +100,9 @@ def _minted_by_live_peer(name: str) -> bool:
 
 
 def _native_fs() -> bool:
-    """The rule that picks the route of a PUT's file-system sequences
-    (stage a shard file, commit a version): one native call each when the
+    """The rule that picks the route of a request's file-system sequences
+    (stage a shard file, commit a version, open a shard file, read an
+    ``xl.meta`` whole): one native call each when the
     native library is loaded and no disk fault is armed, else the Python
     sequence, a system call a turn at the interpreter lock. Nothing
     steers it: the Python sequence is the only one without a compiler,
@@ -486,39 +487,53 @@ class XLStorage(StorageAPI):
     def _read_all_inner(self, volume: str, path: str,
                         probe_volume: bool = True) -> bytes:
         """Untraced read_all for composite ops (xl.meta loads) — keeps
-        one logical storage call = one span/window observation. Raw
-        os.open/os.read, not io.open: one xl.meta read is four turns at
-        the interpreter lock (open, fstat, read, close), a quorum pass
-        49 of them on a 12-drive set and 25 on a 6-drive one, and on the
-        chip's host a turn costs ~3 ms (12 drives) or ~2 ms (6) beside 20
-        clients (PERF.md section 6, PRs 30, 31): a STAT that moves no
-        byte takes 147-160 ms at 12 drives, 53-60 ms at 6. A missing
-        file is told from a missing volume by a probe AFTER the failure;
-        ``probe_volume=False`` leaves even that out for a caller whose
-        next step fails on a missing volume anyway (rename_data)."""
+        one logical storage call = one span/window observation. One
+        native call (``native.read_file``: open, fstat, read to the end,
+        close, the interpreter lock let go once) when ``_native_fs()``
+        says so, else the same four from Python, raw os.open/os.read and
+        not io.open: the reference sequence, and the one a run with a
+        disk fault armed takes. From Python one xl.meta read is four
+        turns at the interpreter lock, a quorum pass 49 of them on a
+        12-drive set and 25 on a 6-drive one, and on the chip's host a
+        turn costs ~3 ms (12 drives) or ~2.5 ms (6) beside 20 clients
+        (PERF.md section 5): a STAT that moved no byte took 134-156 ms at
+        12 drives, 62 ms at 6 (ledger, PR 39); natively the pass is 13
+        and 7 (PERF.md section 6, PR 40). A file over 64 KiB costs a
+        second call (``native.READ_FILE_ONE_CALL``). Both routes raise
+        the same errors. A missing file is told from a missing volume by
+        a probe AFTER the failure; ``probe_volume=False`` leaves even
+        that out for a caller whose next step fails on a missing volume
+        anyway (rename_data)."""
+        full = self._abs(volume, path)
+        native_route = _native_fs()
+        _mx.inc("minio_tpu_storage_file_reads_total",
+                route="native" if native_route else "python")
         try:
-            fd = os.open(self._abs(volume, path), os.O_RDONLY)
+            if native_route:
+                out = _native.read_file(full)
+                if isinstance(out, int):
+                    raise OSError(-out, os.strerror(-out), full)
+                return out
+            fd = os.open(full, os.O_RDONLY)
+            try:
+                size = os.fstat(fd).st_size
+                chunks = []
+                got = 0
+                while got < size:
+                    b = os.read(fd, size - got)
+                    if not b:
+                        break
+                    chunks.append(b)
+                    got += len(b)
+                return chunks[0] if len(chunks) == 1 else b"".join(chunks)
+            finally:
+                os.close(fd)
         except FileNotFoundError:
             if probe_volume and not os.path.isdir(self._abs(volume)):
                 raise errors.VolumeNotFound(volume) from None
             raise errors.FileNotFound(path) from None
         except IsADirectoryError:
             raise errors.IsNotRegular(path) from None
-        try:
-            size = os.fstat(fd).st_size
-            chunks = []
-            got = 0
-            while got < size:
-                b = os.read(fd, size - got)
-                if not b:
-                    break
-                chunks.append(b)
-                got += len(b)
-            return chunks[0] if len(chunks) == 1 else b"".join(chunks)
-        except IsADirectoryError:
-            raise errors.IsNotRegular(path) from None
-        finally:
-            os.close(fd)
 
     def write_all(self, volume: str, path: str, data: bytes) -> None:
         """Atomic whole-file write (tmp + rename)."""
