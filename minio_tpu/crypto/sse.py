@@ -247,12 +247,10 @@ def _timed_block(lane, op: str, seq0: int, pkgs: list) -> list:
     minio_tpu_workloads_sse_seconds_total{cipher,op}."""
     t0 = time.monotonic()
     try:
-        return getattr(lane, op + "_block")(seq0, pkgs)
+        with _stages.stage("sse_" + op):
+            return getattr(lane, op + "_block")(seq0, pkgs)
     finally:
         dt_s = time.monotonic() - t0
-        st = _stages.active()
-        if st is not None:
-            st.add("sse_" + op, dt_s)
         try:
             from ..obs import metrics as _mx
             _mx.inc("minio_tpu_workloads_sse_seconds_total", dt_s,

@@ -486,18 +486,17 @@ def erasure_encode(erasure: Erasure, stream, writers: list,
         try:
             use = [fds[i] if writers[i] is not None else -1
                    for i in range(len(writers))]
-            t0 = time.monotonic() if stc is not None else 0.0
             times = np.zeros(2, dtype=np.float64) if stc is not None \
                 else None
-            codes = native.put_block_fds(
-                buf, buf_len, pmat, k, m, shard_len, chunk, HIGHWAY_KEY,
-                use, offset, algo_id, scratch=scratch, times=times)
-            if stc is not None:
+            # one native call: it says how its time split between the
+            # encode and the writes, and the boundary hands that share on
+            with _stages.timed(stc, "encode_hash") as b:
+                codes = native.put_block_fds(
+                    buf, buf_len, pmat, k, m, shard_len, chunk, HIGHWAY_KEY,
+                    use, offset, algo_id, scratch=scratch, times=times)
                 if times is not None and times[0] > 0.0:
-                    stc.add("encode_hash", float(times[0]))
-                    stc.add("shard_write", float(times[1]))
-                else:
-                    stc.add("encode_hash", time.monotonic() - t0)
+                    b.split("shard_write",
+                            float(times[1] / (times[0] + times[1])))
             digs = _extract_digests(scratch.reshape(k + m, fl), shard_len) \
                 if etag is not None else None
             return codes, digs
@@ -1101,8 +1100,9 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
                 _mx.inc("minio_tpu_pipeline_get_blocks_total",
                         route="native_fd" if healthy else "native_degraded")
                 # pure CPU kernel work — records no spans
-                fut = encode_pool().submit(pread_block, fds, offs,  # graftlint: disable=GL005
-                                           shard_len, out_dest, rebuild)
+                fut = encode_pool().submit(  # graftlint: disable=GL005
+                    _stages.pooled(pread_block, "decode"), fds, offs,
+                    shard_len, out_dest, rebuild)
                 return ["native", fut, b, block_data_len, boff, blen,
                         dest, present]
             framed = None
@@ -1113,7 +1113,8 @@ def erasure_decode(erasure: Erasure, writer, readers: list, offset: int,
                 _mx.inc("minio_tpu_pipeline_get_blocks_total",
                         route="native")
                 fut = encode_pool().submit(  # graftlint: disable=GL005 — pure kernel compute
-                    native.get_block, framed, k, shard_len, fuse_chunk,
+                    _stages.pooled(native.get_block, "decode"), framed, k,
+                    shard_len, fuse_chunk,
                     HIGHWAY_KEY, get_algo_id,
                     out=out_dest if out_dest is not None
                     else pool.get(k * shard_len))

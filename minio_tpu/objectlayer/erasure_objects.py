@@ -20,6 +20,7 @@ from ..obs import attribution as _attr
 from ..obs import latency as _lat
 from ..obs import metrics as _mx
 from ..obs import spans as _spans
+from ..obs import stages as _stages
 from ..obs import trace as _trc
 from .. import qos as _qos
 from ..erasure.bitrot import (BITROT_CHUNK_KEY, BitrotAlgorithm,
@@ -232,7 +233,8 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                 yield
                 return
             mtx = self.ns_lock.new_lock(bucket, object)
-            ok = mtx.get_lock(10.0) if write else mtx.get_rlock(10.0)
+            with _stages.stage("ns_lock"):
+                ok = mtx.get_lock(10.0) if write else mtx.get_rlock(10.0)
             if not ok:
                 raise dt.InsufficientWriteQuorum(bucket, object) if write \
                     else dt.InsufficientReadQuorum(bucket, object)
@@ -336,14 +338,15 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
     def get_bucket_info(self, bucket: str) -> BucketInfo:
         check_names(bucket)
         last: BaseException = dt.BucketNotFound(bucket)
-        for d in self.disks:
-            if d is None:
-                continue
-            try:
-                v = d.stat_vol(bucket)
-                return BucketInfo(name=v.name, created=v.created)
-            except Exception as e:  # noqa: BLE001
-                last = e
+        with _stages.stage("bucket_check"):
+            for d in self.disks:
+                if d is None:
+                    continue
+                try:
+                    v = d.stat_vol(bucket)
+                    return BucketInfo(name=v.name, created=v.created)
+                except Exception as e:  # noqa: BLE001
+                    last = e
         raise to_object_err(last, bucket)
 
     def list_buckets(self) -> list[BucketInfo]:
@@ -512,23 +515,24 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             raise
         try:
             futs = {}
-            for j, d in enumerate(shuffled):
-                if d is None or writers[j] is None:
-                    errs[j] = errors.DiskNotFound()
-                    continue
-                fij = replace(fi, erasure=replace(fi.erasure, index=j + 1),
-                              metadata=dict(fi.metadata),
-                              data=writers[j].sink.getvalue() if inline
-                              else None)
-                futs[j] = meta_pool().submit(
-                    _spans.wrap_ctx(d.rename_data), META_TMP, tmp_id, fij,
-                    bucket, object)
-            for j, f in futs.items():
-                try:
-                    f.result()
-                except Exception as e:  # noqa: BLE001
-                    errs[j] = e if isinstance(e, errors.StorageError) \
-                        else errors.FaultyDisk(str(e))
+            with _stages.stage("commit"):
+                for j, d in enumerate(shuffled):
+                    if d is None or writers[j] is None:
+                        errs[j] = errors.DiskNotFound()
+                        continue
+                    fij = replace(
+                        fi, erasure=replace(fi.erasure, index=j + 1),
+                        metadata=dict(fi.metadata),
+                        data=writers[j].sink.getvalue() if inline else None)
+                    futs[j] = meta_pool().submit(
+                        _spans.wrap_ctx(d.rename_data), META_TMP, tmp_id,
+                        fij, bucket, object)
+                for j, f in futs.items():
+                    try:
+                        f.result()
+                    except Exception as e:  # noqa: BLE001
+                        errs[j] = e if isinstance(e, errors.StorageError) \
+                            else errors.FaultyDisk(str(e))
         finally:
             lock_cm.__exit__(None, None, None)
         err = errors.reduce_write_quorum_errs(
@@ -646,17 +650,19 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         of passes (a GET takes one, shared by its headers and its body)."""
         _mx.inc("minio_tpu_objectlayer_quorum_meta_reads_total", op=op)
         disks = self.disks
-        # "" = latest; "null" resolves to the unversioned entry inside the
-        # journal (XLMeta.find_version) — do NOT collapse it to latest here
-        fis, errs = read_all_fileinfo(disks, bucket, object, version_id,
-                                      read_data)
-        read_quorum, _ = object_quorum_from_meta(
-            fis, errs, self.default_parity)
-        err = errors.reduce_read_quorum_errs(
-            errs, errors.BASE_IGNORED_ERRS, read_quorum)
-        if err is not None:
-            raise to_object_err(err, bucket, object)
-        fi = find_file_info_in_quorum(fis, read_quorum)
+        with _stages.stage("meta_pass"):
+            # "" = latest; "null" resolves to the unversioned entry inside
+            # the journal (XLMeta.find_version) — do NOT collapse it to
+            # latest here
+            fis, errs = read_all_fileinfo(disks, bucket, object, version_id,
+                                          read_data)
+            read_quorum, _ = object_quorum_from_meta(
+                fis, errs, self.default_parity)
+            err = errors.reduce_read_quorum_errs(
+                errs, errors.BASE_IGNORED_ERRS, read_quorum)
+            if err is not None:
+                raise to_object_err(err, bucket, object)
+            fi = find_file_info_in_quorum(fis, read_quorum)
         return fi, fis, errs
 
     def _stat_object(self, bucket: str, object: str, opts: ObjectOptions,
@@ -885,7 +891,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                           mod_time=FileInfo.now())
 
         errs: list[BaseException | None] = [None] * len(disks)
-        with self._locked(bucket, object):
+        with self._locked(bucket, object), _stages.stage("delete"):
             futs = {}
             for i, d in enumerate(disks):
                 if d is None:
@@ -1329,7 +1335,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             # future heal-path dispatch op on the latency lane too.
             with _spans.maybe_root("heal.object", cls="background",
                                    bucket=bucket, object=object,
-                                   mode=scan_mode), _attr.observed("heal"), \
+                                   mode=scan_mode), _attr.observed("heal.object"), \
                     _qos.lane_affinity(self._lane_key), \
                     _qos.device_stream(_qos.STREAM_INTERACTIVE):
                 return self._heal_object_inner(bucket, object, version_id,
@@ -1354,8 +1360,9 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         # read_data: an inline version's shards come with the pass; they
         # are what check_parts / verify_file look at and what the rebuild
         # reads
-        fis, errs = read_all_fileinfo(disks, bucket, object, vid,
-                                      read_data=True)
+        with _stages.stage("meta_pass"):
+            fis, errs = read_all_fileinfo(disks, bucket, object, vid,
+                                          read_data=True)
         read_quorum, _ = object_quorum_from_meta(fis, errs,
                                                  self.default_parity)
 
@@ -1552,7 +1559,9 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                           metadata=dict(fi.metadata),
                           data=sinks[i].getvalue() if inline else None)
             try:
-                disks[i].rename_data(META_TMP, tmp_id, fih, bucket, object)
+                with _stages.stage("commit"):
+                    disks[i].rename_data(META_TMP, tmp_id, fih, bucket,
+                                         object)
                 state[i] = DRIVE_STATE_OK
             except Exception:  # noqa: BLE001
                 pass

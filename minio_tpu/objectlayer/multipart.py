@@ -16,6 +16,7 @@ from ..erasure import Erasure, new_bitrot_writer
 from ..erasure.streaming import close_writers, erasure_encode
 from ..obs import metrics as _mx
 from ..obs import spans as _spans
+from ..obs import stages as _stages
 from ..storage.datatypes import ErasureInfo, FileInfo, ObjectPartInfo
 from ..storage.xlstorage import META_MULTIPART, META_TMP, new_tmp_id
 from ..utils import errors
@@ -78,21 +79,22 @@ class MultipartMixin:
         write_quorum = fi.write_quorum(parity)
         errs = [None] * n
         futs = {}
-        for i, d in enumerate(disks):
-            if d is None:
-                errs[i] = errors.DiskNotFound()
-                continue
-            fij = replace(fi, erasure=replace(
-                fi.erasure, index=fi.erasure.distribution[i]),
-                metadata=dict(fi.metadata))
-            futs[i] = meta_pool().submit(
-                _spans.wrap_ctx(d.write_metadata), META_MULTIPART, upath,
-                fij)
-        for i, f in futs.items():
-            try:
-                f.result()
-            except Exception as e:  # noqa: BLE001
-                errs[i] = e
+        with _stages.stage("commit"):
+            for i, d in enumerate(disks):
+                if d is None:
+                    errs[i] = errors.DiskNotFound()
+                    continue
+                fij = replace(fi, erasure=replace(
+                    fi.erasure, index=fi.erasure.distribution[i]),
+                    metadata=dict(fi.metadata))
+                futs[i] = meta_pool().submit(
+                    _spans.wrap_ctx(d.write_metadata), META_MULTIPART, upath,
+                    fij)
+            for i, f in futs.items():
+                try:
+                    f.result()
+                except Exception as e:  # noqa: BLE001
+                    errs[i] = e
         err = errors.reduce_write_quorum_errs(
             errs, errors.BASE_IGNORED_ERRS, write_quorum)
         if err is not None:
@@ -107,17 +109,18 @@ class MultipartMixin:
         upath = upload_path(bucket, object, upload_id)
         disks = self.disks
         _mx.inc("minio_tpu_objectlayer_quorum_meta_reads_total", op="upload")
-        fis, errs = read_all_fileinfo(disks, META_MULTIPART, upath)
-        read_quorum, _ = object_quorum_from_meta(fis, errs,
-                                                 self.default_parity)
-        err = errors.reduce_read_quorum_errs(
-            errs, errors.BASE_IGNORED_ERRS, read_quorum)
-        if err is not None:
-            raise dt.NoSuchUpload(bucket, object, upload_id)
-        try:
-            fi = find_file_info_in_quorum(fis, read_quorum)
-        except errors.StorageError:
-            raise dt.NoSuchUpload(bucket, object, upload_id) from None
+        with _stages.stage("meta_pass"):
+            fis, errs = read_all_fileinfo(disks, META_MULTIPART, upath)
+            read_quorum, _ = object_quorum_from_meta(fis, errs,
+                                                     self.default_parity)
+            err = errors.reduce_read_quorum_errs(
+                errs, errors.BASE_IGNORED_ERRS, read_quorum)
+            if err is not None:
+                raise dt.NoSuchUpload(bucket, object, upload_id)
+            try:
+                fi = find_file_info_in_quorum(fis, read_quorum)
+            except errors.StorageError:
+                raise dt.NoSuchUpload(bucket, object, upload_id) from None
         return fi, fis, errs
 
     # --- put part -----------------------------------------------------------
@@ -226,17 +229,18 @@ class MultipartMixin:
             "meta": dict(opts.user_defined) if opts is not None else {}},
             use_bin_type=True)
         errs = [None] * len(disks)
-        for j, d in enumerate(shuffled):
-            if d is None or writers[j] is None:
-                errs[j] = errors.DiskNotFound()
-                continue
-            try:
-                d.rename_file(META_TMP, f"{tmp_id}/part.{part_id}",
-                              META_MULTIPART, f"{upath}/part.{part_id}")
-                d.write_all(META_MULTIPART,
-                            f"{upath}/part.{part_id}.meta", part_meta)
-            except Exception as e:  # noqa: BLE001
-                errs[j] = e
+        with _stages.stage("commit"):
+            for j, d in enumerate(shuffled):
+                if d is None or writers[j] is None:
+                    errs[j] = errors.DiskNotFound()
+                    continue
+                try:
+                    d.rename_file(META_TMP, f"{tmp_id}/part.{part_id}",
+                                  META_MULTIPART, f"{upath}/part.{part_id}")
+                    d.write_all(META_MULTIPART,
+                                f"{upath}/part.{part_id}.meta", part_meta)
+                except Exception as e:  # noqa: BLE001
+                    errs[j] = e
         err = errors.reduce_write_quorum_errs(
             errs, errors.BASE_IGNORED_ERRS, write_quorum)
         if err is not None:
@@ -389,20 +393,21 @@ class MultipartMixin:
         tmp_id = new_tmp_id()
         errs = [None] * len(disks)
         futs = {}
-        for i, d in enumerate(disks):
-            if d is None or fis[i] is None:
-                errs[i] = errors.DiskNotFound()
-                continue
-            shard_idx = fis[i].erasure.index
-            futs[i] = meta_pool().submit(
-                _spans.wrap_ctx(self._commit_one_disk), d, upath, tmp_id,
-                fi, shard_idx, parts, bucket, object)
-        for i, f in futs.items():
-            try:
-                f.result()
-            except Exception as e:  # noqa: BLE001
-                errs[i] = e if isinstance(e, errors.StorageError) \
-                    else errors.FaultyDisk(str(e))
+        with _stages.stage("commit"):
+            for i, d in enumerate(disks):
+                if d is None or fis[i] is None:
+                    errs[i] = errors.DiskNotFound()
+                    continue
+                shard_idx = fis[i].erasure.index
+                futs[i] = meta_pool().submit(
+                    _spans.wrap_ctx(self._commit_one_disk), d, upath, tmp_id,
+                    fi, shard_idx, parts, bucket, object)
+            for i, f in futs.items():
+                try:
+                    f.result()
+                except Exception as e:  # noqa: BLE001
+                    errs[i] = e if isinstance(e, errors.StorageError) \
+                        else errors.FaultyDisk(str(e))
         err = errors.reduce_write_quorum_errs(
             errs, errors.BASE_IGNORED_ERRS, write_quorum)
         if err is not None:

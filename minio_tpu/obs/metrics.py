@@ -1220,9 +1220,27 @@ def _attribution_lines() -> list[str]:
              "# TYPE minio_tpu_stage_seconds_total counter",
              "# TYPE minio_tpu_stage_share_of_wall gauge",
              "# TYPE minio_tpu_stage_op_wall_seconds_total counter",
-             "# TYPE minio_tpu_stage_op_total counter"]
+             "# TYPE minio_tpu_stage_op_total counter",
+             "# TYPE minio_tpu_request_stage_seconds_total counter",
+             "# TYPE minio_tpu_request_stage_switches_total counter"]
     for op, ent in sorted(rep.items()):
         lab_op = _esc(op)
+        # the unit itself (stage=""): its wall, its own thread's CPU
+        # seconds and voluntary switches, on both clocks
+        for stage, st in (("", {
+                "seconds_total": ent["wall_seconds_total"],
+                "cpu_seconds_total": ent["cpu_seconds_total"],
+                "switches_total": ent["switches_total"]}),
+                *sorted(ent["stages"].items())):
+            lab = f'api="{lab_op}",stage="{_esc(stage)}"'
+            lines += [
+                f'minio_tpu_request_stage_seconds_total{{{lab},'
+                f'clock="wall"}} {st["seconds_total"]}',
+                f'minio_tpu_request_stage_seconds_total{{{lab},'
+                f'clock="cpu"}} {st["cpu_seconds_total"]}',
+                f'minio_tpu_request_stage_switches_total{{{lab}}} '
+                f'{st["switches_total"]}',
+            ]
         lines.append(
             f'minio_tpu_stage_op_wall_seconds_total{{op="{lab_op}"}} '
             f'{ent["wall_seconds_total"]}')

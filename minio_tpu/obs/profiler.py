@@ -55,6 +55,7 @@ import threading
 import time
 from collections import Counter
 
+from . import stages as _stages
 from .lockrank import _ORIG_LOCK
 
 #: sampling defaults (overridable via the ``profiler`` config KVS).
@@ -160,7 +161,6 @@ _ROLE_PATTERNS: tuple[tuple[str, str], ...] = (
     ("auto-heal", "scanner"),
     ("mrf-healer", "scanner"),
     ("heal-seq", "scanner"),
-    ("loadgen-scanner", "scanner"),
     ("lock-maintenance", "lock-maintenance"),
     ("dsync-", "lock-maintenance"),
     ("rpc-ping", "lock-maintenance"),
@@ -195,23 +195,26 @@ def thread_role(ident: int, name: str) -> str:
 
 # -- per-thread QoS tag registry ----------------------------------------------
 
-#: ident -> (qos class, op). Plain dict, GIL-atomic single-key updates;
-#: the sampler reads it cross-thread (contextvars cannot be).
-_tags: dict[int, tuple[str, str]] = {}
+#: ident -> (qos class, op, stage). Plain dict, GIL-atomic single-key
+#: updates; the sampler reads it cross-thread (contextvars cannot be).
+_tags: dict[int, tuple[str, str, str]] = {}
 
 
-def set_task_tag(cls: str, op: str) -> None:
+def set_task_tag(cls: str, op: str, stage: str = "") -> None:
     """Tag the calling thread's current work for sample attribution.
     The request path and the dispatch flush path call this at work
-    start and :func:`clear_task_tag` at work end."""
-    _tags[threading.get_ident()] = (cls, op)
+    start and :func:`clear_task_tag` at work end. ``stage`` is what the
+    thread is at when no stage boundary (obs/stages.py) is open on it;
+    an open boundary names the stage itself, and the sampler folds by
+    (op, stage, function)."""
+    _tags[threading.get_ident()] = (cls, op, stage)
 
 
 def clear_task_tag() -> None:
     _tags.pop(threading.get_ident(), None)
 
 
-def current_tag() -> tuple[str, str] | None:
+def current_tag() -> tuple[str, str, str] | None:
     return _tags.get(threading.get_ident())
 
 
@@ -282,8 +285,8 @@ class _Agg:
     counters. ``feed`` runs on the sampler thread only — no lock."""
 
     __slots__ = ("cap", "stacks", "leaves", "roles", "subsystems",
-                 "classes", "ops", "samples", "passes", "lockwait",
-                 "drops", "started_at", "started_mono", "hz")
+                 "classes", "ops", "stages", "samples", "passes",
+                 "lockwait", "drops", "started_at", "started_mono", "hz")
 
     def __init__(self, cap: int, hz: float):
         self.cap = cap
@@ -294,6 +297,7 @@ class _Agg:
         self.subsystems: Counter = Counter()
         self.classes: Counter = Counter()
         self.ops: Counter = Counter()
+        self.stages: Counter = Counter()
         self.samples = 0
         self.passes = 0
         self.lockwait = 0
@@ -302,13 +306,15 @@ class _Agg:
         self.started_mono = time.monotonic()
 
     def feed(self, sig: str, leaf: str, role: str, subsys: str,
-             tag: tuple[str, str] | None, waiting: bool) -> None:
+             tag: tuple[str, str, str] | None, waiting: bool) -> None:
         self.samples += 1
         self.roles[role] += 1
         self.subsystems[subsys] += 1
         if tag is not None:
             self.classes[tag[0]] += 1
             self.ops[tag[1]] += 1
+            if tag[2]:
+                self.stages[tag[1] + "/" + tag[2]] += 1
         if waiting:
             self.lockwait += 1
         if sig in self.stacks or len(self.stacks) < self.cap:
@@ -452,6 +458,11 @@ class _Sampler(threading.Thread):
                 role = thread_role(tid, names.get(tid, ""))
                 role_cache[tid] = role
             tag = _tags.get(tid)
+            stg = _stages.open_stage(tid)
+            if stg is not None:
+                # an open stage boundary names op and stage itself (a
+                # pool worker has no tag of its own: the request's)
+                tag = (tag[0] if tag else "-", stg[0], stg[1])
             waiting = tid in _waiting
             key = (id(frame), frame.f_lasti, id(frame.f_code), role,
                    tag, waiting)
@@ -462,8 +473,10 @@ class _Sampler(threading.Thread):
                 sig, leaf, subsys = _fold(frame)
                 full_sig = (
                     f"role:{role};class:{tag[0] if tag else '-'};"
-                    f"subsys:{subsys};{sig}"
-                    + (";[lockwait]" if waiting else ""))
+                    f"subsys:{subsys};"
+                    + (f"op:{tag[1]};stage:{tag[2]};"
+                       if tag and tag[2] else "")
+                    + sig + (";[lockwait]" if waiting else ""))
                 fold_cache[tid] = (key, full_sig, leaf, subsys)
             if feed_base:
                 _base.feed(full_sig, leaf, role, subsys, tag, waiting)
@@ -682,6 +695,7 @@ def report_top(agg: _Agg, n: int = 10) -> dict:
         "roles": _shares(agg.roles, total),
         "classes": _shares(agg.classes, total),
         "ops": _shares(agg.ops, total),
+        "stages": _shares(agg.stages, total, top=32),
         "lockwait_share": round(agg.lockwait / total, 4) if total
         else 0.0,
         "lock_contention": lock_report(n),
@@ -692,98 +706,6 @@ def snapshot_report(n: int = 10) -> dict:
     """The always-on base aggregate as a top report."""
     ensure_started()
     return report_top(_base, n)
-
-
-def _copy_counter(c: Counter) -> Counter:
-    """Copy a counter the sampler thread may be growing — a new key
-    landing mid-iteration raises RuntimeError; retry, then give up
-    empty (the delta clamps handle it)."""
-    for _ in range(4):
-        try:
-            return Counter(c)
-        except RuntimeError:
-            continue
-    return Counter()
-
-
-def agg_snapshot(full: bool = False) -> dict:
-    """Point-in-time copy of the base aggregate's counters — the cheap
-    half of :func:`delta_report`. ``full`` also copies the folded
-    stacks/leaves (top-frames deltas for bench windows)."""
-    ensure_started()
-    a = _base
-    with _wait_lock:
-        lock_waits = {site: (st[0], st[1])
-                      for site, st in _wait_stats.items()}
-    snap = {
-        "samples": a.samples,
-        "passes": a.passes,
-        "lockwait": a.lockwait,
-        "drops": a.drops,
-        "hz": a.hz,
-        "mono": time.monotonic(),
-        "subsystems": _copy_counter(a.subsystems),
-        "roles": _copy_counter(a.roles),
-        "classes": _copy_counter(a.classes),
-        "ops": _copy_counter(a.ops),
-        "lock_waits": lock_waits,
-    }
-    if full:
-        snap["stacks"] = _copy_counter(a.stacks)
-        snap["leaves"] = _copy_counter(a.leaves)
-    return snap
-
-
-def delta_report(before: dict, n: int = 10) -> dict:
-    """Attribution report over the base aggregate's growth since
-    ``before`` (an :func:`agg_snapshot`). This is the ZERO-ADDED-COST
-    window: it rides the always-on sampler instead of attaching a
-    capture, so a measured section (a parallel-GET burst, a forced
-    scanner cycle) pays nothing beyond the standing base rate — and
-    crucially, a window and its surrounding baseline carry the identical sampling
-    tax, so before/during comparisons stay unbiased."""
-    after = agg_snapshot(full="stacks" in before)
-    samples = max(0, after["samples"] - before["samples"])
-    duration = max(1e-9, after["mono"] - before["mono"])
-    passes = max(0, after["passes"] - before["passes"])
-    # window-scoped lock contention: the cumulative per-site stats are
-    # diffed the same way as every other field — without this, a run
-    # report would blame its measured phase for preload/setup waits
-    lock_rows = []
-    for site, (c, s) in after["lock_waits"].items():
-        c0, s0 = before.get("lock_waits", {}).get(site, (0, 0.0))
-        if c - c0 > 0:
-            lock_rows.append({"site": site, "waits": c - c0,
-                              "wait_seconds_total": round(s - s0, 6)})
-    lock_rows.sort(key=lambda r: -r["wait_seconds_total"])
-    out = {
-        "samples": samples,
-        "duration_s": round(duration, 3),
-        # observed pass rate over the window (see report_top)
-        "sample_hz": round(passes / duration, 2) if passes
-        else round(after["hz"], 2),
-        "drops": max(0, after["drops"] - before["drops"]),
-        "subsystems": _shares(after["subsystems"] -
-                              before["subsystems"], samples),
-        "roles": _shares(after["roles"] - before["roles"], samples),
-        "classes": _shares(after["classes"] - before["classes"],
-                           samples),
-        "ops": _shares(after["ops"] - before["ops"], samples),
-        "lockwait_share": round(
-            max(0, after["lockwait"] - before["lockwait"]) / samples,
-            4) if samples else 0.0,
-        "lock_contention": lock_rows[:n],
-    }
-    if "stacks" in before:
-        leaves = after["leaves"] - before["leaves"]
-        stacks = after["stacks"] - before["stacks"]
-        out["top_frames"] = [
-            {"frame": f, "count": c,
-             "share": round(c / samples, 4) if samples else 0.0}
-            for f, c in leaves.most_common(n)]
-        out["top_stacks"] = [{"stack": s, "count": c}
-                             for s, c in stacks.most_common(n)]
-    return out
 
 
 def base_agg() -> _Agg:

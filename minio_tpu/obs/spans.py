@@ -33,6 +33,8 @@ import uuid
 from collections import OrderedDict
 from dataclasses import dataclass
 
+from . import stages as _stages
+
 #: RPC header carrying the caller's span context (W3C traceparent
 #: shape: ``00-<trace_id>-<span_id>-<flags>``); lowercase because the
 #: server's header map is lowercased.
@@ -113,11 +115,19 @@ def wrap_ctx(fn):
     """Bind ``fn`` to the caller's contextvars (span context included)
     so pool-executed storage fan-outs still record into the right
     trace — contextvars do not cross thread-pool submissions on their
-    own."""
+    own. The stage collector rides along, and while one is armed the
+    task is a boundary of its own on the worker (obs/stages.py
+    ``pool_task``: ``<stage>.pool``, the worker's CPU time and
+    switches)."""
     ctx = contextvars.copy_context()
-
-    def run(*a, **kw):
-        return ctx.run(fn, *a, **kw)
+    task = _stages.pool_task()
+    if task is None:
+        def run(*a, **kw):
+            return ctx.run(fn, *a, **kw)
+    else:
+        def run(*a, **kw):
+            with task:
+                return ctx.run(fn, *a, **kw)
 
     return run
 

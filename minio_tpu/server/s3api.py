@@ -19,6 +19,8 @@ import xml.etree.ElementTree as ET
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from ..bucket import BucketMetadataSys
+from ..obs import attribution as _attr
+from ..obs import stages as _stages
 from ..objectlayer import ObjectLayer, ObjectOptions
 from ..objectlayer import datatypes as dt
 from ..utils.hashreader import (BadDigestError, HashReader,
@@ -624,6 +626,14 @@ class _S3Handler(BaseHTTPRequestHandler):
     def log_message(self, fmt, *args):  # noqa: A003
         pass
 
+    def parse_request(self):
+        """The request line is in: the first end of the request's record
+        (obs/attribution.py), read before the head is parsed so that
+        ``head`` is a stage of it. The wait for the line is the
+        connection's idle time, not the request's."""
+        self._head = _attr.mark() if _attr.enabled() else None
+        return super().parse_request()
+
     # --- plumbing -----------------------------------------------------------
 
     def _parse(self):
@@ -727,18 +737,19 @@ class _S3Handler(BaseHTTPRequestHandler):
             # truncated body instead of seeing EOF. Cut the connection.
             self.close_connection = True
             return
-        self.send_response(status)
-        for k, v in (headers or {}).items():
-            if v is not None and v != "":
-                self.send_header(k, v)
-        if body or status not in (204, 304):
-            self.send_header("Content-Type", content_type)
-            self.send_header("Content-Length", str(len(body)))
-        else:
-            self.send_header("Content-Length", "0")
-        self.end_headers()
-        if body and self.command != "HEAD":
-            self.wfile.write(body)
+        with _stages.stage("respond"):
+            self.send_response(status)
+            for k, v in (headers or {}).items():
+                if v is not None and v != "":
+                    self.send_header(k, v)
+            if body or status not in (204, 304):
+                self.send_header("Content-Type", content_type)
+                self.send_header("Content-Length", str(len(body)))
+            else:
+                self.send_header("Content-Length", "0")
+            self.end_headers()
+            if body and self.command != "HEAD":
+                self.wfile.write(body)
 
     def _error(self, code: str, message: str, status: int):
         if status in (204, 304):  # bodiless statuses per RFC 9110
@@ -794,7 +805,9 @@ class _S3Handler(BaseHTTPRequestHandler):
             return
         bucket = self.bucket if bucket is None else bucket
         key = self.key if key is None else key
-        if not gate(access_key, action, bucket, key):
+        with _stages.stage("auth"):
+            allowed = gate(access_key, action, bucket, key)
+        if not allowed:
             raise AuthError("AccessDenied", f"not allowed to {action}")
 
     def _sts(self, body: bytes):
@@ -875,8 +888,18 @@ class _S3Handler(BaseHTTPRequestHandler):
 
     # --- routing ------------------------------------------------------------
 
-    def _route(self):
-        self._parse()
+    def _plane_name(self) -> str:
+        """'admin' / 'internal' for the server's own planes (every path
+        under /minio/), '' for an S3 call."""
+        path = getattr(self, "url_path", self.path)
+        if path.startswith("/minio/admin/"):
+            return "admin"
+        return "internal" if path.startswith("/minio/") else ""
+
+    def _plane(self):
+        """The routing ladder ahead of S3: what serves this request when
+        it is not an S3 call, as a callable (run by ``_route`` outside the
+        ``route`` stage), or None."""
         # unauthenticated health endpoints (cmd/healthcheck-handler.go):
         # liveness = this process serves HTTP (the RPC reconnect pings
         # probe it DURING cluster bootstrap, when no node has an object
@@ -884,69 +907,48 @@ class _S3Handler(BaseHTTPRequestHandler):
         # readiness/cluster = storage is actually online
         if self.url_path.startswith("/minio/health/"):
             if self.url_path.rstrip("/").endswith("/live"):
-                return self._send(200, b"", "text/plain; charset=utf-8")
+                return lambda: self._send(200, b"",
+                                          "text/plain; charset=utf-8")
             ok = self.s3.obj is not None and self.s3.obj.is_ready()
-            return self._send(200 if ok else 503, b"",
-                              "text/plain; charset=utf-8")
+            return lambda: self._send(200 if ok else 503, b"",
+                                      "text/plain; charset=utf-8")
         # internal RPC services (storage/lock/peer — reference
         # registerDistErasureRouters, cmd/routers.go:26-39)
         if self.url_path.startswith("/minio/") and self.s3.internal:
             parts = self.url_path.split("/", 4)
             if len(parts) >= 5 and parts[2] in self.s3.internal:
-                return self._internal_rpc(parts[2], parts[4])
+                return lambda: self._internal_rpc(parts[2], parts[4])
         if self.s3.obj is None:
-            return self._error("ServerNotInitialized",
-                               "server still starting", 503)
+            return lambda: self._error("ServerNotInitialized",
+                                       "server still starting", 503)
         if self.url_path.startswith("/minio/metrics") or \
                 self.url_path.startswith("/minio/v2/metrics"):
-            from ..obs.metrics import render_prometheus
-            scope = "node" if self.url_path.rstrip("/").endswith("/node") \
-                else "cluster"
-            # ?attribution=1 appends the standing per-op stage
-            # breakdown families (minio_tpu_stage_*, ISSUE 9)
-            attribution = self.query.get("attribution", [""])[0] == "1"
-            # exemplars are OpenMetrics-only syntax: emit them (and the
-            # matching content type + # EOF) only on EXPLICIT
-            # ?openmetrics=1 request. Not Accept-negotiated on purpose:
-            # modern Prometheus lists openmetrics-text in its default
-            # Accept, and this exposition keeps classic counter naming
-            # ('X_total' declared as-is), which a STRICT OM parser
-            # rejects wholesale — sniffing Accept would break scrapers
-            # that parse the classic form fine today. A classic parser
-            # conversely reads a trailing exemplar '#' as an invalid
-            # timestamp, so the default form strips them.
-            om = self.query.get("openmetrics", [""])[0] == "1"
-            ctype = ("application/openmetrics-text; version=1.0.0; "
-                     "charset=utf-8") if om else \
-                "text/plain; version=0.0.4"
-            return self._send(200, render_prometheus(
-                self.s3, scope, attribution=attribution,
-                openmetrics=om), ctype)
+            return self._metrics
         if self.url_path.startswith("/minio/admin/"):
             from .admin import handle_admin
-            return handle_admin(self)
+            return lambda: handle_admin(self)
         # web console plane (reference cmd/web-router.go: /minio/webrpc
         # JSON-RPC + JWT-authenticated upload/download routes + the static
         # single-file SPA at /minio/)
         if self.url_path in ("/minio", "/minio/", "/minio/index.html"):
             from .webrpc import handle_console
-            return handle_console(self)
+            return lambda: handle_console(self)
         if self.url_path == "/minio/webrpc":
             from .webrpc import handle_webrpc
-            return handle_webrpc(self)
+            return lambda: handle_webrpc(self)
         if self.url_path.startswith("/minio/upload/"):
             from .webrpc import handle_upload
             rest = self.url_path[len("/minio/upload/"):]
             bucket, _, obj = rest.partition("/")
-            return handle_upload(self, bucket, obj)
+            return lambda: handle_upload(self, bucket, obj)
         if self.url_path.startswith("/minio/download/"):
             from .webrpc import handle_download
             rest = self.url_path[len("/minio/download/"):]
             bucket, _, obj = rest.partition("/")
-            return handle_download(self, bucket, obj)
+            return lambda: handle_download(self, bucket, obj)
         if self.url_path == "/minio/zip":
             from .webrpc import handle_download_zip
-            return handle_download_zip(self)
+            return lambda: handle_download_zip(self)
         # STS endpoint: POST / with form-encoded Action (cmd/sts-handlers.go)
         # — AssumeRoleWithWebIdentity carries no Authorization header (the
         # JWT is the credential), so the gate is the Action itself
@@ -954,20 +956,62 @@ class _S3Handler(BaseHTTPRequestHandler):
                 self.s3.iam is not None:
             body = self._read_body()
             if b"Action=Assume" in body or b"Action=assume" in body:
-                return self._sts(body)
+                return lambda: self._sts(body)
         # browser POST uploads authenticate via the signed policy inside
         # the form, not an Authorization header
         if self.command == "POST" and self.key == "" and \
                 self.bucket and self.hdr.get("content-type", "").startswith(
                     "multipart/form-data"):
-            try:
-                return self.post_policy_upload()
-            except dt.ObjectAPIError as e:
-                return self._api_error(e)
-            except AuthError as e:
-                return self._error(e.code, e.message, e.status)
+            return self._post_policy
+        return None
+
+    def _metrics(self):
+        from ..obs.metrics import render_prometheus
+        scope = "node" if self.url_path.rstrip("/").endswith("/node") \
+            else "cluster"
+        # ?attribution=1 appends the standing per-op stage
+        # breakdown families (minio_tpu_stage_*, ISSUE 9)
+        attribution = self.query.get("attribution", [""])[0] == "1"
+        # exemplars are OpenMetrics-only syntax: emit them (and the
+        # matching content type + # EOF) only on EXPLICIT
+        # ?openmetrics=1 request. Not Accept-negotiated on purpose:
+        # modern Prometheus lists openmetrics-text in its default
+        # Accept, and this exposition keeps classic counter naming
+        # ('X_total' declared as-is), which a STRICT OM parser
+        # rejects wholesale — sniffing Accept would break scrapers
+        # that parse the classic form fine today. A classic parser
+        # conversely reads a trailing exemplar '#' as an invalid
+        # timestamp, so the default form strips them.
+        om = self.query.get("openmetrics", [""])[0] == "1"
+        ctype = ("application/openmetrics-text; version=1.0.0; "
+                 "charset=utf-8") if om else \
+            "text/plain; version=0.0.4"
+        return self._send(200, render_prometheus(
+            self.s3, scope, attribution=attribution,
+            openmetrics=om), ctype)
+
+    def _post_policy(self):
         try:
-            access_key = self._authenticate()
+            return self.post_policy_upload()
+        except dt.ObjectAPIError as e:
+            return self._api_error(e)
+        except AuthError as e:
+            return self._error(e.code, e.message, e.status)
+
+    def _route(self):
+        with _stages.stage("route"):
+            self._parse()
+            unit = getattr(self, "_unit", None)
+            if unit is not None:
+                # the stages of a request go by its API's name from here
+                unit.st.api = self._api = self._plane_name() or \
+                    self._api_name()
+            plane = self._plane()
+        if plane is not None:
+            return plane()
+        try:
+            with _stages.stage("auth"):
+                access_key = self._authenticate()
         except AuthError as e:
             # anonymous access rides bucket policies when IAM is on
             if self.s3.iam is not None and e.code == "AccessDenied" and \
@@ -1587,8 +1631,15 @@ class _S3Handler(BaseHTTPRequestHandler):
         sent_mark = getattr(self.wfile, "sent", 0)
         release = None
         from ..obs import profiler as _prof
+        # the request's stage collector, from the socket to the reply
+        # (obs/attribution.py): armed here, where the span root is, its
+        # first end read when the request line came in (parse_request)
+        self._api = None
+        self._unit = unit = _attr.begin(
+            rid, f"s3.{self.command.lower()}", getattr(self, "_head", None))
         try:
-            proceed, release = self._admit()
+            with _stages.stage("admit"):
+                proceed, release = self._admit()
             # per-thread QoS tag (obs/profiler.py): contextvars are not
             # visible cross-thread, so the sampling profiler joins this
             # worker's samples to its admitted class + op through the
@@ -1603,17 +1654,16 @@ class _S3Handler(BaseHTTPRequestHandler):
             if release is not None:
                 release()
             try:
-                self._drain_body()
+                with _stages.stage("drain"):
+                    self._drain_body()
             except Exception:  # noqa: BLE001
                 self.close_connection = True
+            epilogue = _stages.stage("epilogue")
+            epilogue.__enter__()
             dur = _time.perf_counter() - t0
             status = getattr(self, "_last_status", 0)
             path = getattr(self, "url_path", self.path)
-            api = f"s3.{self.command}"
-            if path.startswith("/minio/admin/"):
-                api = "admin"
-            elif path.startswith("/minio/"):
-                api = "internal"
+            api = self._plane_name() or f"s3.{self.command}"
             api_detail = api
             try:
                 mx.inc("minio_tpu_requests_total", api=api,
@@ -1624,7 +1674,7 @@ class _S3Handler(BaseHTTPRequestHandler):
                 if api.startswith("s3."):
                     # per-API-name family (reference metrics-v2 label
                     # scheme: api="getobject"-style)
-                    name = self._api_name()
+                    name = self._api or self._api_name()
                     api_detail = f"s3.{name}"
                     mx.inc("minio_tpu_s3_requests_total", api=name)
                     if status >= 400:
@@ -1720,6 +1770,13 @@ class _S3Handler(BaseHTTPRequestHandler):
                             sp.schedule_collect(rid, peers)
                 except Exception:  # noqa: BLE001
                     pass
+            epilogue.__exit__(None, None, None)
+            self._head = None
+            _attr.finish(
+                unit, api_detail[3:] if api_detail.startswith("s3.")
+                else api_detail, status,
+                getattr(self, "_consumed", 0) + max(
+                    0, getattr(self.wfile, "sent", 0) - sent_mark))
 
     def do_GET(self):  # noqa: N802
         self._handle()
@@ -2465,12 +2522,8 @@ class _S3Handler(BaseHTTPRequestHandler):
             status = 206
             headers["Content-Range"] = \
                 f"bytes {rng[0]}-{rng[1]}/{logical_size}"
-        self.send_response(status)
-        for k, v in headers.items():
-            if v:
-                self.send_header(k, v)
-        self.send_header("Content-Length", str(length))
-        self.end_headers()
+        headers["Content-Length"] = str(length)
+        self._respond_head(status, headers)
         if length > 0:
             self._write_plain(body, oi, sse, self.wfile, offset, length)
         self._notify("s3:ObjectAccessed:Get", oi)
@@ -2492,12 +2545,8 @@ class _S3Handler(BaseHTTPRequestHandler):
             body, status = data[rng[0]:rng[1] + 1], 206
             headers["Content-Range"] = \
                 f"bytes {rng[0]}-{rng[1]}/{len(data)}"
-        self.send_response(status)
-        for k, v in headers.items():
-            if v:
-                self.send_header(k, v)
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
+        headers["Content-Length"] = str(len(body))
+        self._respond_head(status, headers)
         self.wfile.write(body)
         self._notify("s3:ObjectAccessed:Get", oi)
 
@@ -2538,12 +2587,7 @@ class _S3Handler(BaseHTTPRequestHandler):
             h["x-amz-storage-class"] = oi.internal.get(tx.META_TIER, "")
             if oi.size > 0 and tx.is_restored(oi):
                 h["x-amz-restore"] = 'ongoing-request="false"'
-            self.send_response(200)
-            for k, v in h.items():
-                if v:
-                    self.send_header(k, v)
-            self.end_headers()
-            return
+            return self._respond_head(200, h)
         sse = self._sse_read_ctx(oi)
         h = self._obj_headers(oi)
         if sse:
@@ -2554,11 +2598,17 @@ class _S3Handler(BaseHTTPRequestHandler):
             h["Content-Length"] = str(
                 oi.actual_size if oi.internal.get(cz.META_COMPRESSION)
                 else oi.size)
-        self.send_response(200)
-        for k, v in h.items():
-            if v:
-                self.send_header(k, v)
-        self.end_headers()
+        self._respond_head(200, h)
+
+    def _respond_head(self, status: int, headers: dict) -> None:
+        """Status line and headers of a reply whose body, if any, is
+        streamed after them (stage ``respond``, as ``_send``)."""
+        with _stages.stage("respond"):
+            self.send_response(status)
+            for k, v in headers.items():
+                if v:
+                    self.send_header(k, v)
+            self.end_headers()
 
     def _check_preconditions(self, oi):
         inm = self.hdr.get("if-none-match", "")

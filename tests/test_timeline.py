@@ -356,9 +356,9 @@ def test_attribution_covers_put_get_heal_e2e(tmp_path):
     assert rep["put"]["stages"]["encode_hash"]["seconds_total"] > 0
     assert rep["put"]["stages"]["shard_write"]["seconds_total"] > 0
     assert rep["get"]["count"] >= 1 and rep["get"]["stages"]
-    assert rep["heal"]["stages"].get("rebuild", {}).get(
+    assert rep["heal.object"]["stages"].get("rebuild", {}).get(
         "seconds_total", 0) > 0
-    assert rep["heal"]["stages"]["shard_write"]["seconds_total"] > 0
+    assert rep["heal.object"]["stages"]["shard_write"]["seconds_total"] > 0
 
 
 def test_attribution_disabled_with_recorder():
