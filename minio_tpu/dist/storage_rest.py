@@ -107,6 +107,13 @@ class StorageRESTClient(StorageAPI):
             "svolume": src_volume, "spath": src_path,
             "dvolume": dst_volume, "dpath": dst_path})
 
+    def commit_part(self, src_volume, src_path, dst_volume, dst_path,
+                    meta: bytes) -> None:
+        """One round trip where ``rename_file`` + ``write_all`` were two."""
+        self._call("commitpart", {
+            "svolume": src_volume, "spath": src_path,
+            "dvolume": dst_volume, "dpath": dst_path}, meta)
+
     def delete_path(self, volume: str, path: str, recursive: bool = False
                     ) -> None:
         self._call("deletepath", {"volume": volume, "path": path,
@@ -328,6 +335,11 @@ class StorageRESTService:
 
     def _h_renamefile(self, d, p, b):
         d.rename_file(p["svolume"], p["spath"], p["dvolume"], p["dpath"])
+        return b""
+
+    def _h_commitpart(self, d, p, b):
+        d.commit_part(p["svolume"], p["spath"], p["dvolume"], p["dpath"],
+                      b or b"")
         return b""
 
     def _h_deletepath(self, d, p, b):

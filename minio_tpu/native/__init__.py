@@ -12,10 +12,11 @@ gf256_simd.cpp + highwayhash.cpp) provides:
   ``mt_get_block_pread_degraded`` (pread+verify+rebuild+assemble) that
   carry the end-to-end object path on the CPU route,
 - a PUT's per-drive file-system sequences ``mt_stage_file`` /
-  ``mt_close_fds`` / ``mt_commit_version`` / ``mt_commit_inline``
-  (storage/xlstorage.py: a shard file staged, a version committed, in one
-  call each), a read's ``mt_open_shard`` (open + fstat) and
-  ``mt_read_file`` (a whole file, an ``xl.meta``: open, fstat, read, close).
+  ``mt_close_fds`` / ``mt_commit_version`` / ``mt_commit_inline`` /
+  ``mt_commit_part`` (storage/xlstorage.py: a shard file staged, a version
+  or a multipart part committed, in one call each), a read's
+  ``mt_open_shard`` (open + fstat) and ``mt_read_file`` (a whole file, an
+  ``xl.meta``: open, fstat, read, close).
 
 All entry points release the GIL (plain ctypes CDLL calls), so concurrent
 requests scale across cores where the host has them.
@@ -242,6 +243,11 @@ def _load_native_locked() -> ctypes.CDLL:
             ctypes.c_char_p, ctypes.c_long, ctypes.c_char_p, ctypes.c_int,
             ctypes.c_int, ctypes.POINTER(ctypes.c_int)]
         lib.mt_commit_inline.restype = ctypes.c_int
+        lib.mt_commit_part.argtypes = [
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
+            ctypes.c_long, ctypes.c_int, ctypes.POINTER(ctypes.c_int)]
+        lib.mt_commit_part.restype = ctypes.c_int
         _lib = lib
     return _lib
 
@@ -474,8 +480,8 @@ def verify_framed(framed, plen: int, chunk: int, key: bytes,
 
 # --- a request's file-system sequences (storage/xlstorage.py) ---------------
 
-#: mt_commit_version's and mt_commit_inline's steps, as the result names the
-#: one that failed
+#: mt_commit_version's, mt_commit_inline's and mt_commit_part's steps, as the
+#: result names the one that failed
 COMMIT_OBJECT_DIR = 1
 COMMIT_STAGED = 2
 COMMIT_DATA_RENAME = 3
@@ -565,4 +571,19 @@ def commit_inline(vol: str, obj: str, tmp: str, meta: bytes,
     load_native().mt_commit_inline(
         os.fsencode(vol), os.fsencode(obj), os.fsencode(tmp), meta,
         len(meta), names, len(purge), int(do_fsync), out)
+    return list(out)
+
+
+def commit_part(vol: str, part: str, src: str, tmp: str, tmp_parent: str,
+                meta: bytes, do_fsync: bool) -> list[int]:
+    """The file-system half of one drive's ``commit_part`` in one call
+    (native/pipeline.cpp mt_commit_part has the steps): the staged shard
+    file ``src`` renamed to ``<vol>/<part>``, ``meta`` written to ``tmp``
+    and renamed to ``<vol>/<part>.meta``, the emptied ``tmp_parent``
+    removed. The result reads as ``commit_version``'s."""
+    out = (ctypes.c_int * 7)()
+    load_native().mt_commit_part(
+        os.fsencode(vol), os.fsencode(part), os.fsencode(src),
+        os.fsencode(tmp), os.fsencode(tmp_parent), meta, len(meta),
+        int(do_fsync), out)
     return list(out)

@@ -367,8 +367,9 @@ long mt_verify_framed(const uint8_t* framed, long plen, long chunk,
 // Python (~2-3 ms beside 20 clients on the chip's host, PERF.md section 6).
 // The entry points below run those sequences with the lock let go once:
 // mt_stage_file (storage/xlstorage.py _StagedFile), mt_close_fds (its
-// close_many) and mt_commit_version (XLStorage.rename_data). The readers'
-// are mt_open_shard (_FileReadAt: open + fstat) and mt_read_file
+// close_many), mt_commit_version (XLStorage.rename_data) and mt_commit_part
+// (XLStorage.commit_part: a multipart part's shard and its sidecar). The
+// readers' are mt_open_shard (_FileReadAt: open + fstat) and mt_read_file
 // (_read_all_inner: open, fstat, read to the end, close: an xl.meta read,
 // so a quorum metadata pass is a turn a drive where it was four). They
 // perform the steps of the Python sequences in the Python sequences' order,
@@ -418,15 +419,16 @@ int fsync_at(const char* path, int flags, int* ok) {
   return e;
 }
 
-// steps of mt_commit_version and mt_commit_inline, as `out[0]` names the one
-// that failed
+// steps of mt_commit_version, mt_commit_inline and mt_commit_part, as
+// `out[0]` names the one that failed
 enum {
   kStepDone = 0,
-  kStepObjectDir = 1,   // mkdir of the object directory below the volume
-  kStepStaged = 2,      // the staged data directory is not a directory
-  kStepDataRename = 3,  // <src> -> <object>/<dataDir>
-  kStepMetaWrite = 4,   // xl.meta written under its tmp name
-  kStepMetaRename = 5,  // tmp name -> <object>/xl.meta
+  // (in brackets: what the step is for a multipart part)
+  kStepObjectDir = 1,   // mkdir of the object [upload] directory below the vol
+  kStepStaged = 2,      // the staged data directory [part file] is not there
+  kStepDataRename = 3,  // <src> -> <object>/<dataDir> [<upload>/part.N]
+  kStepMetaWrite = 4,   // xl.meta [the sidecar] written under its tmp name
+  kStepMetaRename = 5,  // tmp name -> <object>/xl.meta [<upload>/part.N.meta]
   kStepFsync = 6,       // an fsync of policy `always` failed; out[2] = kind
 };
 
@@ -436,11 +438,21 @@ int commit_failed(int* out, int step, int e) {
   return step;
 }
 
-// durable_replace of a new xl.meta: `meta` written to `tmp`  [always: fsync
-// it], renamed over <odir>/xl.meta  [always: fsync <odir>]. 0, or the step
-// that failed as commit_failed left it in `out`.
-int commit_meta(const std::string& odir, const std::string& tmp,
-                const uint8_t* meta, long meta_len, int do_fsync, int* out) {
+// durability.fsync_path(strict) under policy `always`, nothing under another:
+// 0, or the errno of a failed fsync with its kind in out[2].
+int synced(const char* path, bool dir, int do_fsync, int* out) {
+  if (!do_fsync) return 0;
+  const int e = fsync_at(path, dir ? O_DIRECTORY : 0, &out[dir ? 4 : 3]);
+  if (e) out[2] = dir ? 1 : 0;
+  return e;
+}
+
+// durable_replace of a new xl.meta (or a part's sidecar): `meta` written to
+// `tmp`  [always: fsync it], renamed over `mdst`, a file of <odir>  [always:
+// fsync <odir>]. 0, or the step that failed as commit_failed left it in `out`.
+int commit_meta(const std::string& mdst, const std::string& odir,
+                const std::string& tmp, const uint8_t* meta, long meta_len,
+                int do_fsync, int* out) {
   int e;
   int fd = open(tmp.c_str(), O_WRONLY | O_CREAT | O_TRUNC | O_CLOEXEC, 0666);
   if (fd < 0) return commit_failed(out, kStepMetaWrite, errno);
@@ -464,13 +476,10 @@ int commit_meta(const std::string& odir, const std::string& tmp,
     out[3]++;
   }
   close(fd);
-  const std::string mdst = odir + "/xl.meta";
   if (rename(tmp.c_str(), mdst.c_str()) != 0)
     return commit_failed(out, kStepMetaRename, errno);
-  if (do_fsync && (e = fsync_at(odir.c_str(), O_DIRECTORY, &out[4]))) {
-    out[2] = 1;
+  if ((e = synced(odir.c_str(), true, do_fsync, out)))
     return commit_failed(out, kStepFsync, e);
-  }
   return kStepDone;
 }
 
@@ -579,12 +588,6 @@ int mt_commit_version(const char* vol, const char* obj, const char* ddir,
                       int n_purge, int do_fsync, int* out) {
   for (int i = 0; i < 7; i++) out[i] = 0;
   auto fail = [&](int step, int e) { return commit_failed(out, step, e); };
-  auto synced = [&](const char* path, bool dir) {
-    if (!do_fsync) return 0;
-    const int e = fsync_at(path, dir ? O_DIRECTORY : 0, &out[dir ? 4 : 3]);
-    if (e) out[2] = dir ? 1 : 0;
-    return e;
-  };
   int e = mkdirs_below(vol, obj, false);
   if (e) return fail(kStepObjectDir, e);
   const std::string odir = std::string(vol) + "/" + obj;
@@ -595,11 +598,13 @@ int mt_commit_version(const char* vol, const char* obj, const char* ddir,
   if (stat(dst.c_str(), &st) == 0 && S_ISDIR(st.st_mode) &&
       rm_tree(dst.c_str()) < 0)
     return fail(kStepDataRename, errno);
-  if ((e = synced(src, true))) return fail(kStepFsync, e);
+  if ((e = synced(src, true, do_fsync, out))) return fail(kStepFsync, e);
   if (rename(src, dst.c_str()) != 0) return fail(kStepDataRename, errno);
-  if ((e = synced(odir.c_str(), true))) return fail(kStepFsync, e);
+  if ((e = synced(odir.c_str(), true, do_fsync, out)))
+    return fail(kStepFsync, e);
 
-  if (commit_meta(odir, std::string(tmp_parent) + "/xl.meta", meta, meta_len,
+  if (commit_meta(odir + "/xl.meta", odir,
+                  std::string(tmp_parent) + "/xl.meta", meta, meta_len,
                   do_fsync, out))
     return out[0];
   purge_ddirs(odir, purge, n_purge, out);
@@ -622,8 +627,45 @@ int mt_commit_inline(const char* vol, const char* obj, const char* tmp,
   const int e = mkdirs_below(vol, obj, false);
   if (e) return commit_failed(out, kStepObjectDir, e);
   const std::string odir = std::string(vol) + "/" + obj;
-  if (commit_meta(odir, tmp, meta, meta_len, do_fsync, out)) return out[0];
+  if (commit_meta(odir + "/xl.meta", odir, tmp, meta, meta_len, do_fsync, out))
+    return out[0];
   purge_ddirs(odir, purge, n_purge, out);
+  return kStepDone;
+}
+
+// Commit one multipart part on one drive, the file-system half of
+// commit_part: the staged shard file <src> becomes <vol>/<part> (`part` is
+// <upload>/part.N below the volume) and `meta` its sidecar <vol>/<part>.meta:
+//   1. <src> is there; mkdir the directories of `part` below the volume (the
+//      upload's: there since the Create, but for a drive that missed it)
+//   2. [always: fsync <src>]  rename <src> -> <vol>/<part>  [always: fsync
+//      the upload's directory]
+//   3. write `meta` to `tmp` (a name under .minio.sys/tmp)  [always: fsync it]
+//      rename it -> <vol>/<part>.meta  [always: fsync the upload's directory]
+//   4. rmdir <tmp_parent>, the staging directory the shard left empty
+// The shard is in place before its sidecar names it, and the sidecar is
+// never seen torn. `out` as mt_commit_version's (out[5] stays 0; out[6]: 1
+// when <tmp_parent> is there and could not be removed). Steps 1-3 stop at
+// the first failure; step 4 is the clean-up of a commit that stands.
+int mt_commit_part(const char* vol, const char* part, const char* src,
+                   const char* tmp, const char* tmp_parent,
+                   const uint8_t* meta, long meta_len, int do_fsync,
+                   int* out) {
+  for (int i = 0; i < 7; i++) out[i] = 0;
+  auto fail = [&](int step, int e) { return commit_failed(out, step, e); };
+  struct stat st;
+  if (stat(src, &st) != 0) return fail(kStepStaged, ENOENT);
+  int e = mkdirs_below(vol, part, true);
+  if (e) return fail(kStepObjectDir, e);
+  const std::string dst = std::string(vol) + "/" + part;
+  const std::string udir = dst.substr(0, dst.rfind('/'));
+  if ((e = synced(src, false, do_fsync, out))) return fail(kStepFsync, e);
+  if (rename(src, dst.c_str()) != 0) return fail(kStepDataRename, errno);
+  if ((e = synced(udir.c_str(), true, do_fsync, out)))
+    return fail(kStepFsync, e);
+  if (commit_meta(dst + ".meta", udir, tmp, meta, meta_len, do_fsync, out))
+    return out[0];
+  if (rmdir(tmp_parent) != 0 && errno != ENOENT) out[6] = 1;
   return kStepDone;
 }
 

@@ -37,6 +37,17 @@ def upload_path(bucket: str, object: str, upload_id: str = "") -> str:
     return f"{h}/{upload_id}" if upload_id else h
 
 
+def _gather(futs: dict, errs: list) -> None:
+    """Wait for one task a drive; a task's error lands at its drive's
+    place in ``errs`` (anything but a StorageError as FaultyDisk)."""
+    for i, f in futs.items():
+        try:
+            f.result()
+        except Exception as e:  # noqa: BLE001
+            errs[i] = e if isinstance(e, errors.StorageError) \
+                else errors.FaultyDisk(str(e))
+
+
 class MultipartMixin:
     """Multipart methods for ErasureObjects (mixed into the class; relies on
     self.disks / self.default_parity / self.block_size / self.bitrot_algo /
@@ -229,22 +240,37 @@ class MultipartMixin:
             "meta": dict(opts.user_defined) if opts is not None else {}},
             use_bin_type=True)
         errs = [None] * len(disks)
+        futs = {}
+        staged, part = f"{tmp_id}/part.{part_id}", f"{upath}/part.{part_id}"
         with _stages.stage("commit"):
+            # every drive at once, one storage call a drive (reference
+            # PutObjectPart's rename over an errgroup of the disks)
             for j, d in enumerate(shuffled):
                 if d is None or writers[j] is None:
                     errs[j] = errors.DiskNotFound()
                     continue
-                try:
-                    d.rename_file(META_TMP, f"{tmp_id}/part.{part_id}",
-                                  META_MULTIPART, f"{upath}/part.{part_id}")
-                    d.write_all(META_MULTIPART,
-                                f"{upath}/part.{part_id}.meta", part_meta)
-                except Exception as e:  # noqa: BLE001
-                    errs[j] = e
+                futs[j] = meta_pool().submit(
+                    _spans.wrap_ctx(d.commit_part), META_TMP, staged,
+                    META_MULTIPART, part, part_meta)
+            _gather(futs, errs)
         err = errors.reduce_write_quorum_errs(
             errs, errors.BASE_IGNORED_ERRS, write_quorum)
+        missed = [d for d, e in zip(shuffled, errs) if e is not None]
         if err is not None:
+            # a refused part is on no drive (reference undoRename): the
+            # sidecar goes first, so that none names a missing shard
+            for j in futs:
+                if errs[j] is None:
+                    for path in (part + ".meta", part):
+                        try:
+                            shuffled[j].delete_path(META_MULTIPART, path)
+                        except Exception:  # noqa: BLE001
+                            pass
+            self._cleanup_tmp(tmp_id, missed)
             raise to_object_err(err, bucket, object)
+        if missed:
+            # a drive that committed removed its own staging (commit_part)
+            self._cleanup_tmp(tmp_id, missed)
         return PartInfo(part_number=part_id, etag=etag, size=total,
                         actual_size=hr.actual_size
                         if hr.actual_size >= 0 else total,
@@ -402,12 +428,7 @@ class MultipartMixin:
                 futs[i] = meta_pool().submit(
                     _spans.wrap_ctx(self._commit_one_disk), d, upath, tmp_id,
                     fi, shard_idx, parts, bucket, object)
-            for i, f in futs.items():
-                try:
-                    f.result()
-                except Exception as e:  # noqa: BLE001
-                    errs[i] = e if isinstance(e, errors.StorageError) \
-                        else errors.FaultyDisk(str(e))
+            _gather(futs, errs)
         err = errors.reduce_write_quorum_errs(
             errs, errors.BASE_IGNORED_ERRS, write_quorum)
         if err is not None:

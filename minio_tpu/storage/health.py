@@ -51,7 +51,7 @@ BENIGN_ERRS = (
 _DELEGATED = [
     "disk_info", "make_vol", "make_vols", "list_vols", "stat_vol",
     "delete_vol", "list_dir", "read_all", "write_all", "append_file",
-    "create_file_writer", "rename_file", "delete_path",
+    "create_file_writer", "rename_file", "commit_part", "delete_path",
     "stat_file_size", "rename_data", "write_metadata", "update_metadata",
     "read_version", "list_versions", "delete_version", "delete_versions",
     "check_parts", "verify_file", "walk_dir", "walk_versions",

@@ -87,6 +87,15 @@ class StorageAPI(abc.ABC):
                     dst_path: str) -> None: ...
 
     @abc.abstractmethod
+    def commit_part(self, src_volume: str, src_path: str, dst_volume: str,
+                    dst_path: str, meta: bytes) -> None:
+        """Commit a multipart part: the staged shard file ``<src>`` moves
+        to ``<dst>``, ``meta`` is written to ``<dst>.meta`` atomically and
+        only after it, and the emptied staging directory (``src_path``'s
+        first component) is removed."""
+        ...
+
+    @abc.abstractmethod
     def delete_path(self, volume: str, path: str, recursive: bool = False
                     ) -> None: ...
 

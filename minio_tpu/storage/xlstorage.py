@@ -59,7 +59,8 @@ WRITE_STEPS = (
     "post_data_rename",   # dataDir visible, xl.meta not yet updated
     "pre_meta_write",     # version journal about to be rewritten
     "post_meta_write",    # journal committed, tmp/purge cleanup pending
-    "pre_rename_file",    # rename_file commit (multipart part promote)
+    "pre_rename_file",    # rename_file / commit_part's Python sequence
+                          # (multipart part promote)
     "pre_append",         # append_file about to mutate in place
 )
 
@@ -101,8 +102,8 @@ def _minted_by_live_peer(name: str) -> bool:
 
 def _native_fs() -> bool:
     """The rule that picks the route of a request's file-system sequences
-    (stage a shard file, commit a version, open a shard file, read an
-    ``xl.meta`` whole): one native call each when the
+    (stage a shard file, commit a version or a multipart part, open a
+    shard file, read an ``xl.meta`` whole): one native call each when the
     native library is loaded and no disk fault is armed, else the Python
     sequence, a system call a turn at the interpreter lock. Nothing
     steers it: the Python sequence is the only one without a compiler,
@@ -612,6 +613,66 @@ class XLStorage(StorageAPI):
             self._write_step("pre_rename_file", tmp=src)
             durable_replace(src, dst)
 
+    def commit_part(self, src_volume: str, src_path: str, dst_volume: str,
+                    dst_path: str, meta: bytes) -> None:
+        """Commit a multipart part on this drive: the staged shard file
+        ``<src>`` becomes ``<dst>`` and ``meta`` its sidecar
+        ``<dst>.meta`` (a tmp name under ``.minio.sys/tmp``, then
+        renamed: never seen torn, and never before the shard it names),
+        and the staging directory the shard leaves empty is removed.
+
+        ONE native call (``native.commit_part``) when ``_native_fs()``
+        says so; every run with a disk fault armed takes the Python
+        sequence below (``rename_file`` + ``write_all``'s, crash points
+        ``pre_rename_file``, ``pre_replace`` / ``post_replace``). Both
+        leave the same tree and issue the same fsyncs in the same order
+        (docs/durability.md, tests/test_put_turns.py). Why: from Python
+        the sequence is 8 file-system calls a drive, each a turn at the
+        interpreter lock of ~4-6 ms beside 8 clients on the chip's host,
+        and ``put_object_part`` made them one drive after the other on
+        the request's thread: 59 % of a 16 MiB part PUT's wall (PERF.md
+        section 6, PR 43). Now a drive's commit is one call, all drives
+        at once."""
+        native_route = _native_fs()
+        _mx.inc("minio_tpu_storage_part_commits_total",
+                route="native" if native_route else "python")
+        with self._op("commit_part", dst_volume, dst_path,
+                      in_bytes=len(meta)):
+            src = self._abs(src_volume, src_path)
+            dst = self._abs(dst_volume, dst_path)
+            staging = self._abs(src_volume, src_path.split("/")[0])
+            if native_route:
+                # native/pipeline.cpp mt_commit_part; the policy, the
+                # counters, ``batched``'s markers (durable_replace's two,
+                # for as far as the sequence came) and the typed errors
+                # stay here, as in ``_commit_native``
+                mode = fsync_mode()
+                result = _native.commit_part(
+                    self._abs(dst_volume), dst_path, src,
+                    self._abs(META_TMP, new_tmp_id()), staging, meta,
+                    mode == FSYNC_ALWAYS)
+                step = self._count_syncs(result)
+                if mode == FSYNC_BATCHED:
+                    if step == 0 or step > _native.COMMIT_DATA_RENAME:
+                        flusher().enqueue(dst)
+                    if step == 0:
+                        flusher().enqueue(dst + ".meta")
+                self._commit_outcome(result, dst_volume, src_path, dst)
+                return
+            if not os.path.exists(src):
+                raise errors.FileNotFound(src_path)
+            self._make_parent(dst_volume, dst)
+            self._write_step("pre_rename_file", tmp=src)
+            durable_replace(src, dst)
+            self._write_all_inner(dst_volume, dst_path + ".meta", meta)
+            try:
+                os.rmdir(staging)
+            except FileNotFoundError:
+                pass
+            except OSError:
+                _mx.inc("minio_tpu_durability_purge_failed_total",
+                        kind="tmp")
+
     def delete_path(self, volume: str, path: str, recursive: bool = False
                     ) -> None:
         with self._op("delete", volume, path):
@@ -839,14 +900,7 @@ class XLStorage(StorageAPI):
         flusher's markers (for as far as the sequence came) and the typed
         errors of the Python sequence. ``ddir_dst``: the committed data
         directory, None for an inline version."""
-        step, err, sync_kind, file_syncs, dir_syncs, ddirs_left, tmp_left = \
-            result
-        if file_syncs:
-            _mx.inc("minio_tpu_durability_fsync_total", file_syncs,
-                    kind="file")
-        if dir_syncs:
-            _mx.inc("minio_tpu_durability_fsync_total", dir_syncs,
-                    kind="dir")
+        step = self._count_syncs(result)
         if mode == FSYNC_BATCHED:
             # the markers durable_replace_dir and durable_replace leave,
             # for as far as the sequence came
@@ -855,6 +909,28 @@ class XLStorage(StorageAPI):
                 flusher().enqueue_tree(ddir_dst)
             if step == 0:
                 flusher().enqueue(self._meta_path(dst_volume, dst_path))
+        self._commit_outcome(
+            result, dst_volume, src_path,
+            ddir_dst or self._meta_path(dst_volume, dst_path))
+
+    @staticmethod
+    def _count_syncs(result: list) -> int:
+        """The fsyncs a native commit made, counted; returns its step."""
+        step, _, _, file_syncs, dir_syncs, _, _ = result
+        if file_syncs:
+            _mx.inc("minio_tpu_durability_fsync_total", file_syncs,
+                    kind="file")
+        if dir_syncs:
+            _mx.inc("minio_tpu_durability_fsync_total", dir_syncs,
+                    kind="dir")
+        return step
+
+    def _commit_outcome(self, result: list, dst_volume: str, src_path: str,
+                        where: str) -> None:
+        """A native commit that stands counts what it could not remove;
+        one that failed raises the Python sequence's typed error for the
+        step it names (the volume is probed only now)."""
+        step, err, sync_kind, _, _, ddirs_left, tmp_left = result
         if step == 0:
             if ddirs_left:
                 _mx.inc("minio_tpu_durability_purge_failed_total",
@@ -865,14 +941,14 @@ class XLStorage(StorageAPI):
             return
         if step == _native.COMMIT_STAGED:
             raise errors.FileNotFound(src_path)
-        if step == _native.COMMIT_OBJECT_DIR and err == errno.ENOENT \
+        if step == _native.COMMIT_OBJECT_DIR \
+                and err in (errno.ENOENT, errno.ENOTDIR) \
                 and not os.path.isdir(self._abs(dst_volume)):
             raise errors.VolumeNotFound(dst_volume)
         if step == _native.COMMIT_FSYNC:
             _mx.inc("minio_tpu_durability_fsync_failed_total",
                     kind="dir" if sync_kind else "file")
-        raise OSError(err, os.strerror(err),
-                      ddir_dst or self._meta_path(dst_volume, dst_path))
+        raise OSError(err, os.strerror(err), where)
 
     def _purge_ddirs(self, volume: str, path: str, ddirs: list[str]):
         """Remove data dirs of replaced versions (overwrite cleanup).

@@ -287,6 +287,82 @@ def test_storage_rest_roundtrip(rpc_node, tmp_path):
     rc.close()
 
 
+def test_storage_rest_commit_part_roundtrip(rpc_node):
+    """A remote drive's part commit is ONE RPC (ISSUE 43: ``commitpart``,
+    where ``renamefile`` + ``writeall`` were two): the shard staged over
+    the wire lands beside its sidecar, the staging directory goes, and the
+    drive's typed errors come back as themselves."""
+    from minio_tpu.dist.storage_rest import StorageRESTClient
+    from minio_tpu.obs import metrics as mx
+    from minio_tpu.storage.xlstorage import META_MULTIPART, META_TMP
+    url = f"http://127.0.0.1:{rpc_node.server.port}"
+    disk_path = list(rpc_node.local_disks)[0]
+    rc = StorageRESTClient(url, disk_path, SK)
+    w = rc.create_file_writer(META_TMP, "t1/part.4")
+    w.write(b"shard-")
+    w.write(b"bytes")
+    w.close()
+    calls = []
+    orig = rc.rpc.call
+    rc.rpc.call = lambda method, *a, **kw: (calls.append(method),
+                                            orig(method, *a, **kw))[1]
+    key = 'minio_tpu_storage_part_commits_total{route="native"}'
+    before = mx.counters_snapshot().get(key, 0)
+    sidecar = bytes(range(256)) * 3  # binary, as msgpack is
+    rc.commit_part(META_TMP, "t1/part.4", META_MULTIPART, "hash/up/part.4",
+                   sidecar)
+    assert calls == ["commitpart"]
+    assert mx.counters_snapshot().get(key, 0) - before == 1
+    assert rc.read_all(META_MULTIPART, "hash/up/part.4") == b"shard-bytes"
+    assert rc.read_all(META_MULTIPART, "hash/up/part.4.meta") == sidecar
+    assert rc.list_dir(META_MULTIPART, "hash/up") == ["part.4",
+                                                      "part.4.meta"]
+    assert rc.list_dir(META_TMP, "") == []
+    # typed errors over the wire: nothing staged; no such volume (the
+    # staged shard stays for the sweep)
+    with pytest.raises(errors.FileNotFound):
+        rc.commit_part(META_TMP, "t1/part.4", META_MULTIPART,
+                       "hash/up/part.5", b"m")
+    w = rc.create_file_writer(META_TMP, "t2/part.1")
+    w.write(b"s")
+    w.close()
+    with pytest.raises(errors.VolumeNotFound):
+        rc.commit_part(META_TMP, "t2/part.1", "missing-vol", "up/part.1",
+                       b"m")
+    assert rc.list_dir(META_TMP, "t2") == ["part.1"]
+    assert rc.list_dir(META_MULTIPART, "hash/up") == ["part.4",
+                                                      "part.4.meta"]
+    rc.close()
+
+
+def test_two_node_multipart_parts_commit_on_remote_drives(cluster):
+    """A part PUT through node 0 commits on node 1's drives too (three of
+    the six are remote: one ``commitpart`` RPC each), is listed from either
+    node, completes, and reads back through node 1."""
+    from minio_tpu.objectlayer.multipart import upload_path
+    from minio_tpu.storage.xlstorage import META_MULTIPART, META_TMP
+    n0, n1 = cluster
+    n0.obj.make_bucket("mpshared")
+    uid = n0.obj.new_multipart_upload("mpshared", "big")
+    bodies = [rng_bytes(5 << 20, seed=5), rng_bytes(300 << 10, seed=6)]
+    parts = [n0.obj.put_object_part("mpshared", "big", uid, i + 1,
+                                    io.BytesIO(b), len(b))
+             for i, b in enumerate(bodies)]
+    up = upload_path("mpshared", "big", uid)
+    for n in cluster:
+        for d in n.local_disks.values():
+            assert sorted(x for x in d.list_dir(META_MULTIPART, up)
+                          if x.startswith("part.")) == [
+                "part.1", "part.1.meta", "part.2", "part.2.meta"]
+            assert d.list_dir(META_TMP, "") == []
+    assert [p.part_number for p in
+            n1.obj.list_object_parts("mpshared", "big", uid).parts] == [1, 2]
+    n1.obj.complete_multipart_upload("mpshared", "big", uid, parts)
+    c1 = S3Client(f"http://127.0.0.1:{n1.server.port}", AK, SK)
+    r = c1.get_object("mpshared", "big")
+    assert r.status_code == 200 and r.content == b"".join(bodies)
+
+
 def test_single_node_rpc_cluster_s3(rpc_node):
     """S3 traffic against the node built through the Node assembly."""
     c = S3Client(f"http://127.0.0.1:{rpc_node.server.port}", AK, SK)

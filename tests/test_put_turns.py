@@ -22,6 +22,13 @@ Python sequences make a file-system call a step.
   with the error the health tracker fences on and leaves nothing staged;
 * a disk fault armed anywhere moves the process onto the Python sequence
   (where the crash points are), and the route counters say so;
+* a multipart part (ISSUE 43) commits on all its drives at once, ONE native
+  call a drive (``native.commit_part``: the shard renamed into the upload's
+  directory, its sidecar written under a tmp name and renamed, the emptied
+  staging directory removed), where ``rename_file`` + ``write_all`` made 8
+  calls a drive, one drive after the other on the request's thread: a part
+  PUT is 37 | 19 calls (121 | 61 before); its native and its Python
+  sequence leave the same tree, fsyncs, markers and errors;
 * an object at or under 128 KiB (ISSUE 39) stages nothing: its shards ride
   in the drives' xl.meta, a PUT is 2 calls a drive (the xl.meta read, one
   native commit) and a GET makes exactly the calls of a STAT; its native
@@ -40,13 +47,15 @@ import pytest
 from minio_tpu import fault, native
 from minio_tpu.objectlayer import ErasureObjects
 from minio_tpu.objectlayer.datatypes import ObjectNotFound
+from minio_tpu.objectlayer.multipart import upload_path
 from minio_tpu.obs import metrics as mx
 from minio_tpu.storage import (ErasureInfo, FileInfo, ObjectPartInfo,
                                XLStorage)
 from minio_tpu.storage import durability
 from minio_tpu.storage.health import DiskHealthCheck
 from minio_tpu.storage.xlmeta import XL_META_FILE, XLMeta
-from minio_tpu.storage.xlstorage import META_TMP, _FileWriter, _StagedFile
+from minio_tpu.storage.xlstorage import (META_MULTIPART, META_TMP,
+                                         _FileWriter, _StagedFile)
 from minio_tpu.utils import errors
 
 pytestmark = pytest.mark.skipif(not native.available(),
@@ -60,7 +69,7 @@ OS_CALLS = (
     "unlink", "remove", "rename", "replace", "scandir", "listdir", "link",
     "symlink", "readlink", "truncate", "ftruncate", "utime", "chmod")
 NATIVE_CALLS = ("stage_file", "close_fds", "commit_version", "commit_inline",
-                "open_shard", "read_file")
+                "commit_part", "open_shard", "read_file")
 
 #: a rule that matches no drive: arming it is what moves the process onto
 #: the Python sequences
@@ -104,6 +113,15 @@ def _file_reads(before=None):
     snap = mx.counters_snapshot()
     return {route: snap.get(
         f'minio_tpu_storage_file_reads_total{{route="{route}"}}', 0)
+        - (before[route] if before else 0) for route in ("native", "python")}
+
+
+def _part_commits(before=None):
+    """The multipart part commits (ISSUE 43) by route, since ``before``
+    when given."""
+    snap = mx.counters_snapshot()
+    return {route: snap.get(
+        f'minio_tpu_storage_part_commits_total{{route="{route}"}}', 0)
         - (before[route] if before else 0) for route in ("native", "python")}
 
 
@@ -276,6 +294,46 @@ def test_inline_put_and_get_take_the_turns_of_a_stat(tmp_path, monkeypatch,
     assert ol.get_object_bytes("b", "k/new") == body[::-1]
 
 
+@pytest.mark.parametrize("n,parity,whole", [(12, 4, 37), (6, 2, 19)])
+def test_part_put_commits_in_one_call_a_drive(tmp_path, monkeypatch, n,
+                                              parity, whole):
+    """ISSUE 43: a 10 MiB part PUT is 37 | 19 calls (12 | 6 drives): on the
+    request's thread the upload's quorum pass (ONE native read a drive),
+    ONE native call a drive to stage the shard file and one close of them
+    all (25 | 13), and on the pool ONE native commit a drive. Before it
+    read 121 | 61, all on the request's thread (this method on the parent
+    commit dd56fce; PERF.md section 7, PR 40): ``rename_file`` (exists,
+    makedirs' stat and mkdir, replace) + ``write_all`` (mkdir, open, write,
+    close, replace) were 8 calls a drive, one drive after the other.
+    Nothing is left staged: the commit removes the directory it emptied."""
+    ol = _layer(str(tmp_path), n, parity)
+    ol.make_bucket("b")
+    body = _body(10 << 20)
+    uid = ol.new_multipart_upload("b", "k/mp")
+    first = ol.put_object_part("b", "k/mp", uid, 1, io.BytesIO(body),
+                               len(body))
+    turns = _Turns(monkeypatch, str(tmp_path),
+                   ops=("rename_data", "commit_part"))
+    before, reads = _part_commits(), _file_reads()
+    with turns:
+        part = ol.put_object_part("b", "k/mp", uid, 2, io.BytesIO(body[::-1]),
+                                  len(body))
+    assert turns.of_request() == n + n + 1, turns.calls
+    commits = turns.by_commit()
+    assert len(commits) == n and max(commits.values()) == 1, turns.calls
+    assert turns.total() == whole, turns.calls
+    assert sum(v for (t, _, name), v in turns.calls.items()
+               if name.endswith("commit_part") and t != turns.request) == n
+    assert _part_commits(before) == {"native": n, "python": 0}
+    assert _file_reads(reads) == {"native": n, "python": 0}
+    for d in ol.disks:
+        assert os.listdir(os.path.join(d.base, META_TMP)) == []
+    assert [p.part_number for p in
+            ol.list_object_parts("b", "k/mp", uid).parts] == [1, 2]
+    ol.complete_multipart_upload("b", "k/mp", uid, [first, part])
+    assert ol.get_object_bytes("b", "k/mp") == body + body[::-1]
+
+
 @pytest.mark.parametrize("n,parity", [(12, 4), (6, 2)])
 @pytest.mark.parametrize("size", [10 << 20, 64 << 10],
                          ids=["shard_files", "inline"])
@@ -386,8 +444,89 @@ CASES = {
 }
 
 
+UPLOAD = "5d41402abc4b2a76b9719d911017c592/upload-one"
+
+#: a multipart part's commit (ISSUE 43). name -> (whether the upload's
+#: directory is there, as the Create left it; the (part number, shard,
+#: sidecar) committed one after another)
+PART_CASES = {
+    "part_in_its_upload": (True, [(3, b"shard-three", b"meta-three")]),
+    # a drive that missed the Create: the upload's directories are made
+    "part_upload_dir_missing": (False, [(3, b"shard-three", b"meta-three")]),
+    "part_sent_again": (True, [(3, b"shard-three", b"meta-three"),
+                               (3, b"shard-again", b"meta-again" * 40)]),
+    "part_after_part": (True, [(1, b"one" * 999, b"meta-one"),
+                               (2, b"", b"meta-two")]),
+}
+
+
+def _commit_part(disk, num, shard, sidecar, tmp_id=None,
+                 volume=META_MULTIPART, upload=UPLOAD):
+    """Stage a part's shard file and commit it the way put_object_part
+    does."""
+    tmp_id = tmp_id or f"{os.getpid()}-{uuid.uuid4()}"
+    w = disk.create_file_writer(META_TMP, f"{tmp_id}/part.{num}")
+    w.write(shard)
+    w.close()
+    disk.commit_part(META_TMP, f"{tmp_id}/part.{num}", volume,
+                     f"{upload}/part.{num}", sidecar)
+
+
+def _part_sequences_leave_the_same_tree(tmp_path, markers, case, mode):
+    there, parts = PART_CASES[case]
+    seen = {}
+    for route in ("native", "python"):
+        disk = XLStorage(str(tmp_path / route))
+        if there:
+            disk.write_all(META_MULTIPART, f"{UPLOAD}/{XL_META_FILE}",
+                           b"the upload's journal")
+        if route == "python":
+            fault.arm(NO_DRIVE)
+        del markers[:]
+        syncs, routes, staged = _fsyncs(), _part_commits(), _route_counters()
+        for num, shard, sidecar in parts:
+            _commit_part(disk, num, shard, sidecar)
+        after = _fsyncs()
+        seen[route] = {
+            "tree": _tree(disk.base),
+            "fsyncs": {k: after[k] - syncs[k] for k in after},
+            "markers": [(k, os.path.relpath(p, disk.base))
+                        for k, p in markers],
+            "routes": _part_commits(routes),
+            "staged": _route_delta(staged)[("staged_files", route)]}
+        fault.clear()
+    nat, py = seen["native"], seen["python"]
+    n = len(parts)
+    assert nat["routes"] == {"native": n, "python": 0}
+    assert py["routes"] == {"native": 0, "python": n}
+    assert nat["staged"] == py["staged"] == n
+    assert nat["tree"] == py["tree"]
+    tree = nat["tree"]
+    # the staging directory the shard left empty is gone on both routes
+    assert [p for p in tree if p.startswith(".minio.sys/tmp")] == [
+        ".minio.sys/tmp/"]
+    up = f"{META_MULTIPART}/{UPLOAD}"
+    last = {num: (shard, sidecar) for num, shard, sidecar in parts}
+    assert sorted(p for p in tree if p.startswith(up + "/part.")) == sorted(
+        f"{up}/part.{num}{ext}" for num in last for ext in ("", ".meta"))
+    for num, (shard, sidecar) in last.items():
+        assert tree[f"{up}/part.{num}"] == shard
+        assert tree[f"{up}/part.{num}.meta"] == sidecar
+    assert (f"{up}/{XL_META_FILE}" in tree) == there
+    assert nat["fsyncs"] == py["fsyncs"]
+    assert nat["markers"] == py["markers"]
+    # under ``always``: the staged file at its close and again as
+    # durable_replace's source, the sidecar's tmp; the upload's directory
+    # after each of the two renames (docs/durability.md)
+    assert nat["fsyncs"] == ({"file": 3 * n, "dir": 2 * n}
+                             if mode == "always" else {"file": 0, "dir": 0})
+    assert nat["markers"] == ([
+        ("file", f"{up}/part.{num}{ext}") for num, _, _ in parts
+        for ext in ("", ".meta")] if mode == "batched" else [])
+
+
 @pytest.mark.parametrize("mode", ["off", "batched", "always"])
-@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("case", sorted(CASES) + sorted(PART_CASES))
 def test_native_and_python_sequences_leave_the_same_tree(
         tmp_path, monkeypatch, case, mode):
     monkeypatch.setenv("MINIO_TPU_FSYNC", mode)
@@ -396,6 +535,9 @@ def test_native_and_python_sequences_leave_the_same_tree(
     monkeypatch.setattr(fl, "enqueue_tree",
                         lambda p: markers.append(("tree", p)))
     monkeypatch.setattr(fl, "enqueue", lambda p: markers.append(("file", p)))
+    if case in PART_CASES:
+        _part_sequences_leave_the_same_tree(tmp_path, markers, case, mode)
+        return
     key, versions = CASES[case]
     versions = versions()
     seen = {}
@@ -520,21 +662,22 @@ os.environ["FS_ORDER_LOG"] = base + ".log"
 for fi in (t._fi(ddir="dd-one", size=11), t._fi(ddir="dd-two", size=12),
            t._fi(ddir="dd-three", size=13, data=b"framed-shard" * 9)):
     t._commit(disk, "a/obj", fi)
+t._commit_part(disk, 7, b"shard-seven", b"meta-seven")
 """
 
 
 def _staged_name(path, base):
     """``path`` below the drive ``base``; what is staged under
     ``.minio.sys/tmp`` by its last component (the ids are minted), and
-    ``xl.meta``'s tmp, which has a name of its own on either route, as
-    ``tmp/xl.meta``."""
+    ``xl.meta``'s tmp (or a part's sidecar's), which has a name of its own
+    on either route, as ``tmp/xl.meta``."""
     if path == "-":
         return path
     rel = os.path.relpath(path, base)
     if not rel.startswith(".minio.sys/tmp/"):
         return rel
     last = rel.split("/")[-1]
-    return "tmp/" + (last if last in ("part.1", "dd-one", "dd-two")
+    return "tmp/" + (last if last in ("part.1", "part.7", "dd-one", "dd-two")
                      else "xl.meta")
 
 
@@ -573,8 +716,18 @@ def test_always_issues_the_python_sequences_fsyncs_in_their_order(tmp_path):
     assert logs["native"] == logs["python"]
     assert logs["native"][:7] == one
     # the third version is inline: durable_replace of xl.meta and no more
-    assert logs["native"][14:] == one[4:]
-    assert len(logs["native"]) == 17
+    assert logs["native"][14:17] == one[4:]
+    # a multipart part (ISSUE 43): the staged shard at its close, then
+    # durable_replace of it and durable_replace of its sidecar
+    up = f"{META_MULTIPART}/{UPLOAD}"
+    assert logs["native"][17:] == [
+        ("fsync", "tmp/part.7", "-"),
+        ("fsync", "tmp/part.7", "-"),
+        ("rename", "tmp/part.7", f"{up}/part.7"),
+        ("fsync", up, "-"),
+        ("fsync", "tmp/xl.meta", "-"),
+        ("rename", "tmp/xl.meta", f"{up}/part.7.meta"),
+        ("fsync", up, "-")]
 
 
 def test_staged_file_means_what_file_writer_means(tmp_path, monkeypatch):
@@ -673,6 +826,36 @@ def test_commit_errors_are_the_same_on_both_routes(tmp_path, route):
     out = _outcome(lambda: disk.rename_data(META_TMP, "t-file", fi,
                                             "bucket", "file/obj"))
     assert out[0] is NotADirectoryError, out
+    # a multipart part's commit (ISSUE 43). No shard file was staged:
+    # nothing is made for it
+    with pytest.raises(errors.FileNotFound):
+        disk.commit_part(META_TMP, "t-none/part.1", META_MULTIPART,
+                         f"{UPLOAD}/part.1", b"m")
+    assert disk.list_dir(META_MULTIPART, "") == []
+    # the volume is not there: it is not made, the shard stays staged
+    with pytest.raises(errors.VolumeNotFound):
+        _commit_part(disk, 1, b"s", b"m", tmp_id="t-pvol", volume="nobucket")
+    assert not os.path.exists(os.path.join(disk.base, "nobucket"))
+    assert disk.list_dir(META_TMP, "t-pvol") == ["part.1"]
+    # the upload's directory is there (the Create made it) or not (this
+    # drive missed it): the part and its sidecar land, the staging goes
+    for tmp_id, num in (("t-made", 1), ("t-there", 2)):
+        _commit_part(disk, num, b"shard", b"sidecar", tmp_id=tmp_id)
+        assert disk.read_all(META_MULTIPART, f"{UPLOAD}/part.{num}") \
+            == b"shard"
+        assert disk.read_all(META_MULTIPART, f"{UPLOAD}/part.{num}.meta") \
+            == b"sidecar"
+        assert not os.path.exists(os.path.join(disk.base, META_TMP, tmp_id))
+    # the upload's directory, or the part's name, is in the way
+    disk.write_all(META_MULTIPART, "hash/upload", b"x")
+    out = _outcome(lambda: _commit_part(disk, 1, b"s", b"m", tmp_id="t-nd",
+                                        upload="hash/upload"))
+    assert out == (NotADirectoryError, 20), out
+    disk.write_all(META_MULTIPART, f"{UPLOAD}/part.9/below", b"x")
+    out = _outcome(lambda: _commit_part(disk, 9, b"s", b"m", tmp_id="t-isd"))
+    assert out == (IsADirectoryError, 21), out
+    assert disk.list_dir(META_MULTIPART, UPLOAD) == [
+        "part.1", "part.1.meta", "part.2", "part.2.meta", "part.9/"]
 
 
 def _kill(disk):
@@ -876,6 +1059,23 @@ def test_armed_fault_takes_the_python_route(tmp_path):
         ("staged_files", "python"): 6, ("commits", "python"): 6}
     assert ol.get_object_info("b", "python").size == len(body)
     assert _file_reads(reads) == {"native": 0, "python": 12}
+    # a multipart part goes by the same rule (ISSUE 43)
+    uid = ol.new_multipart_upload("b", "mp")
+    parts, before = [], _part_commits()
+    for num, arm in ((1, False), (2, True)):
+        fault.clear()
+        if arm:
+            fault.arm(NO_DRIVE)
+        parts.append(ol.put_object_part("b", "mp", uid, num,
+                                        io.BytesIO(body * 2), 2 * len(body)))
+        assert _part_commits(before) == {"native": 6, "python": 6 * arm}
+    for d in ol.disks:
+        assert sorted(os.listdir(os.path.join(
+            d.base, META_MULTIPART, upload_path("b", "mp", uid)))) == [
+            "part.1", "part.1.meta", "part.2", "part.2.meta", XL_META_FILE]
+        assert os.listdir(os.path.join(d.base, META_TMP)) == []
+    ol.complete_multipart_upload("b", "mp", uid, parts)
+    assert ol.get_object_bytes("b", "mp") == body * 4
     fault.clear()
     trees = [_tree(os.path.join(d.base, "b")) for d in ol.disks]
     for t in trees:

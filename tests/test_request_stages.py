@@ -102,7 +102,8 @@ def _one_record(api: str) -> dict:
      {"commit.pool": 6}),
     ("delete", "deleteobject", {"bucket_check", "meta_pass", "delete"},
      {"delete.pool": 6}),
-    ("part", "putobjectpart", {"meta_pass", "body_read", "commit"}, {}),
+    ("part", "putobjectpart", {"meta_pass", "body_read", "commit"},
+     {"commit.pool": 6}),
 ])
 def test_a_request_leaves_one_record_whose_stages_sum_to_its_wall(
         served, case, api, has, pool):
