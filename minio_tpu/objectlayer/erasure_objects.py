@@ -67,10 +67,30 @@ def _count_inline(op: str, nbytes: int, versions: int = 1) -> None:
     _mx.inc("minio_tpu_objectlayer_inline_bytes_total", nbytes, op=op)
 
 
+def layout_of(parts: int, inline: bool) -> str:
+    """How a version lies on its drives, as a read finds it: ``inline``
+    (shards inside xl.meta), ``multipart`` (part files of more parts than
+    one) or ``file`` (one part's shard files, however they were sent)."""
+    return "inline" if inline else "multipart" if parts > 1 else "file"
+
+
+def count_put(route: str, nbytes: int, versions: int = 1) -> None:
+    """A version reached write quorum by ``route``, the way it was SENT:
+    ``inline`` or ``file`` (``put_object``), ``multipart``
+    (``complete_multipart_upload``, of however many parts). The route also
+    goes onto the span around the caller."""
+    _mx.inc("minio_tpu_objectlayer_put_versions_total", versions,
+            route=route)
+    _mx.inc("minio_tpu_objectlayer_put_bytes_total", nbytes, route=route)
+    _spans.annotate(route=route)
+
+
 # there from the start, at 0: a share of a window in which nothing was
 # inline reads 0, where a program without the path has nothing to read
 for _op in ("put", "get", "heal"):
     _count_inline(_op, 0, 0)
+for _route in ("inline", "file", "multipart"):
+    count_put(_route, 0, 0)
 _mx.inc("minio_tpu_pipeline_get_blocks_total", 0, route="inline")
 
 
@@ -560,6 +580,8 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         if inline:
             _count_inline("put", total)
             _spans.annotate(inline=True)
+        count_put("inline" if inline else "file", total)
+        _stages.touched(total)
         from ..scanner.tracker import global_tracker
         global_tracker().mark(bucket, object)
         self.metacache.on_write(bucket)
@@ -680,6 +702,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
             if not opts.version_id:
                 raise dt.ObjectNotFound(bucket, object)
             raise dt.MethodNotAllowed(bucket, object)
+        _stages.touched(fi.size)
         oi = ObjectInfo.from_file_info(
             fi, bucket, object,
             opts.versioned or bool(opts.version_id) or bool(fi.version_id))
@@ -783,6 +806,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
                 if b is not None)
             _count_inline("get", length)
             _spans.annotate(inline=True)
+        _spans.annotate(layout=layout_of(len(fi.parts), inline))
         part_start = 0  # start byte of current part within the object
         for part in () if inline else fi.parts:
             part_end = part_start + part.size

@@ -271,6 +271,7 @@ class MultipartMixin:
         if missed:
             # a drive that committed removed its own staging (commit_part)
             self._cleanup_tmp(tmp_id, missed)
+        _stages.touched(total)
         return PartInfo(part_number=part_id, etag=etag, size=total,
                         actual_size=hr.actual_size
                         if hr.actual_size >= 0 else total,
@@ -375,7 +376,17 @@ class MultipartMixin:
     def complete_multipart_upload(self, bucket: str, object: str,
                                   upload_id: str, parts,
                                   opts: ObjectOptions = None) -> ObjectInfo:
-        from .erasure_objects import ACTUAL_SIZE_KEY, to_object_err
+        with _spans.span("objectlayer.complete_multipart_upload",
+                         bucket=bucket, object=object):
+            return self._complete_multipart_upload_inner(
+                bucket, object, upload_id, parts, opts)
+
+    def _complete_multipart_upload_inner(self, bucket: str, object: str,
+                                         upload_id: str, parts,
+                                         opts: ObjectOptions = None
+                                         ) -> ObjectInfo:
+        from .erasure_objects import ACTUAL_SIZE_KEY, count_put, \
+            to_object_err
         opts = opts or ObjectOptions()
         fi, fis, _ = self._upload_meta(bucket, object, upload_id)
         upath = upload_path(bucket, object, upload_id)
@@ -433,6 +444,7 @@ class MultipartMixin:
             errs, errors.BASE_IGNORED_ERRS, write_quorum)
         if err is not None:
             raise to_object_err(err, bucket, object)
+        _stages.touched(fi.size)
         # reap the upload dir
         for d in disks:
             if d is None:
@@ -449,6 +461,10 @@ class MultipartMixin:
             _bs.on_put(bucket, fi.size)
         except Exception:  # noqa: BLE001 — obs must never fail a commit
             pass
+        # (counted last, next to the reply: a window's edge then falls
+        # between a version counted and its acknowledgement as seldom as
+        # for a PUT, not for the whole reap above)
+        count_put("multipart", fi.size)
         return ObjectInfo.from_file_info(fi, bucket, object, opts.versioned)
 
     def _commit_one_disk(self, d, upath: str, tmp_id: str, fi: FileInfo,

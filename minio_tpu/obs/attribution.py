@@ -16,7 +16,9 @@ work and keeps what it collected:
 
 One record a finished unit: end time on ``time.monotonic``, id, API,
 status, bytes, wall and thread CPU seconds and voluntary switches of its
-own thread, and a stage its ``[wall_s, cpu_s, count, switches]``, the
+own thread, ``object_bytes`` (the size of the object it read, wrote,
+statted or removed, as the object layer told ``stages.touched``; -1 where
+there was none), and a stage its ``[wall_s, cpu_s, count, switches]``, the
 stages of its own thread (``stages``, self times, ``other`` = the rest)
 apart from what ran beside it (``pool``); ``sampled`` says whether the
 unit read the CPU clock and the switches at all (``stages.cpu_stride``:
@@ -87,7 +89,8 @@ def _keep(api: str, rid: str, status: int, nbytes: int, nested: bool,
     with _lock:
         names = _names.setdefault(names, names)
         _ring[_n % RING] = (t_end, rid, api, status, nbytes, nested, wall,
-                            cpu, sw, names, vals, st.sampled)
+                            cpu, sw, names, vals, st.sampled,
+                            st.object_bytes)
         _n += 1
         for stage, v in (("", (wall, cpu, 1, sw)), *own.items(),
                          *aside.items()):
@@ -102,11 +105,12 @@ def _keep(api: str, rid: str, status: int, nbytes: int, nested: bool,
 
 def _expand(rec: tuple) -> dict:
     (t_end, rid, api, status, nbytes, nested, wall, cpu, sw,
-     (own, aside), vals, sampled) = rec
+     (own, aside), vals, sampled, object_bytes) = rec
     rows = [[vals[i], vals[i + 1], int(vals[i + 2]), int(vals[i + 3])]
             for i in range(0, len(vals), 4)]
     return {"t_end": t_end, "id": rid, "api": api, "status": status,
-            "bytes": nbytes, "nested": nested, "wall_s": wall,
+            "bytes": nbytes, "object_bytes": object_bytes,
+            "nested": nested, "wall_s": wall,
             "cpu_s": cpu, "switches": sw, "sampled": sampled,
             "stages": dict(zip(own, rows)),
             "pool": dict(zip(aside, rows[len(own):]))}

@@ -138,7 +138,8 @@ class StageTimes:
     object layer's ``attribution.observed`` arms one INSIDE the
     request's, and every charge flows to both."""
 
-    __slots__ = ("own", "aside", "parent", "tid", "api", "sampled")
+    __slots__ = ("own", "aside", "parent", "tid", "api", "sampled",
+                 "object_bytes")
 
     def __init__(self, parent: "StageTimes | None" = None, api: str = "",
                  sampled: bool = True):
@@ -150,6 +151,9 @@ class StageTimes:
         #: do this unit's boundaries read the CPU clock and the switches?
         #: (``cpu_stride``; a chained collector does as its parent does)
         self.sampled = sampled if parent is None else parent.sampled
+        #: size of the object the unit read, wrote, statted or removed
+        #: (``touched``); -1 while nobody has said
+        self.object_bytes = -1
 
     def _charge(self, stage: str, wall: float, cpu: float, sw: int,
                 tid: int) -> None:
@@ -266,6 +270,20 @@ class _Stage:
 def active() -> StageTimes | None:
     """The armed collector, or None (the common, zero-cost case)."""
     return _current.get()
+
+
+def touched(nbytes: int) -> None:
+    """The object layer says how large the object is that the armed unit
+    of work reads, writes, stats or removes (a part PUT: the part): kept
+    on the unit's collector and on those it chains into, so that a
+    request's record can be told apart by the size of its object, where
+    its ``bytes`` (consumed + sent) are ~0 for a STAT and a DELETE."""
+    if not _tl._enabled:
+        return
+    st = _current.get()
+    while st is not None:
+        st.object_bytes = nbytes
+        st = st.parent
 
 
 def open_stage(tid: int) -> tuple[str, str] | None:

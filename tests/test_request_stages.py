@@ -19,7 +19,16 @@ FRONT = {"head", "admit", "route", "auth", "respond", "drain", "epilogue"}
 
 
 @pytest.fixture(autouse=True)
-def _clean():
+def _clean(monkeypatch):
+    """Every unit of work reads the CPU clock here (a stride of 1), as on
+    a host where a read is cheap. ``cpu_stride`` times that read ONCE a
+    process, at whichever test first arms a unit: in this sandbox a read
+    costs 0.57-0.59 us against a line at 0.6 (1.5 x CPU_READ_BUDGET_NS),
+    so beside five busy test workers a process now and then settles on a
+    stride of 2 for good, half its records carry no CPU seconds, and a
+    case that asks ``cpu_s > 0`` of its one record fails by that draw.
+    What the rule makes of a read's cost has its own cases below."""
+    monkeypatch.setattr(stages, "_stride", 1)
     os.environ.pop("MINIO_TPU_TIMELINE", None)
     timeline.configure()
     attribution.reset()
@@ -177,6 +186,7 @@ def test_a_spin_reads_as_cpu_and_a_sleep_as_wall_without_cpu(
 
 
 def test_nested_stages_charge_self_time():
+    t0 = time.monotonic()
     with stages.collect() as st:
         with stages.stage("outer"):
             _spin(0.02)
@@ -184,10 +194,14 @@ def test_nested_stages_charge_self_time():
                 _spin(0.03)
                 with stages.timed(st, "leaf"):
                     time.sleep(0.02)
+    total = time.monotonic() - t0
     assert st.own["inner"][1] == pytest.approx(0.03, abs=0.01)
     assert st.own["outer"][1] == pytest.approx(0.02, abs=0.01)
     assert st.own["leaf"][0] >= 0.02 and st.own["leaf"][1] < 0.005
-    assert st.own["outer"][0] < 0.035 and st.own["inner"][0] < 0.045
+    # a boundary's own wall leaves out what ran inside and around it (at
+    # least their CPU and sleep), however long a busy host makes the whole
+    assert st.own["outer"][0] <= total - 0.05 + 0.002
+    assert st.own["inner"][0] <= total - 0.04 + 0.002
     assert not stages._open     # every boundary closed behind itself
 
 
@@ -234,10 +248,8 @@ def test_an_object_operation_chains_into_the_request_and_is_nested(served):
 
 
 def test_where_the_cpu_clock_is_dear_one_unit_in_n_reads_it(monkeypatch):
-    """``cpu_stride`` follows what a read costs here (cheap: every unit).
-    At a stride of 4 one unit in four reads the CPU clock and the
+    """At a stride of 4 one unit in four reads the CPU clock and the
     switches, its record says so, and it stands for four in the sums."""
-    assert stages.cpu_stride() == 1
     monkeypatch.setattr(stages, "_stride", 4)
     reads = {"n": 0}
     real = stages._thread_time
@@ -263,6 +275,28 @@ def test_where_the_cpu_clock_is_dear_one_unit_in_n_reads_it(monkeypatch):
     assert rep["cpu_seconds_total"] == pytest.approx(0.005 * 8, rel=0.3)
     assert rep["stages"]["meta_pass"]["cpu_seconds_total"] == \
         pytest.approx(0.005 * 8, rel=0.3)
+
+
+@pytest.mark.parametrize("read_ns,stride", [
+    (280, 1), (590, 1), (610, 2), (5900, 15), (12000, 30), (50000, 64)])
+def test_the_stride_follows_what_a_read_of_the_cpu_clock_costs(
+        monkeypatch, read_ns, stride):
+    """``cpu_stride`` is the cost of one ``time.thread_time`` over
+    CPU_READ_BUDGET_NS, at least 1 and at most 64, timed once: the
+    sandbox's 0.28-0.59 us read every unit, the chip's host (5.9 us) one
+    in 15. On a clock that only the reads advance, so under any load."""
+    import types
+    now = [0]
+
+    def thread_time():
+        now[0] += read_ns
+        return now[0] / 1e9
+    monkeypatch.setattr(stages, "time", types.SimpleNamespace(
+        perf_counter_ns=lambda: now[0], thread_time=thread_time))
+    monkeypatch.setattr(stages, "_stride", 0)
+    assert stages.cpu_stride() == stride
+    calls = now[0]
+    assert stages.cpu_stride() == stride and now[0] == calls    # once
 
 
 def test_the_ring_overwrites_and_counts(monkeypatch):
