@@ -11,7 +11,6 @@ accelerator via minio_tpu.erasure.
 from __future__ import annotations
 
 import time as _time
-import uuid
 from dataclasses import replace
 
 from ..erasure import (DEFAULT_BITROT_ALGO, Erasure, new_bitrot_reader,
@@ -33,7 +32,7 @@ from ..erasure.streaming import (BufferSink, BufferSource, close_readers,
 from ..storage.datatypes import ErasureInfo, FileInfo, ObjectPartInfo
 from ..storage.xlmeta import SMALL_FILE_THRESHOLD
 from ..storage.xlstorage import META_BUCKET, META_TMP, new_tmp_id
-from ..utils import errors
+from ..utils import errors, ids
 from ..utils.hashreader import HashReader
 from . import datatypes as dt
 from .datatypes import (DRIVE_STATE_CORRUPT, DRIVE_STATE_MISSING,
@@ -439,7 +438,7 @@ class ErasureObjects(MultipartMixin, ObjectLayer):
         fi = FileInfo(
             volume=bucket, name=object,
             version_id=FileInfo.new_version_id() if opts.versioned else "",
-            data_dir=str(uuid.uuid4()),
+            data_dir=ids.uuid4_str(),
             mod_time=opts.mod_time or FileInfo.now())
         distribution = hash_order(f"{bucket}/{object}", n)
         er = Erasure(data, parity, self.block_size)

@@ -29,10 +29,10 @@ import contextvars
 import os
 import threading
 import time
-import uuid
 from collections import OrderedDict
 from dataclasses import dataclass
 
+from ..utils import ids as _ids
 from . import stages as _stages
 
 #: RPC header carrying the caller's span context (W3C traceparent
@@ -81,11 +81,11 @@ def annotate(**attrs) -> None:
 
 def new_trace_id() -> str:
     """32-hex trace id — doubles as the S3 ``x-amz-request-id``."""
-    return uuid.uuid4().hex
+    return _ids.trace_id()
 
 
 def new_span_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return _ids.span_id()
 
 
 def to_traceparent(ctx: SpanContext) -> str:

@@ -24,7 +24,6 @@ import shutil
 import stat
 import threading
 import time
-import uuid
 from typing import Iterator
 
 from .. import fault as _fault
@@ -34,6 +33,7 @@ from ..obs import metrics as _mx
 from ..obs import spans as _spans
 from ..obs import trace as _trc
 from ..utils import errors
+from ..utils import ids as _ids
 from .datatypes import DiskInfo, FileInfo, VolInfo
 from .durability import (FSYNC_ALWAYS, FSYNC_BATCHED, durable_replace,
                          durable_replace_dir, flusher, fsync_after_write,
@@ -78,7 +78,7 @@ def new_tmp_id() -> str:
     (shared-disk peer layers must not eat each other's in-flight
     staging), while a restart — a new pid — reclaims everything the
     dead process left behind."""
-    return f"{os.getpid()}-{uuid.uuid4()}"
+    return f"{os.getpid()}-{_ids.uuid4_str()}"
 
 
 def _minted_by_live_peer(name: str) -> bool:

@@ -32,7 +32,13 @@ Python sequences make a file-system call a step.
 * an object at or under 128 KiB (ISSUE 39) stages nothing: its shards ride
   in the drives' xl.meta, a PUT is 2 calls a drive (the xl.meta read, one
   native commit) and a GET makes exactly the calls of a STAT; its native
-  and its Python commit leave the same tree, bytes, fsyncs and markers."""
+  and its Python commit leave the same tree, bytes, fsyncs and markers;
+* a counted turn is ANY call that lets go of the interpreter lock, the
+  system's entropy included (ISSUE 45): ``os.urandom`` is wrapped beside the
+  file-system functions, and a traced request, which minted an id a span, a
+  staging id a drive and its data directory's name from ``uuid.uuid4()``
+  (15 | 9 such calls a STAT, 40 | 22 a part PUT), makes none: ids are
+  minted in the process (``minio_tpu/utils/ids.py``)."""
 import builtins
 import collections
 import io
@@ -49,6 +55,7 @@ from minio_tpu.objectlayer import ErasureObjects
 from minio_tpu.objectlayer.datatypes import ObjectNotFound
 from minio_tpu.objectlayer.multipart import upload_path
 from minio_tpu.obs import metrics as mx
+from minio_tpu.obs import spans
 from minio_tpu.storage import (ErasureInfo, FileInfo, ObjectPartInfo,
                                XLStorage)
 from minio_tpu.storage import durability
@@ -70,6 +77,13 @@ OS_CALLS = (
     "symlink", "readlink", "truncate", "ftruncate", "utime", "chmod")
 NATIVE_CALLS = ("stage_file", "close_fds", "commit_version", "commit_inline",
                 "commit_part", "open_shard", "read_file")
+#: the system's entropy: ``os.urandom`` is a system call made with the lock
+#: released like any of the above (``uuid.uuid4`` reaches it through the
+#: module attribute); it names no path, so it is counted on the request's
+#: thread, inside the marked ``XLStorage`` methods and on every thread of
+#: the program's pools (``POOL_THREADS``)
+ENTROPY = "urandom"
+POOL_THREADS = "minio-tpu-"
 
 #: a rule that matches no drive: arming it is what moves the process onto
 #: the Python sequences
@@ -145,6 +159,7 @@ class _Turns:
         self._drive = threading.local()
         for name in OS_CALLS:
             self._wrap(monkeypatch, os, name)
+        self._wrap(monkeypatch, os, ENTROPY, pools=True)
         self._wrap(monkeypatch, builtins, "open")
         for name in NATIVE_CALLS:
             self._wrap(monkeypatch, native, name)
@@ -163,7 +178,7 @@ class _Turns:
 
         monkeypatch.setattr(XLStorage, op, marked)
 
-    def _wrap(self, monkeypatch, mod, name):
+    def _wrap(self, monkeypatch, mod, name, pools=False):
         orig = getattr(mod, name)
 
         def counted(*a, **kw):
@@ -172,7 +187,9 @@ class _Turns:
                 drive = getattr(self._drive, "base", None)
                 if tid == self.request or drive is not None or (
                         a and isinstance(a[0], str)
-                        and a[0].startswith(self.root)):
+                        and a[0].startswith(self.root)) or (
+                        pools and threading.current_thread().name
+                        .startswith(POOL_THREADS)):
                     self.calls[(tid, drive, f"{mod.__name__}.{name}")] += 1
             return orig(*a, **kw)
 
@@ -192,6 +209,11 @@ class _Turns:
     def of_request(self):
         return sum(v for (t, _, _), v in self.calls.items()
                    if t == self.request)
+
+    def minted(self):
+        """The calls for the system's entropy, on any thread."""
+        return sum(v for (_, _, name), v in self.calls.items()
+                   if name == f"os.{ENTROPY}")
 
     def by_commit(self):
         out = collections.Counter()
@@ -372,6 +394,70 @@ def test_delete_takes_two_reads_a_drive_and_the_python_removal(
         ol.get_object_info("b", "k/gone")
     for d in ol.disks:
         assert os.listdir(os.path.join(d.base, "b")) == ["warm"]
+
+
+#: what a served request asked of ``os.urandom`` before ISSUE 45 (this test
+#: on the parent commit b324023, 12 | 6 drives): the request id and the
+#: root's span id, a span id a traced storage call and a span of the object
+#: layer, a staging id a PUT and one more a drive in its commit, the data
+#: directory's name
+MINTED_BEFORE = {
+    "stat": (15, 9), "get_inline": (16, 10), "get_shard_files": (16, 10),
+    "put_inline": (30, 18), "put_shard_files": (18, 12), "part_put": (40, 22),
+    "delete_inline": (28, 16), "delete_shard_files": (28, 16)}
+
+
+@pytest.mark.parametrize("n,parity", [(12, 4), (6, 2)])
+@pytest.mark.parametrize("kind", sorted(MINTED_BEFORE))
+def test_a_request_asks_the_system_for_no_random_number(
+        tmp_path, monkeypatch, kind, n, parity):
+    """ISSUE 45: an id is a name and is minted in the process
+    (``minio_tpu/utils/ids.py``), so a traced request of an unversioned
+    bucket makes no call of ``os.urandom`` on any of its threads, where
+    ``uuid.uuid4()`` made one an id (``MINTED_BEFORE``), each a turn at the
+    interpreter lock that the counts above did not see. The request is
+    traced as a served one is: the request id minted, a sampled root
+    opened, every storage call and object-layer span recorded under it
+    with a span id of its own."""
+    ol = _layer(str(tmp_path), n, parity)
+    ol.make_bucket("b")
+    small, big = _body(64 << 10), _body(10 << 20)
+    ol.put_object("b", "small", io.BytesIO(small), len(small))
+    ol.put_object("b", "big", io.BytesIO(big), len(big))
+    uid = ol.new_multipart_upload("b", "mp")
+    ol.put_object_part("b", "mp", uid, 1, io.BytesIO(big), len(big))
+    act = {
+        "stat": lambda: ol.get_object_info("b", "big"),
+        "get_inline": lambda: ol.get_object_bytes("b", "small"),
+        "get_shard_files": lambda: ol.get_object_bytes("b", "big"),
+        "put_inline": lambda: ol.put_object(
+            "b", "new", io.BytesIO(small), len(small)),
+        "put_shard_files": lambda: ol.put_object(
+            "b", "new", io.BytesIO(big), len(big)),
+        "part_put": lambda: ol.put_object_part(
+            "b", "mp", uid, 2, io.BytesIO(big), len(big)),
+        "delete_inline": lambda: ol.delete_object("b", "small"),
+        "delete_shard_files": lambda: ol.delete_object("b", "big"),
+    }[kind]
+    turns = _Turns(monkeypatch, str(tmp_path),
+                   ops=("rename_data", "commit_part", "delete_version"))
+    with turns:
+        rid = spans.new_trace_id()
+        root, tok = spans.begin_request(rid)
+        try:
+            act()
+        finally:
+            recorded = list(spans._active[rid]["spans"])
+            spans.finish_request(root, tok, name=f"s3.{kind}",
+                                 duration_s=0.0)
+    assert root.sampled and len(rid) == 32
+    # every span is still opened and recorded: a storage call a drive at
+    # the least, each under an id of its own
+    ids = [s["span_id"] for s in recorded] + [root.span_id]
+    assert len(recorded) >= n, recorded
+    assert len(set(ids)) == len(ids) and all(
+        len(i) == 16 and int(i, 16) >= 0 for i in ids), ids
+    assert turns.minted() == 0, (MINTED_BEFORE[kind], turns.calls)
 
 
 # --- (b) the two sequences leave the same tree ------------------------------
